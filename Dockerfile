@@ -13,8 +13,9 @@
 #          JAX_PLATFORMS=cpu. See docker/ for local N-node compose.
 FROM python:3.12-slim
 
-# native host runtime (cpp/psnative.so) builds with g++ at image build
-# time, like the reference's `RUN make -j8`
+# native host runtime (cpp/psnative.cc) builds with g++ at image build
+# time, like the reference's `RUN make -j8`; on a host with another CPU the
+# loader rebuilds it at first use
 RUN apt-get update \
     && apt-get install -y --no-install-recommends g++ make \
     && rm -rf /var/lib/apt/lists/*
@@ -28,7 +29,7 @@ WORKDIR /home/parameter_server_tpu
 COPY parameter_server_tpu parameter_server_tpu
 COPY configs configs
 COPY script script
-COPY bench.py setup.py Makefile ./
+COPY bench.py chip_smoke.py setup.py Makefile ./
 RUN make native
 
 ENV PYTHONPATH=/home/parameter_server_tpu

@@ -35,7 +35,7 @@ import numpy as np
 
 from ...utils import file as psfile
 
-from ...utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...learner.sgd import ISGDCompNode, ISGDScheduler, SGDProgress
@@ -307,9 +307,8 @@ class ELLBatch:
 class ELLPackedBatch:
     """ELLBatch with slot ids packed to 3 bytes on the wire.
 
-    The host→device link (PCIe, or an RPC tunnel in disaggregated setups)
-    is the pipeline's scarce resource — the device step is ~100x faster
-    than the transfer. Slot ids address ``num_slots`` < 2^24 entries, so
+    Every byte here crosses the host→device link once per example.
+    Slot ids address ``num_slots`` < 2^24 entries, so
     int32 wastes a byte per feature; we ship little-endian u24 and
     reassemble with three cheap VPU ops inside the jitted step. This is the
     same byte-economy instinct as the reference's fixing_float filter
@@ -358,10 +357,9 @@ class ELLBitsSuperBatch:
     """T minibatches of ELLBits wire stacked on a leading scan axis.
 
     The device steps through all T minibatches in ONE launch
-    (``lax.scan`` inside the jitted step): on a tunneled/remote TPU the
-    per-launch round trip costs as much as several device steps, so
-    batching launches is the single biggest throughput lever — and it is
-    the idiomatic XLA shape for a sequential optimizer loop anyway.
+    (``lax.scan`` inside the jitted step): one dispatch and one
+    transfer per T ministeps — the idiomatic XLA shape for a
+    sequential optimizer loop.
     Within a superbatch the weights advance every ministep (staleness 0);
     the configured ``max_delay`` bound still governs the snapshot taken
     across superbatch submissions, so the delay bound is never exceeded.
@@ -1456,6 +1454,11 @@ def make_train_step_hashed(
     return _donation_variants(step_impl, name="step_hashed")
 
 
+#: slots per device-side weight derivation in ``weights_dense`` (256 MB
+#: of f32 at a time)
+_WEIGHTS_WINDOW = 1 << 26
+
+
 def sparse_update_min_slots() -> int:
     """``SGDConfig.update="auto"`` flip point, in PER-SERVER shard
     slots: below it the dense sweep wins (the whole-shard Pallas pass
@@ -2209,9 +2212,8 @@ class AsyncSGDWorker(ISGDCompNode):
         )
         # step functions cached per (encoding, binary, with_aux)
         self._steps: Dict[Tuple[str, bool, bool], object] = {}
-        # no-donate: weights_dense derives FROM the live state, which
-        # keeps training afterwards
-        self._weights_fn = jax.jit(self.updater.weights)
+        self._weights_window = min(self.num_slots, _WEIGHTS_WINDOW)
+        self._weights_fn = self._make_weights_fn()
         # max_delay=0 still bounds in-flight work to one step ahead — 0 here
         # would mean *unbounded* (executor semantics), pinning every metrics
         # future in memory
@@ -2303,6 +2305,26 @@ class AsyncSGDWorker(ISGDCompNode):
             from ...learner.consistency import ConsistencyRuntime
 
             self._consistency = ConsistencyRuntime.from_config(self, sgd)
+
+    def _make_weights_fn(self):
+        """The jitted weight derivation behind ``weights_dense``, over a
+        window of slots at a traced start (one program). Windowed
+        because the whole weight vector of a 2^30 table is a 4 GiB
+        temporary that does not fit beside the live state and its
+        bounded-delay snapshot (RESOURCE_EXHAUSTED with 2.63 GiB free
+        on a 16 GB chip). Closes over ``lr.alpha``: rebuilt when the
+        consistency controller backs the learning rate off."""
+        window = self._weights_window
+
+        def weights_window(state, start):
+            return self.updater.weights(jax.tree.map(
+                lambda a: jax.lax.dynamic_slice_in_dim(a, start, window)
+                if a.ndim >= 1 else a,
+                state,
+            ))
+
+        # no-donate: derives FROM the live state, which keeps training
+        return jax.jit(weights_window)
 
     def set_effective_tau(self, tau: int) -> int:
         """Move the live bounded-delay τ (between submissions; the
@@ -2409,7 +2431,14 @@ class AsyncSGDWorker(ISGDCompNode):
             per_nnz = -(-batch.nnz // d)
             # tight padding: 25% headroom rounded to 4k — transfer bytes are
             # the pipeline's scarce resource, not compile-shape variety
-            nnz = self.sgd.nnz_pad or max(4096, -(-int(per_nnz * 1.25) // 4096) * 4096)
+            nnz = self.sgd.nnz_pad or max(
+                4096, -(-int(per_nnz * 1.25) // 4096) * 4096,
+                # never below the lane budget: the first batch through
+                # the tail-feature filter is nearly empty (its counts
+                # are cold), and pads pinned from it are exceeded as
+                # soon as features start to pass
+                rows * self.sgd.ell_lanes,
+            )
             self._pads = (rows, nnz, nnz)
         return self._pads
 
@@ -2866,7 +2895,7 @@ class AsyncSGDWorker(ISGDCompNode):
         would fire once at trace time and never again (pslint
         jit-purity). No-op for non-FTRL/non-decay updaters and while
         telemetry is off."""
-        from ...ops.ftrl import _use_pallas
+        from ...ops import use_pallas
         from ...ops.ftrl_sparse import resolve_update_path
         from ...telemetry.instruments import cached_ftrl_instruments
         from .updaters import FTRLUpdater
@@ -2887,7 +2916,7 @@ class AsyncSGDWorker(ISGDCompNode):
                 or getattr(prepped, "uslots", np.empty((0, 0))).shape[-1]
             )
         path = resolve_update_path(
-            self._update_mode, on_tpu=_use_pallas(), shard=shard, u=u,
+            self._update_mode, on_tpu=use_pallas(), shard=shard, u=u,
             bf16_n=self.updater.sqrt_n_dtype == jnp.bfloat16,
             has_seed=True,  # _submit_prepped always threads a seed
         )
@@ -3205,7 +3234,17 @@ class AsyncSGDWorker(ISGDCompNode):
         # drain in-flight steps (state advances on the executor thread)
         # WITHOUT popping: metrics stay claimable by a later collect()
         self.executor.wait_all(pop=False)
-        return np.asarray(self._weights_fn(self.state))
+        n, window = self.num_slots, self._weights_window
+        out = np.empty(n, np.float32)
+        for lo in range(0, n, window):
+            lo = min(lo, n - window)  # the last window may overlap
+            # sixteen windows of a 2^30 table outlast the heartbeat
+            # timeout: a worker writing its model out is not dead
+            self.po.beat(self.name)
+            out[lo:lo + window] = np.asarray(
+                self._weights_fn(self.state, np.int32(lo))
+            )
+        return out
 
     def recover_server_shard(self, shard: int) -> bool:
         """Rebuild a dead server shard's slot segment from the live

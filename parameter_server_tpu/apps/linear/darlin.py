@@ -46,7 +46,7 @@ import numpy as np
 
 from ...utils import file as psfile
 
-from ...utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...learner.bcd import BCDProgress, BCDScheduler, FeatureBlock

@@ -214,8 +214,7 @@ def apply_state_rows(updater, state, rel, ok, g_u, seed=None, *,
 
         if ftrl_sparse.use_sparse_kernel(
             state["z"].shape[0], rel.shape[0],
-            updater.sqrt_n_dtype == jnp.bfloat16, seed is not None,
-            force_pallas,
+            updater.sqrt_n_dtype == jnp.bfloat16, force_pallas,
         ):
             z_new, n_new = ftrl_sparse.ftrl_sparse_update(
                 state["z"], state["sqrt_n"], rel, ok, g_u,

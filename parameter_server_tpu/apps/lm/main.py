@@ -41,6 +41,16 @@ def _load_corpus(path: str | None, rng: np.random.Generator) -> np.ndarray:
 
 
 def main(argv=None) -> int:
+    run(argv)
+    return 0
+
+
+def run(argv=None) -> dict:
+    """The CLI's body. Returns what a caller in the same process may
+    want to look at afterwards (``chip_smoke.py`` checks decode against
+    the training forward on the trained weights): ``params``, ``cfg``,
+    ``mesh``, ``losses`` as ``[(step, loss)]`` at the report steps, and
+    ``generated`` (the decoded token ids, or None without --prompt)."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--data", default=None, help="text/bytes file (default: synthetic)")
     ap.add_argument("--steps", type=int, default=100)
@@ -58,8 +68,9 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--attention", default="ring_flash",
         choices=("ring", "ring_flash", "ring_zigzag", "a2a"),
-        help="sequence-parallel schedule (default ring_flash: measured "
-        "1.45x over the XLA chunk path on v5e, BENCH_ONCHIP.md)",
+        help="sequence-parallel schedule (default ring_flash: the "
+        "Pallas flash kernel per ring hop on a TPU, the XLA chunk path "
+        "elsewhere)",
     )
     ap.add_argument("--window", type=int, default=None,
                     help="sliding-window span (flash modes)")
@@ -163,9 +174,9 @@ def main(argv=None) -> int:
     )
     args = ap.parse_args(argv)
 
-    from ...parallel.mesh import honor_jax_platforms
+    from ...utils import compile_cache
 
-    honor_jax_platforms()
+    compile_cache.enable()  # before the first jit; this CLI owns its mesh
 
     import jax
     import optax
@@ -472,6 +483,8 @@ def main(argv=None) -> int:
     t_start = _time.perf_counter()
     last_t, last_i = t_start, start_step
     loop_raised = False
+    losses = []
+    generated = None
     try:
         with device_trace(args.profile):
             for i in range(start_step + spl, args.steps + 1, spl):
@@ -481,6 +494,7 @@ def main(argv=None) -> int:
                 rec = None
                 if report:
                     ll = float(loss)
+                    losses.append((i, ll))
                     print(f"{i:>5} {ll:>9.4f} {ll / np.log(2):>10.4f}",
                           flush=True)
                     # throughput window closes BEFORE any eval below so
@@ -592,10 +606,14 @@ def main(argv=None) -> int:
             hits = np.flatnonzero(out[gen_start:] == args.eos_byte)
             if hits.size:
                 out = out[: gen_start + hits[0] + 1]
+        generated = out
         text = bytes(out.astype(np.uint8)).decode("utf-8", "replace")
         print(f"--- generation ({args.gen_tokens} tokens, {note}) ---")
         print(text)
-    return 0
+    return {
+        "params": params, "cfg": cfg, "mesh": mesh, "losses": losses,
+        "generated": generated,
+    }
 
 
 if __name__ == "__main__":

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ...models.convnet import cross_entropy

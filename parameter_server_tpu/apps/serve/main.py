@@ -123,9 +123,6 @@ def _decode_lane(gamma: int, models):
 
 
 def main(argv=None) -> int:
-    from ...parallel.mesh import honor_jax_platforms
-
-    honor_jax_platforms()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--num-slots", type=int, default=1 << 18)
     ap.add_argument("--key-space", type=int, default=1 << 24)

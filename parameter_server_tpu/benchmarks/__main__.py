@@ -10,9 +10,6 @@ from . import components  # noqa: F401 — populates REGISTRY
 
 
 def main(argv=None) -> int:
-    from ..parallel.mesh import honor_jax_platforms
-
-    honor_jax_platforms()  # JAX_PLATFORMS=cpu must win over the plugin
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument(
         "names",

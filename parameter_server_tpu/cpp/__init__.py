@@ -1,24 +1,61 @@
-"""ctypes loader for the native host library (``libpsnative.so``).
+"""ctypes loader for the native host library (``psnative.cc``).
 
-Builds lazily with ``make`` on first use if g++ is available; all callers
-must handle ``native() is None`` and fall back to NumPy paths. This mirrors
-the reference's split: C++ for the host data plane, accelerator code
+The library is always built on the machine that runs it: its file name
+carries a digest of the source, the build flags and this host's CPU, so
+a checkout copied from another machine (or an edited source) never
+loads a stale or foreign ``.so`` — it builds its own with ``make``, once,
+on first use. A build that fails raises :class:`NativeBuildError` with
+the compiler's output; nothing quietly falls back to NumPy. (The
+``native() is None`` branches at the call sites are the NumPy references
+the parity tests reach by swapping :func:`native` out.) This mirrors the
+reference's split: C++ for the host data plane, accelerator code
 elsewhere.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_LIB_PATH = os.path.join(_DIR, "libpsnative.so")
+_SRC = os.path.join(_DIR, "psnative.cc")
 _lock = threading.Lock()
-_lib: Optional[ctypes.CDLL] = None
-_tried = False
+_lib: Optional[ctypes.CDLL] = None  # guarded-by: _lock
+
+
+class NativeBuildError(RuntimeError):
+    """``make`` could not produce the native library; carries its output."""
+
+
+def _host_tag() -> str:
+    """What ``-march=native`` resolves against: the machine type and the
+    CPU's feature flags (Linux; empty elsewhere, where the machine type
+    alone keys the build)."""
+    flags = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    flags = line.split(":", 1)[1].strip()
+                    break
+    except OSError:
+        pass
+    return f"{platform.machine()} {flags}"
+
+
+def lib_path() -> str:
+    """Where this host's build of the current source lives."""
+    h = hashlib.sha256()
+    for path in (_SRC, os.path.join(_DIR, "Makefile")):
+        with open(path, "rb") as f:
+            h.update(f.read())
+    h.update(_host_tag().encode())
+    return os.path.join(_DIR, f"libpsnative-{h.hexdigest()[:16]}.so")
 
 
 def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -73,33 +110,33 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
-def native() -> Optional[ctypes.CDLL]:
-    """The loaded native library, building it if needed; None if unavailable."""
-    global _lib, _tried
-    if _lib is not None or _tried:
-        return _lib
+def native() -> ctypes.CDLL:
+    """The loaded native library, built from ``psnative.cc`` first if
+    this host has no build of the current source yet."""
+    global _lib
     with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        src = os.path.join(_DIR, "psnative.cc")
-        stale = os.path.exists(_LIB_PATH) and os.path.exists(src) and (
-            os.path.getmtime(src) > os.path.getmtime(_LIB_PATH)
-        )
-        if not os.path.exists(_LIB_PATH) or stale:
-            try:
-                subprocess.run(
-                    ["make", "-C", _DIR],
-                    capture_output=True,
-                    timeout=120,
-                    check=True,
-                )
-            except (OSError, subprocess.SubprocessError):
-                return None
-        try:
-            _lib = _configure(ctypes.CDLL(_LIB_PATH))
-        except (OSError, AttributeError):
-            # AttributeError: a stale .so missing newer symbols that slipped
-            # past the mtime check — honor the None contract, don't raise
-            _lib = None
+        if _lib is None:
+            path = lib_path()
+            if not os.path.exists(path):
+                _build(path)
+            _lib = _configure(ctypes.CDLL(path))
         return _lib
+
+
+def _build(path: str) -> None:
+    # built under a temporary name and renamed, so a concurrent process
+    # never loads a half-written library
+    tmp = f"{path}.{os.getpid()}.tmp"
+    cmd = ["make", "-C", _DIR, f"LIB={os.path.basename(tmp)}"]
+    try:
+        proc = subprocess.run(
+            cmd, capture_output=True, text=True, timeout=300
+        )
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeBuildError(f"could not run {' '.join(cmd)}: {e}") from e
+    if proc.returncode != 0:
+        raise NativeBuildError(
+            f"{' '.join(cmd)} exited {proc.returncode}:\n"
+            f"{proc.stdout}{proc.stderr}"
+        )
+    os.replace(tmp, path)

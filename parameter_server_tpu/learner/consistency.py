@@ -210,7 +210,7 @@ class AdaptiveTauController:
 
         worker.lr.alpha = float(worker.lr.alpha) * self.backoff_factor
         worker._steps.clear()
-        worker._weights_fn = jax.jit(worker.updater.weights)
+        worker._weights_fn = worker._make_weights_fn()
         rolled_back = False
         if self._snapshot is not None:
             # drain in-flight steps before installing old state:

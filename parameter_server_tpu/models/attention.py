@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..parallel.ring import ring_next
@@ -73,7 +73,7 @@ def ring_attention(
     causal: bool = False,
     impl: str = "xla",
     use_pallas=None,
-    interpret=None,
+    interpret=False,
     window=None,
 ) -> jax.Array:
     """Exact attention with S sharded over ``axis``. q,k,v: [B, S, H].
@@ -108,7 +108,7 @@ def ring_attention(
             "window (sliding-window attention) is implemented by the "
             "flash kernels — use impl='flash' or 'zigzag'"
         )
-    if use_pallas is not None or interpret is not None:
+    if use_pallas is not None or interpret:
         raise ValueError(
             "use_pallas/interpret only apply to impl='flash'/'zigzag'; "
             "the xla impl would silently ignore them (and you would "
@@ -317,7 +317,7 @@ def ulysses_attention(
     causal: bool = False,
     impl: str = "xla",
     use_pallas=None,
-    interpret=None,
+    interpret=False,
     window=None,
 ) -> jax.Array:
     """All-to-all (DeepSpeed-Ulysses-style) sequence parallelism: the
@@ -340,7 +340,7 @@ def ulysses_attention(
         raise ValueError(
             f"ulysses_attention impl must be 'xla' or 'flash', got {impl!r}"
         )
-    if impl == "xla" and (use_pallas is not None or interpret is not None):
+    if impl == "xla" and (use_pallas is not None or interpret):
         raise ValueError(
             "use_pallas/interpret only apply to impl='flash'; the xla "
             "impl would silently ignore them"

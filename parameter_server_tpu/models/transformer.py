@@ -633,17 +633,26 @@ def _chunked_causal_attn(q, k, v, window, chunk: int = 256):
     return out[:, :p_len]
 
 
-def _prefill_attention(q, k, v, window, use_flash=None, interpret=None):
+def _prefill_attention(q, k, v, window, use_flash=None, interpret=False):
     """Prefill attention dispatch: q [B, P, nh, hd], k/v [B, P, kvh, hd]
-    -> [B, P, nh*hd]. On TPU backends the Pallas flash kernel does the
-    O(P^2) work (MXU-shaped matmuls, O(block) VMEM, window blocks
-    skipped); elsewhere the chunked XLA path bounds transient memory.
-    ``use_flash=None`` auto-selects by backend; tests force the flash
-    path in interpret mode and compare against the chunked path."""
-    from ..ops.flash_attention import _use_pallas, flash_mha
+    -> [B, P, nh*hd]. On a one-chip TPU process the Pallas flash kernel
+    does the O(P^2) work (MXU-shaped matmuls, O(block) VMEM, window
+    blocks skipped); elsewhere the chunked XLA path bounds transient
+    memory. ``use_flash=None`` auto-selects; tests force the flash
+    path in interpret mode and compare against the chunked path.
+
+    One chip only, because the serving forwards run under plain jits
+    that GSPMD partitions when the weights are Megatron-split or
+    replicated over a mesh, and a Mosaic kernel cannot ride that
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map" — TP decode on four TPU v5 lite chips).
+    Whether the enclosing jit spans several chips is not visible at
+    trace time; the process's device count is."""
+    from ..ops import use_pallas
+    from ..ops.flash_attention import flash_mha
 
     if use_flash is None:
-        use_flash = _use_pallas()
+        use_flash = use_pallas() and jax.device_count() == 1
     if not use_flash:
         return _chunked_causal_attn(q, k, v, window)
     b, p_len, nh, hd = q.shape

@@ -3,10 +3,9 @@
 The big-table row path (``update='sparse'``, updaters.apply_state_rows)
 today runs as four separate XLA ops — gather z, gather √n, scatter z',
 scatter √n' — each a full trip through the memory system with
-intermediate row vectors materialized between them (~80 ms for 640k
-rows at 2^30 slots, 0.7–1.5% of HBM peak per BENCH_r05/BENCH_ONCHIP).
-This kernel is the IO-aware formulation (the FlashAttention lesson,
-arXiv:2205.14135): ONE pass over exactly the touched rows —
+intermediate row vectors materialized between them. This kernel is the
+IO-aware formulation (the FlashAttention lesson, arXiv:2205.14135): ONE
+pass over exactly the touched rows —
 
 - the deduped slot ids are reduced to unique 128-lane TABLE ROWS and
   scalar-prefetched (``PrefetchScalarGridSpec``), so the kernel can
@@ -28,15 +27,17 @@ contract makes every genuine (row, lane) target unique, padding and
 non-owned entries carry g = 0 and merge into real rows as pass-through
 lanes, so the kernel never needs a mask operand or a sentinel row.
 
-``sqrt_n`` may be stored bf16 (``SGDConfig.ftrl_state_dtype``): math
-widens to f32 in VMEM and the write-back narrows with STOCHASTIC
-rounding — the on-core PRNG when compiled, and on the interpret path a
-dither substitute indexed by each lane's u-position so the narrow is
-BIT-IDENTICAL to the jnp reference's position-hash dither
-(ops/ftrl.dither_hash_u32, the parity-test contract).
+The kernel covers f32 ``sqrt_n`` only. A bf16 ``sqrt_n`` table
+(``SGDConfig.ftrl_state_dtype``) cannot be moved one table row per
+DMA: Mosaic tiles a bf16 HBM ref (8,128)(2,1) and refuses the row
+slice ("Slice shape along dimension 0 must be aligned to tiling (8),
+but is 1", TPU v5 lite, jax 0.9.0), and a uint32 view of the same ref
+tiles (4,128) with the same refusal — its DMA granule is 8 table
+rows. Such tables take the XLA rows path until a kernel with an
+8-row granule exists (ROADMAP Speed 2).
 
 ``ftrl_sparse_update`` auto-selects: Pallas on TPU backends for
-tileable shapes, the XLA rows reference elsewhere (bit-identical
+tileable f32 shapes, the XLA rows reference elsewhere (bit-identical
 formulation of updaters.apply_state_rows for the FTRL/decay case).
 """
 # bit-identical: this module is under the replay bit-identity contract (pslint determinism pass)
@@ -48,15 +49,13 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from . import use_pallas
 from .ftrl import (
     _LANES,
     _TILE,
     _choose_block_rows,
     _ftrl_math,
-    _use_pallas,
-    dither_hash_u32,
     ftrl_update,
 )
 
@@ -68,24 +67,29 @@ PATH_XLA_ROWS = "xla_rows"
 PATH_REF = "ref"
 
 
-def use_sparse_kernel(p: int, u: int, bf16_n: bool, has_seed: bool,
+#: touched-row ids scalar-prefetched per kernel call. The whole vector
+#: lands in SMEM (1 MiB a core on TPU v5 lite: a 639,488-row set, the
+#: width of a 16,384 x 39-lane minibatch, asked for 2.56 MB and the
+#: compiler refused it), so wider row sets run as a scan of calls.
+_SMEM_CHUNK_ROWS = 1 << 16
+
+
+def use_sparse_kernel(p: int, u: int, bf16_n: bool,
                       force_pallas: bool) -> bool:
     """Pure path-selection predicate for the fused sparse kernel
     (testable off device): the kernel runs on TPU backends, for
-    (8,128)-tileable tables, for row counts the (8-sublane) block
-    machinery can tile, and — when √n is stored bf16 — only with a
-    seed for the stochastic narrow. Everything else falls back to the
-    XLA rows path (:func:`ftrl_sparse_rows_ref`), bit-identically.
-    ``force_pallas`` pins the kernel for A/B sweeps and interpret
-    tests, but never onto a shape it cannot tile or narrow correctly.
+    (8,128)-tileable tables with f32 ``sqrt_n`` (module docstring: the
+    compiler refuses single-row DMAs of a bf16 table), and for row
+    counts the (8-sublane) block machinery can tile. Everything else
+    falls back to the XLA rows path (:func:`ftrl_sparse_rows_ref`),
+    bit-identically. ``force_pallas`` pins the kernel for A/B sweeps
+    and interpret tests, but never onto a shape it cannot tile.
     """
-    if not force_pallas and not _use_pallas():
+    if not force_pallas and not use_pallas():
         return False
-    if p % _TILE != 0 or u < 8 or u % 8 != 0:
+    if bf16_n:
         return False
-    if bf16_n and not has_seed:
-        return False
-    return True
+    return p % _TILE == 0 and u >= 8 and u % 8 == 0
 
 
 def resolve_update_path(update_mode: str, *, on_tpu: bool, shard: int,
@@ -110,7 +114,7 @@ def resolve_update_path(update_mode: str, *, on_tpu: bool, shard: int,
     from .ftrl import _TILE, xla_min_slots
 
     if update_mode == "sparse":
-        if on_tpu and use_sparse_kernel(shard, u, bf16_n, has_seed, True):
+        if on_tpu and use_sparse_kernel(shard, u, bf16_n, True):
             return PATH_PALLAS_SPARSE
         return PATH_XLA_ROWS
     # the dense resolution mirrors ops/ftrl.use_ref_path with the
@@ -154,7 +158,7 @@ def ftrl_sparse_rows_ref(z, sqrt_n, rel, ok, g_u, *, alpha, beta, l1,
     )
 
 
-def _row_gradient(rel, ok, g_u, u: int):
+def _row_gradient(rel, ok, g_u, width: int):
     """Unique-row decomposition of the deduped slot vector (in-program,
     O(U) elementwise/scan work — small next to the row traffic it
     organizes). The ``ok`` subsequence of ``rel`` is non-decreasing
@@ -169,14 +173,12 @@ def _row_gradient(rel, ok, g_u, u: int):
     block's stale fetch WRITE BACK over the genuine update (a silent
     lost update, caught in review by exactly the -1-tail shape).
 
-    Returns ``(urows [U] int32, nrows [1] int32, g_rows [U,128] f32,
-    didx [U,128] int32)`` where ``urows[:nrows]`` are the distinct
+    Returns ``(urows [W] int32, nrows [1] int32, g_rows [W,128] f32)``
+    with ``W = width >= U``: ``urows[:nrows]`` are the distinct
     128-lane table rows touched (filler 0 past ``nrows`` — fetch-safe,
-    never written back), ``g_rows`` the per-row dense gradient (scatter
-    -ADD: genuine (row, lane) targets are unique by the slot-unique
-    contract, padding/non-owned entries add 0), and ``didx`` each
-    lane's u-position (-1 untouched) — the dither index that makes the
-    interpret-mode bf16 narrow replay the reference's position hash.
+    never written back) and ``g_rows`` the per-row dense gradient
+    (scatter-ADD: genuine (row, lane) targets are unique by the
+    slot-unique contract, padding/non-owned entries add 0).
     """
     g = jnp.where(ok, g_u, 0.0).astype(jnp.float32)
     relc = rel.astype(jnp.int32)
@@ -187,38 +189,31 @@ def _row_gradient(rel, ok, g_u, u: int):
     )
     inv = jnp.cumsum(first) - 1
     nrows = (inv[-1] + 1).reshape(1)
-    urows = jnp.zeros((u,), jnp.int32).at[inv].set(row)
-    g_rows = jnp.zeros((u, _LANES), jnp.float32).at[inv, lane].add(g)
-    didx = (
-        jnp.full((u, _LANES), -1, jnp.int32)
-        .at[inv, lane]
-        .max(jnp.where(ok, jnp.arange(u, dtype=jnp.int32), -1))
-    )
-    return urows, nrows, g_rows, didx
+    urows = jnp.zeros((width,), jnp.int32).at[inv].set(row)
+    g_rows = jnp.zeros((width, _LANES), jnp.float32).at[inv, lane].add(g)
+    return urows, nrows, g_rows
 
 
 def _grid_params(interpret: bool):
     """Sequential-grid compiler params: the double-buffer recurrence
     (scratch slots + DMA semaphores carried across grid steps) requires
-    'arbitrary' dimension semantics. Same CompilerParams /
-    TPUCompilerParams compat chain as ops/flash_attention."""
+    'arbitrary' dimension semantics."""
     if interpret:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
-    params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
     return {
-        "compiler_params": params_cls(dimension_semantics=("arbitrary",))
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        )
     }
 
 
-def _sparse_body(urows_ref, nrows_ref, z_hbm, n_hbm, g_ref, z_out, n_out,
-                 zin, nin, zco, nco, in_sem, out_sem, *, br, alpha, beta,
-                 l1, l2, narrow_fn):
-    """Shared kernel body: double-buffered row-DMA pipeline around one
-    VMEM FTRL block. Grid steps run sequentially; scratch slot b%2
+def _kernel(urows_ref, nrows_ref, z_hbm, n_hbm, g_ref, z_out, n_out,
+            zin, nin, zco, nco, in_sem, out_sem, *, br, alpha, beta, l1,
+            l2):
+    """Kernel body: double-buffered row-DMA pipeline around one VMEM
+    FTRL block. Grid steps run sequentially; scratch slot b%2
     alternates, so block b's fetch was issued at block b-1 and its
     write-back drains under block b+1's compute."""
     from jax.experimental import pallas as pl
@@ -282,11 +277,11 @@ def _sparse_body(urows_ref, nrows_ref, z_hbm, n_hbm, g_ref, z_out, n_out,
     # trailing blocks past nrows (the grid is statically sized from the
     # PADDED unique width; row-dedup shrinks the live prefix) have every
     # DMA predicated off — skip their compute too instead of running
-    # the full FTRL step (and the bf16 PRNG) on stale scratch
+    # the full FTRL step on stale scratch
     @pl.when(b * br < nrows_ref[0])
     def _():
         z = zin[slot]
-        n = nin[slot].astype(jnp.float32)
+        n = nin[slot]
         g = g_ref[:]
         z_new, n_new = _ftrl_math(z, n, g, alpha=alpha, beta=beta,
                                   l1=l1, l2=l2)
@@ -294,7 +289,7 @@ def _sparse_body(urows_ref, nrows_ref, z_hbm, n_hbm, g_ref, z_out, n_out,
         # padding/non-owned lanes carry g = 0, passing through unchanged)
         keep = g != 0
         zco[slot] = jnp.where(keep, z_new, z)
-        nco[slot] = narrow_fn(jnp.where(keep, n_new, n))
+        nco[slot] = jnp.where(keep, n_new, n)
 
     dma_pair("start", False, slot, b)
 
@@ -307,76 +302,6 @@ def _sparse_body(urows_ref, nrows_ref, z_hbm, n_hbm, g_ref, z_out, n_out,
         @pl.when(b >= 1)
         def _():
             dma_pair("wait", False, nxt, b - 1)
-
-
-def _kernel_f32(urows_ref, nrows_ref, z_hbm, n_hbm, g_ref, z_out, n_out,
-                zin, nin, zco, nco, in_sem, out_sem, *, br, alpha, beta,
-                l1, l2):
-    _sparse_body(
-        urows_ref, nrows_ref, z_hbm, n_hbm, g_ref, z_out, n_out,
-        zin, nin, zco, nco, in_sem, out_sem,
-        br=br, alpha=alpha, beta=beta, l1=l1, l2=l2,
-        narrow_fn=lambda x: x,
-    )
-
-
-def _kernel_bf16(urows_ref, nrows_ref, seed_ref, z_hbm, n_hbm, g_ref,
-                 z_out, n_out, zin, nin, zco, nco, in_sem, out_sem, *,
-                 br, alpha, beta, l1, l2):
-    """bf16-``sqrt_n`` compiled variant: stochastic f32→bf16 narrow
-    with the on-core PRNG, per-block stream (block-correlated rounding
-    noise is biased in aggregate — ops/quantize.py note). An
-    already-bf16-exact value (untouched lanes) is unchanged by
-    construction (low mantissa bits zero)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def narrow(x):
-        pltpu.prng_seed(seed_ref[0] + pl.program_id(0))
-        rnd = pltpu.bitcast(pltpu.prng_random_bits(x.shape), jnp.uint32)
-        bits = pltpu.bitcast(x, jnp.uint32)
-        rounded = (bits + (rnd & jnp.uint32(0xFFFF))) & jnp.uint32(
-            0xFFFF0000
-        )
-        return pltpu.bitcast(rounded, jnp.float32).astype(jnp.bfloat16)
-
-    _sparse_body(
-        urows_ref, nrows_ref, z_hbm, n_hbm, g_ref, z_out, n_out,
-        zin, nin, zco, nco, in_sem, out_sem,
-        br=br, alpha=alpha, beta=beta, l1=l1, l2=l2, narrow_fn=narrow,
-    )
-
-
-def _kernel_bf16_dither(urows_ref, nrows_ref, seed_ref, z_hbm, n_hbm,
-                        g_ref, didx_ref, z_out, n_out, zin, nin, zco,
-                        nco, in_sem, out_sem, *, br, alpha, beta, l1,
-                        l2):
-    """bf16 interpret-mode variant: ``pltpu.prng_*`` has no CPU
-    lowering, so the narrow dithers from :func:`dither_hash_u32`
-    indexed by each lane's u-position (``didx``) — the SAME
-    (index, seed) stream the jnp reference draws over the gathered
-    row vector, which is what makes the parity test BIT-exact. The
-    extra [U, 128] index operand only exists on this path; the
-    compiled kernel uses the PRNG above and ships no index."""
-
-    def narrow(x):
-        rnd = dither_hash_u32(
-            didx_ref[:].astype(jnp.uint32),
-            seed_ref[0].astype(jnp.uint32),
-        )
-        bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
-        rounded = (bits + (rnd & jnp.uint32(0xFFFF))) & jnp.uint32(
-            0xFFFF0000
-        )
-        return jax.lax.bitcast_convert_type(
-            rounded, jnp.float32
-        ).astype(jnp.bfloat16)
-
-    _sparse_body(
-        urows_ref, nrows_ref, z_hbm, n_hbm, g_ref, z_out, n_out,
-        zin, nin, zco, nco, in_sem, out_sem,
-        br=br, alpha=alpha, beta=beta, l1=l1, l2=l2, narrow_fn=narrow,
-    )
 
 
 def _sparse_block_rows(u: int, requested: "int | None" = None) -> int:
@@ -393,6 +318,58 @@ def _sparse_block_rows(u: int, requested: "int | None" = None) -> int:
         except ValueError:
             requested = 512
     return _choose_block_rows(u, requested)
+
+
+def _fused_rows_call(z2d, n2d, urows, nrows, g_rows, *, br, alpha, beta,
+                     l1, l2, interpret):
+    """One kernel launch over ``urows.shape[0]`` candidate rows (at
+    most :data:`_SMEM_CHUNK_ROWS`): ``urows``/``nrows`` are
+    scalar-prefetched, ``g_rows`` streams through VMEM in ``br``-row
+    blocks, and the z/sqrt_n tables stay in HBM and are updated in
+    place."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    any_spec = lambda: pl.BlockSpec(memory_space=pl.ANY)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(urows.shape[0] // br,),
+        in_specs=[
+            any_spec(),
+            any_spec(),
+            pl.BlockSpec(
+                (br, _LANES), lambda i, *_: (i, 0), memory_space=pltpu.VMEM
+            ),
+        ],
+        out_specs=(any_spec(), any_spec()),
+        scratch_shapes=[
+            pltpu.VMEM((2, br, _LANES), jnp.float32),       # z fetch
+            pltpu.VMEM((2, br, _LANES), jnp.float32),       # n fetch
+            pltpu.VMEM((2, br, _LANES), jnp.float32),       # z compute
+            pltpu.VMEM((2, br, _LANES), jnp.float32),       # n compute
+            pltpu.SemaphoreType.DMA((2, 2)),                # fetch sems
+            pltpu.SemaphoreType.DMA((2, 2)),                # write sems
+        ],
+    )
+    # z/sqrt_n update IN PLACE: without the alias the call materializes
+    # fresh z'/n' buffers next to the live table. Alias indices count
+    # the scalar-prefetch operands first. Every touched row is read
+    # (fetch) strictly before its write-back is issued, and rows are
+    # unique across the grid, so the pipeline never observes its own
+    # output.
+    return pl.pallas_call(
+        functools.partial(
+            _kernel, br=br, alpha=alpha, beta=beta, l1=l1, l2=l2
+        ),
+        grid_spec=grid_spec,
+        out_shape=(
+            jax.ShapeDtypeStruct(z2d.shape, z2d.dtype),
+            jax.ShapeDtypeStruct(n2d.shape, n2d.dtype),
+        ),
+        input_output_aliases={2: 0, 3: 1},
+        interpret=interpret,
+        **_grid_params(interpret),
+    )(urows, nrows, z2d, n2d, g_rows)
 
 
 # no-donate: the public z/n entry point is used by parity tests and the
@@ -438,90 +415,54 @@ def ftrl_sparse_update(
     trip of 128-lane rows: ~1 KB fetched + ~1 KB written per distinct
     touched row (z + f32 √n) plus the in-program [U, 128] gradient
     scatter — against the XLA rows path's four separate gather/scatter
-    dispatches. ``seed`` (traced uint32) drives the stochastic bf16
-    narrow; ``block_rows`` tiles the row axis (default 512, env
+    dispatches. Row sets wider than :data:`_SMEM_CHUNK_ROWS` run as a
+    scan of kernel launches over equal chunks (rows are unique across
+    the whole set, so the chunks are independent). ``seed`` only
+    reaches the XLA rows path (the stochastic bf16 narrow);
+    ``block_rows`` tiles the row axis (default 512, env
     ``PS_FTRL_SPARSE_BLOCK_ROWS`` — baked at first trace like the
     dense kernel's knob).
 
     Falls back to :func:`ftrl_sparse_rows_ref` off-TPU and for shapes
-    the kernel cannot tile (``use_sparse_kernel``), so any caller can
-    use it unconditionally.
+    the kernel does not cover (``use_sparse_kernel``), so any caller
+    can use it unconditionally.
     """
     p = z.shape[0]
     u = rel.shape[0]
-    bf16_n = sqrt_n.dtype == jnp.bfloat16
     if z.ndim != 1 or not use_sparse_kernel(
-        p, u, bf16_n, seed is not None, force_pallas
+        p, u, sqrt_n.dtype == jnp.bfloat16, force_pallas
     ):
         return ftrl_sparse_rows_ref(
             z, sqrt_n, rel, ok, g_u,
             alpha=alpha, beta=beta, l1=l1, l2=l2, seed=seed,
         )
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    table_rows = p // _LANES
-    shape2d = (table_rows, _LANES)
-    br = _sparse_block_rows(u, block_rows)
-    urows, nrows, g_rows, didx = _row_gradient(rel, ok, g_u, u)
-
-    blocked = lambda: pl.BlockSpec(  # noqa: E731 — per-spec instance
-        (br, _LANES), lambda i, *_: (i, 0), memory_space=pltpu.VMEM
-    )
-    any_spec = lambda: pl.BlockSpec(memory_space=pltpu.ANY)  # noqa: E731
-    operands = [z.reshape(shape2d), sqrt_n.reshape(shape2d), g_rows]
-    in_specs = [any_spec(), any_spec(), blocked()]
-    n_prefetch = 2
-    prefetch = [urows, nrows]
-    if bf16_n:
-        n_prefetch = 3
-        prefetch.append(jnp.asarray(seed, jnp.int32).reshape(1))
-        if interpret:
-            kernel = functools.partial(
-                _kernel_bf16_dither, br=br, alpha=alpha, beta=beta,
-                l1=l1, l2=l2,
-            )
-            operands.append(didx)
-            in_specs.append(blocked())
-        else:
-            kernel = functools.partial(
-                _kernel_bf16, br=br, alpha=alpha, beta=beta, l1=l1,
-                l2=l2,
-            )
-    else:
-        kernel = functools.partial(
-            _kernel_f32, br=br, alpha=alpha, beta=beta, l1=l1, l2=l2,
-        )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(u // br,),
-        in_specs=in_specs,
-        out_specs=(any_spec(), any_spec()),
-        scratch_shapes=[
-            pltpu.VMEM((2, br, _LANES), jnp.float32),       # z fetch
-            pltpu.VMEM((2, br, _LANES), sqrt_n.dtype),      # n fetch
-            pltpu.VMEM((2, br, _LANES), jnp.float32),       # z compute
-            pltpu.VMEM((2, br, _LANES), sqrt_n.dtype),      # n compute
-            pltpu.SemaphoreType.DMA((2, 2)),                # fetch sems
-            pltpu.SemaphoreType.DMA((2, 2)),                # write sems
-        ],
-    )
-    # z/sqrt_n update IN PLACE: without the alias the call materializes
-    # fresh z'/n' buffers next to the live table — at 2^30 slots that
-    # extra 8 GB is the difference between one chip holding the table
-    # or RESOURCE_EXHAUSTED. Alias indices count the scalar-prefetch
-    # operands first. Every touched row is read (fetch) strictly before
-    # its write-back is issued, and rows are unique across the grid, so
-    # the pipeline never observes its own output.
-    z_new, n_new = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct(shape2d, z.dtype),
-            jax.ShapeDtypeStruct(shape2d, sqrt_n.dtype),
-        ),
-        input_output_aliases={n_prefetch: 0, n_prefetch + 1: 1},
+    shape2d = (p // _LANES, _LANES)
+    chunk = min(u, _SMEM_CHUNK_ROWS)
+    n_chunks = -(-u // chunk)
+    br = _sparse_block_rows(chunk, block_rows)
+    # filler rows past ``nrows`` move no bytes, so padding the row set
+    # to whole chunks costs only the zeroed tail of g_rows
+    urows, nrows, g_rows = _row_gradient(rel, ok, g_u, n_chunks * chunk)
+    call = functools.partial(
+        _fused_rows_call, br=br, alpha=alpha, beta=beta, l1=l1, l2=l2,
         interpret=interpret,
-        **_grid_params(interpret),
-    )(*prefetch, *operands)
+    )
+    tables = (z.reshape(shape2d), sqrt_n.reshape(shape2d))
+    if n_chunks == 1:
+        z_new, n_new = call(*tables, urows, nrows, g_rows)
+    else:
+        def body(carry, xs):
+            rows_c, start, g_c = xs
+            live = jnp.clip(nrows - start, 0, chunk)
+            return call(*carry, rows_c, live, g_c), None
+
+        (z_new, n_new), _ = jax.lax.scan(
+            body,
+            tables,
+            (
+                urows.reshape(n_chunks, chunk),
+                jnp.arange(n_chunks, dtype=jnp.int32) * chunk,
+                g_rows.reshape(n_chunks, chunk, _LANES),
+            ),
+        )
     return z_new.reshape(p), n_new.reshape(p)

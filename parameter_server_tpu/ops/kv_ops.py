@@ -42,9 +42,8 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.compat import shard_map
 
 from ..parallel.mesh import DATA_AXIS, SERVER_AXIS
 from ..parallel.partition import BATCH_SPEC, REPLICATED_SPEC, TABLE_SPEC

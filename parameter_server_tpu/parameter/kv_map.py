@@ -28,7 +28,7 @@ import numpy as np
 
 from ..utils import file as psfile
 
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..ops.kv_ops import localize

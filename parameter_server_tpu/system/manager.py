@@ -112,5 +112,14 @@ class Manager:
             self.nodes.append(Node(Node.WORKER, i))
 
     def stop(self) -> None:
+        """Drop the registry and stop every customer's executor (ref
+        Manager::Stop). Joining the dispatch threads matters beyond
+        tidiness: a parked dispatch loop's frame still holds the last
+        step it ran, and through that closure the app and its device
+        state — a 2^30 table stayed allocated (12.99 GB in use) after
+        the trainer CLI had returned."""
         with self._lock:
+            customers = list(self._customers.values())
             self._customers.clear()
+        for c in customers:
+            c.executor.stop()

@@ -7,7 +7,7 @@ a timeline maps to one of eight categories:
 
     host_prep       parse/localize/remap/stack on host CPU
     encode          compact-wire encode (learner/wire.py, prep pool)
-    upload          host→device staging (the tunnel/link wire time)
+    upload          host→device staging (the link's wire time)
     network         host-wire frames between nodes (Van.transfer — the
                     control-plane/metric-report wire legs; distinct
                     from ``upload``, the host→device link)

@@ -35,13 +35,15 @@ first-class:
 
 Dispatch semantics: the wrapper maintains its own signature →
 ``Compiled`` cache and calls the compiled executable directly, so
-instrumentation adds no second compile. The original jitted callable
-is kept as the safety net: calls whose signature cannot be read
-(foreign leaf types), tracer-stage calls (the function inlined inside
-an enclosing jit), and compiled-dispatch failures (e.g. a sharding the
-lowering was not specialized for) all fall through to the plain jit
-path bit-identically, counted under
-``ps_device_dispatch_fallbacks_total{fn}``. Statics must be passed as
+instrumentation adds no second compile. The original callable only
+takes calls the inventory cannot describe: a signature that cannot be
+read (foreign leaf types) or a plain Python callable that is no jit,
+both counted under ``ps_device_dispatch_fallbacks_total{fn}``, and
+tracer-stage calls (the function inlined inside an enclosing jit,
+which owns the compile). A refused lowering, a failed compile or a
+failed compiled dispatch RAISES: dropping to the plain jit would
+compile the step a second time and hide the refusal behind a counter.
+Statics must be passed as
 keyword arguments at instrumented call sites (true for every wrap
 point: ops/kv_ops, ops jit entry points, the async_sgd step builders).
 
@@ -52,6 +54,7 @@ documents how to read it.
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 import warnings
@@ -181,6 +184,17 @@ def _memory_dict(compiled) -> Optional[Dict[str, int]]:
         return None
 
 
+def _custom_calls(compiled) -> Tuple[str, ...]:
+    """The distinct ``custom_call_target`` names in a compiled
+    executable's text — what says whether a Pallas kernel
+    (``tpu_custom_call``) or an XLA reference made it into the program."""
+    import re
+
+    return tuple(sorted(set(
+        re.findall(r'custom_call_target="([^"]+)"', compiled.as_text())
+    )))
+
+
 def aot_analyze(jit_fn, *args, **kwargs) -> Optional[Dict[str, Any]]:
     """One-shot AOT analysis of a jitted callable at concrete args:
     ``{"flops", "bytes_accessed", "argument_bytes", ..., "donation_
@@ -215,7 +229,7 @@ class _FnRecord:
     __slots__ = (
         "name", "compiles", "recompiles", "donation_fallbacks",
         "dispatch_fallbacks", "calls", "cost", "memory",
-        "donated_bytes", "last_timing",
+        "donated_bytes", "last_timing", "custom_calls",
     )
 
     def __init__(self, name: str):
@@ -229,6 +243,7 @@ class _FnRecord:
         self.memory: Optional[Dict[str, int]] = None      # latest compile
         self.donated_bytes = 0                            # latest compile
         self.last_timing: Optional[Dict[str, Any]] = None # latest sample
+        self.custom_calls: Tuple[str, ...] = ()           # latest compile
 
     def as_dict(self) -> Dict[str, Any]:
         out: Dict[str, Any] = {
@@ -245,6 +260,8 @@ class _FnRecord:
             out["memory"] = dict(self.memory)
         if self.donated_bytes:
             out["donated_bytes"] = self.donated_bytes
+        if self.custom_calls:
+            out["custom_calls"] = list(self.custom_calls)
         if self.last_timing:
             out["roofline"] = dict(self.last_timing)
         return out
@@ -435,13 +452,15 @@ class DeviceInventory:
 
             compiled = cache.get(key)
             if compiled is None:
+                if not hasattr(fn, "lower"):
+                    # a plain callable, not a jit: nothing to compile
+                    # or verify — route to it untouched, counted
+                    self._count_fallback(name)
+                    return fn(*args, **kwargs)
                 compiled = self._compile(
                     name, cache, rec_box, key, fn, lower_args, lower_kwargs,
                     donate,
                 )
-                if compiled is None:  # lowering failed: plain jit path
-                    self._count_fallback(name)
-                    return fn(*args, **kwargs)
 
             rec_sample = False
             if rec_box:
@@ -449,20 +468,16 @@ class DeviceInventory:
                 rec.calls += 1  # benign GIL race: advisory counter
                 se = self._sample_every
                 rec_sample = se > 0 and rec.calls % se == 0
-            try:
-                if rec_sample:
-                    t0 = time.perf_counter()
-                    out = compiled(*dyn_args, **dyn_kwargs)
-                    jax.block_until_ready(out)
-                    self._observe_timing(name, time.perf_counter() - t0)
-                    return out
-                return compiled(*dyn_args, **dyn_kwargs)
-            except Exception:
-                # sharding/layout the lowering was not specialized for,
-                # or a donated buffer already consumed: the plain jit
-                # path owns every edge case
-                self._count_fallback(name)
-                return fn(*args, **kwargs)
+            # a failed dispatch raises: re-running it through the plain
+            # jit would compile the step a second time and then fail
+            # the same way (or hide a sharding the cache key missed)
+            if rec_sample:
+                t0 = time.perf_counter()
+                out = compiled(*dyn_args, **dyn_kwargs)
+                jax.block_until_ready(out)
+                self._observe_timing(name, time.perf_counter() - t0)
+                return out
+            return compiled(*dyn_args, **dyn_kwargs)
 
         wrapper.__name__ = f"instrumented_{name}"
         wrapper.__qualname__ = wrapper.__name__
@@ -475,27 +490,34 @@ class DeviceInventory:
     def _compile(self, name, cache, rec_box, key, fn, args, kwargs, donate):
         import jax
 
-        try:
-            # compiles run outside any lock and capture NO warnings
-            # state: warnings.catch_warnings mutates process-global
-            # filters and is not thread-safe, so two concurrent
-            # compiles could cross-attribute the donation warning —
-            # the alias-bytes comparison below is the deterministic
-            # signal and subsumes it (an unusable donation aliases
-            # fewer bytes than were donated)
-            compiled = fn.lower(*args, **kwargs).compile()
-        except Exception:
-            return None
+        # compiles run outside any lock and capture NO warnings state:
+        # warnings.catch_warnings mutates process-global filters and is
+        # not thread-safe, so two concurrent compiles could
+        # cross-attribute the donation warning — the alias-bytes
+        # comparison below is the deterministic signal and subsumes it
+        # (an unusable donation aliases fewer bytes than were donated).
+        # A refused lowering or compile raises here, once, with the
+        # compiler's own message.
+        compiled = fn.lower(*args, **kwargs).compile()
         cost = _cost_dict(compiled)
         memory = _memory_dict(compiled)
+        custom_calls = _custom_calls(compiled)
+        # per DEVICE, like memory_analysis(): a table sharded over the
+        # server axis donates one shard's bytes on each chip, and
+        # counting the global array would flag every sharded donation
+        # as a fallback
         donated_bytes = 0
         for i in donate:
             if i < len(args):
                 for leaf in jax.tree_util.tree_leaves(args[i]):
                     try:
                         aval = jax.api_util.shaped_abstractify(leaf)
+                        shape = aval.shape
+                        sharding = getattr(leaf, "sharding", None)
+                        if sharding is not None:
+                            shape = sharding.shard_shape(shape)
                         donated_bytes += int(
-                            aval.size * aval.dtype.itemsize
+                            math.prod(shape) * aval.dtype.itemsize
                         )
                     except Exception:
                         pass
@@ -518,6 +540,7 @@ class DeviceInventory:
                 rec.donation_fallbacks += 1
             rec.cost = cost
             rec.memory = memory
+            rec.custom_calls = custom_calls
             rec.donated_bytes = donated_bytes
         if tel is not None:
             tel["compiles"].labels(fn=name).inc()

@@ -417,7 +417,7 @@ def iter_on_thread(it, maxsize: int, close_join_s: float = 2.5):
     interpreter teardown (observed as 'terminate called / FATAL:
     exception not rethrown' from inside a jax device call). The join
     is bounded by ``close_join_s``: a producer wedged inside the
-    SOURCE iterator itself (a stuck read, a wedged tunnel transfer)
+    SOURCE iterator itself (a stuck read, a stalled device transfer)
     cannot be interrupted from here, and close() must not hold up the
     consumer's own error propagation waiting for it."""
     q: "queue.Queue" = queue.Queue(maxsize=maxsize)

@@ -5,9 +5,7 @@ The reference's tracing story is host-side counters
 equivalent visibility tool is an XLA device trace — per-op device
 timelines, HBM traffic, and fusion boundaries — captured with
 ``jax.profiler`` and viewed in TensorBoard's profile plugin or
-Perfetto. This module wraps it behind a no-op-on-failure surface so
-profiling can be wired into production CLIs (LM ``--profile``) without
-making the profiler a hard dependency of training.
+Perfetto. ``device_trace`` is wired into the CLIs' ``--profile`` flag.
 """
 
 from __future__ import annotations
@@ -20,32 +18,22 @@ from typing import Iterator
 def device_trace(log_dir: str | None) -> Iterator[None]:
     """Capture a device trace of the enclosed block into ``log_dir``.
 
-    Output is TensorBoard-profile/Perfetto format. ``None`` is a no-op,
-    so callers can pass an optional CLI flag straight through. A
-    profiler that fails to start (unsupported backend, double-start)
-    degrades to a warning, never a crashed training run."""
+    Output is TensorBoard-profile format: an ``.xplane.pb`` under
+    ``<log_dir>/plugins/profile/<time>/`` that
+    ``jax.profiler.ProfileData.from_file`` reads with nothing but jax.
+    ``None`` is a no-op, so callers can pass an optional CLI flag
+    straight through. A profiler that does not start or stop raises: a
+    run asked to produce a trace must not finish without one."""
     if not log_dir:
         yield
         return
     import jax
 
-    try:
-        jax.profiler.start_trace(log_dir)
-    except Exception as e:  # pragma: no cover - backend-dependent
-        import warnings
-
-        warnings.warn(f"device trace not started: {e!r}")
-        yield
-        return
+    jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:  # pragma: no cover
-            import warnings
-
-            warnings.warn(f"device trace not stopped cleanly: {e!r}")
+        jax.profiler.stop_trace()
 
 
 def annotate(name: str):
@@ -74,9 +62,11 @@ _PHASE_PREFIXES = (
 
 def _trace_files(log_dir: str) -> "list[str]":
     """Trace files of the NEWEST profiler run only: jax.profiler writes
-    each capture under ``<dir>/plugins/profile/<timestamp>/``, and a
-    reused dir (the watcher's fixed /tmp path) accumulates runs — mixing
-    them would sum device time across captures."""
+    each capture under ``<dir>/plugins/profile/<timestamp>/`` (jax
+    0.9.0, on a CPU and on a TPU alike: ``<host>.xplane.pb`` and a
+    Chrome-trace ``<host>.trace.json.gz``, the file read here), and a
+    reused dir accumulates runs — mixing them would sum device time
+    across captures."""
     import glob
     import os
 

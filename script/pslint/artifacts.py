@@ -19,9 +19,8 @@ Finding sub-rules (suppression keys):
 - ``bench-wiring`` — every benchmark key the Makefile invokes
   (``python -m parameter_server_tpu.benchmarks <key>``) must exist in
   the ``@benchmark("<key>")`` REGISTRY; every REGISTRY key must be
-  referenced somewhere (Makefile, ``script/onchip.py``, or
-  ``tests/test_benchmarks.py``) so registered benchmarks cannot become
-  unreachable dead code;
+  referenced somewhere (Makefile or ``tests/test_benchmarks.py``) so
+  registered benchmarks cannot become unreachable dead code;
 - ``metadata-section`` — every name in ``script/bench_diff.py``'s
   ``METADATA_SECTIONS`` must appear as a string constant in the bench
   record producers (``bench.py`` / ``benchmarks/components.py``): a
@@ -258,7 +257,7 @@ class CrossArtifactRule(Rule):
                     )
         # reverse direction: a REGISTRY key nothing references is dead
         ref_texts = [mk_text]
-        for rel in ("script/onchip.py", "tests/test_benchmarks.py"):
+        for rel in ("tests/test_benchmarks.py",):
             try:
                 with open(
                     os.path.join(root, rel), "r", encoding="utf-8"
@@ -276,7 +275,7 @@ class CrossArtifactRule(Rule):
                         line,
                         "bench-wiring",
                         f"benchmark '{key}' is registered but referenced "
-                        "by no Makefile target, script/onchip.py, or "
+                        "by no Makefile target or "
                         "tests/test_benchmarks.py — unreachable "
                         "registration",
                     )

@@ -3,18 +3,15 @@
 Builds the C++ host library (crc32c, hashing, text parsers) as part of the
 package; pure-stdlib build so no pip installs are needed.
 
-    python setup.py build_native   # or: make -C parameter_server_tpu/cpp
+    python setup.py build_native   # or: make native
     pip install -e .               # optional editable install
 """
-
-import subprocess
-from pathlib import Path
 
 from setuptools import Command, find_packages, setup
 
 
 class BuildNative(Command):
-    description = "build the C++ host library (libpsnative.so)"
+    description = "build the C++ host library (the loader's own build)"
     user_options = []
 
     def initialize_options(self):
@@ -24,8 +21,12 @@ class BuildNative(Command):
         pass
 
     def run(self):
-        cpp = Path(__file__).parent / "parameter_server_tpu" / "cpp"
-        subprocess.run(["make", "-C", str(cpp)], check=True)
+        # through the loader, which names the library after its source,
+        # flags and this host's CPU (a bare `make -C cpp` builds a file
+        # nothing loads)
+        from parameter_server_tpu.cpp import native
+
+        native()
 
 
 setup(

@@ -16,8 +16,6 @@ os.environ.setdefault("JAX_ENABLE_X64", "0")
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
@@ -36,23 +34,12 @@ def rng():
 
 
 def require_native(symbol: str = None):
-    """The ONE require-or-skip gate for native-library tests: returns
-    the loaded libpsnative handle, skipping gracefully when it (or the
-    named ``symbol``) is absent — unless PS_REQUIRE_NATIVE=1 (`make
-    native-test`), which turns the skip into a loud failure."""
+    """The loaded libpsnative handle for native-vs-NumPy parity tests.
+    The loader builds it on first use and raises with the compiler's
+    output when it cannot; a build that lacks ``symbol`` fails here."""
     from parameter_server_tpu.cpp import native
 
     lib = native()
-    missing = lib is None or (
-        symbol is not None and getattr(lib, symbol, None) is None
-    )
-    if missing:
-        what = f"libpsnative.so ({symbol})" if symbol else "libpsnative.so"
-        if os.environ.get("PS_REQUIRE_NATIVE"):
-            pytest.fail(
-                f"PS_REQUIRE_NATIVE=1 but {what} is unavailable — run "
-                "`make native` (the tier-1 suite skips gracefully; this "
-                "environment promised the library)"
-            )
-        pytest.skip(f"{what} unavailable (graceful tier-1 skip)")
+    if symbol is not None and getattr(lib, symbol, None) is None:
+        pytest.fail(f"libpsnative has no symbol {symbol}")
     return lib

@@ -86,13 +86,11 @@ class TestRoundtrip:
             codec.decompress(bytes([9]) + b"zz")  # unknown tag
         with pytest.raises(ValueError):
             codec.decompress(bytes([2]) + b"notzlib")
-        if native() is not None:
-            # truncated LZ: token promises literals that aren't there
-            with pytest.raises(ValueError):
-                codec.decompress(bytes([1, 0xF0, 255, 255]))
+        # truncated LZ: token promises literals that aren't there
+        with pytest.raises(ValueError):
+            codec.decompress(bytes([1, 0xF0, 255, 255]))
 
 
-@pytest.mark.skipif(native() is None, reason="native lib unavailable")
 class TestNativeEdges:
     def test_incompressible_stays_raw(self):
         rng = np.random.default_rng(2)
@@ -108,7 +106,7 @@ class TestNativeEdges:
         assert len(frame) < g.nbytes // 10
 
 
-def _require_or_skip_native():
+def _require_native():
     from conftest import require_native
 
     return require_native()
@@ -137,7 +135,7 @@ class TestHostileBuffers:
             assert len(frame) <= len(data) + 1 + len(data) // 255 + 16
 
     def test_native_path(self):
-        _require_or_skip_native()
+        _require_native()
         self._roundtrip_all()
 
     def test_zlib_fallback_path(self, monkeypatch):
@@ -155,7 +153,7 @@ class TestHostileBuffers:
         more output than max_size must raise, not allocate the claim:
         the grow loop is capped at max_size (the >4GB header edge,
         scaled down — the code path is the same -2/grow/cap one)."""
-        _require_or_skip_native()
+        _require_native()
         # token: 4 literals + match-len 15 (extensions follow); then
         # literals, offset=1, and a run of 255-extensions claiming ~2MB
         frame = bytes([1, (4 << 4) | 15]) + b"abcd" + bytes([1, 0]) + (

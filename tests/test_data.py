@@ -87,8 +87,6 @@ class TestGoldenLines:
             lines.append(f"{rng.integers(0, 2)}\t" + "\t".join(ints) + "\t" + "\t".join(cats))
         py = parse_criteo(lines)
         cc = _parse_native(("\n".join(lines) + "\n").encode(), "ps_parse_criteo", 60)
-        if cc is None:  # no native lib in this environment
-            return
         np.testing.assert_array_equal(py.indices, cc.indices)
         np.testing.assert_array_equal(py.indptr, cc.indptr)
         np.testing.assert_array_equal(py.y, cc.y)

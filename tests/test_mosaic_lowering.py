@@ -5,8 +5,12 @@ first contact with the chip they failed Mosaic's (8, 128) block-tiling
 check — exactly the class of bug the interpreter cannot catch.
 ``jax.export`` with ``platforms=['tpu']`` runs the full Pallas->Mosaic
 lowering pipeline on the CPU host, so every kernel variant is lowered for
-TPU in CI. This does not execute anything on a TPU (backend compile/run
-is covered by script/onchip.py); it pins the lowering contract.
+TPU in CI. This does not execute anything on a TPU, and it stops short
+of the chip's own compiler: the bf16 row DMA of the sparse FTRL kernel
+lowered here for months and was refused on a TPU v5 lite ("Slice shape
+along dimension 0 must be aligned to tiling (8), but is 1"). Backend
+compile and run are chip_smoke.py's job; this pins the lowering
+contract.
 """
 
 import jax
@@ -20,10 +24,6 @@ from parameter_server_tpu.ops.quantize import quantize
 
 
 def lower_tpu(fn, *args):
-    # jax 0.4.x only materializes jax.export on explicit submodule
-    # import (same shim as test_ops)
-    import jax.export  # noqa: F401
-
     jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
 
 
@@ -167,21 +167,22 @@ def test_ftrl_sparse_kernel_lowers():
     lower_tpu(fn, Z(p), Z(p), Z(u, jnp.int32), Z(u, jnp.bool_), Z(u))
 
 
-def test_ftrl_sparse_bf16_kernel_lowers():
-    """bf16-sqrt_n sparse variant: on-core PRNG stochastic narrow +
-    bf16 row DMAs (256 B) next to the f32 z rows."""
+def test_ftrl_sparse_chunked_kernel_lowers(monkeypatch):
+    """Past one SMEM chunk of row ids the kernel runs inside a scan
+    over equal chunks: the aliased in-place call must lower as a scan
+    body too."""
+    from parameter_server_tpu.ops import ftrl_sparse
+
+    monkeypatch.setattr(ftrl_sparse, "_SMEM_CHUNK_ROWS", 256)
     p, u = 1 << 14, 1024
 
-    def fn(z, n, rel, ok, g, seed):
+    def fn(z, n, rel, ok, g):
         return ftrl_sparse_update(
             z, n, rel, ok, g, alpha=0.1, beta=1.0, l1=1.0, l2=0.1,
-            seed=seed, force_pallas=True,
+            force_pallas=True, block_rows=64,
         )
 
-    lower_tpu(
-        fn, Z(p), Z(p, jnp.bfloat16), Z(u, jnp.int32), Z(u, jnp.bool_),
-        Z(u), jnp.uint32(3),
-    )
+    lower_tpu(fn, Z(p), Z(p), Z(u, jnp.int32), Z(u, jnp.bool_), Z(u))
 
 
 def test_ftrl_sparse_donated_step_lowers():
@@ -194,8 +195,6 @@ def test_ftrl_sparse_donated_step_lowers():
             z, n, rel, ok, g, alpha=0.1, beta=1.0, l1=1.0, l2=0.1,
             force_pallas=True,
         )
-
-    import jax.export  # noqa: F401
 
     jax.export.export(jax.jit(fn, donate_argnums=(0, 1)), platforms=["tpu"])(
         Z(p), Z(p), Z(u, jnp.int32), Z(u, jnp.bool_), Z(u)

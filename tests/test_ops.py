@@ -185,10 +185,6 @@ class TestFtrlOp:
         # (b) lowering contract, f32 and bf16-state variants
         import re
 
-        # jax 0.4.x only materializes jax.export on explicit submodule
-        # import (lazy attr access raises AttributeError)
-        import jax.export  # noqa: F401
-
         for n_in, seed in ((n, None), (n.astype(jnp.bfloat16), 7)):
             exp = jax.export.export(
                 jax.jit(lambda z, n, g: ftrl_update(
@@ -292,7 +288,7 @@ def test_ftrl_path_selection_predicate(monkeypatch):
     run (misaligned tile, unseeded bf16 narrow)."""
     from parameter_server_tpu.ops import ftrl
 
-    monkeypatch.setattr(ftrl, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ftrl, "use_pallas", lambda: True)
     assert not ftrl.use_ref_path(1 << 20, False, False, False)
     assert not ftrl.use_ref_path(1 << 28, False, False, False)
     assert not ftrl.use_ref_path(1 << 30, True, True, False)
@@ -300,10 +296,10 @@ def test_ftrl_path_selection_predicate(monkeypatch):
     assert ftrl.use_ref_path((1 << 20) + 8, False, False, True)  # tile
     assert ftrl.use_ref_path(1 << 20, True, False, True)  # unseeded bf16
     # off-TPU always ref unless forced
-    monkeypatch.setattr(ftrl, "_use_pallas", lambda: False)
+    monkeypatch.setattr(ftrl, "use_pallas", lambda: False)
     assert ftrl.use_ref_path(1 << 20, False, False, False)
     # env override enables the flip for crossover sweeps
-    monkeypatch.setattr(ftrl, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ftrl, "use_pallas", lambda: True)
     monkeypatch.setenv("PS_FTRL_XLA_MIN_SLOTS", str(1 << 16))
     assert ftrl.use_ref_path(1 << 16, False, False, False)
     assert not ftrl.use_ref_path(1 << 15, False, False, False)

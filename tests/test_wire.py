@@ -830,7 +830,7 @@ class TestStreamWireParity:
         assert wire.tree_nbytes(enc) < wire.tree_nbytes(bits)
 
 
-def _native_or_skip():
+def _native_stream_encode():
     from conftest import require_native
 
     return require_native("ps_stream_encode")
@@ -839,9 +839,7 @@ def _native_or_skip():
 class TestNativeFusedPrep:
     """C-vs-Python fused unique+remap+encode parity: the native one-
     pass ps_stream_encode must be BYTE-IDENTICAL to the NumPy path on
-    the committed ingest fixture's key stream. Skips gracefully when
-    the library is absent (tier-1 on a bare checkout); `make
-    native-test` sets PS_REQUIRE_NATIVE=1 to fail loudly instead."""
+    the committed ingest fixture's key stream."""
 
     NUM_SLOTS = 1 << 18
     LANES = 8
@@ -874,7 +872,7 @@ class TestNativeFusedPrep:
     def test_byte_identical_on_ingest_fixture(self):
         from parameter_server_tpu.utils.murmur import hash_slots
 
-        _native_or_skip()
+        _native_stream_encode()
         keys, rows = self._fixture_keys()
         st = wire.derive_stream_statics(
             keys, self.LANES, self.NUM_SLOTS, self.NUM_SLOTS
@@ -904,7 +902,7 @@ class TestNativeFusedPrep:
         # check C and Python agree on rejection
         from parameter_server_tpu.utils.murmur import hash_slots
 
-        _native_or_skip()
+        _native_stream_encode()
         keys, rows = self._fixture_keys()
         st = wire.derive_stream_statics(
             keys, self.LANES, self.NUM_SLOTS, self.NUM_SLOTS
