@@ -904,8 +904,7 @@ def phase_breakdown(worker, make_parts, T: int, launches: int = 3,
     (utils/profiling.device_trace) for op-level attribution."""
     import jax
 
-    from parameter_server_tpu.telemetry.timeline import device_annotation
-    from parameter_server_tpu.utils.profiling import device_trace
+    from parameter_server_tpu.utils.profiling import annotate, device_trace
 
     prep_s = up_s = dev_s = 0.0
     bytes_moved = 0
@@ -954,7 +953,7 @@ def phase_breakdown(worker, make_parts, T: int, launches: int = 3,
                 # the profiler's device tracks line up with the host
                 # timeline through this named annotation (no-op off-TPU)
                 with telemetry_spans.span("bench.device", phase="breakdown"):
-                    with device_annotation("bench.device"):
+                    with annotate("bench.device"):
                         worker.executor.wait(
                             worker._submit_prepped(staged, with_aux=False)
                         )

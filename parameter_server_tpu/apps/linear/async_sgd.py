@@ -45,6 +45,7 @@ from ...parallel import mesh as meshlib
 from ...parallel import partition as partlib
 from ...parallel.mesh import DATA_AXIS, SERVER_AXIS
 from ...system.message import Task
+from ...telemetry import spans as telemetry_spans
 from ...utils import evaluation
 from ...utils.bitpack import (
     hash_slots_packed,
@@ -2025,7 +2026,6 @@ class DeviceUploader:
         import collections
 
         from ...learner.ingest import pipeline_instruments
-        from ...telemetry import spans as telemetry_spans
         from ...utils.concurrent import iter_on_thread
 
         tel = pipeline_instruments()
@@ -2061,17 +2061,13 @@ class DeviceUploader:
                 # uploaded_bytes must stay the REALIZED link traffic
                 # (doc/OBSERVABILITY.md), so hit bytes are subtracted
                 saved0 = int(getattr(upload_fn, "saved_bytes", 0))
-                if telemetry_spans.get_sink() is not None:
-                    # span (not a hand-built emit): an upload_fn failure
-                    # still closes the event with an `error` attr, so
-                    # the traced flow shows WHERE it died instead of
-                    # silently ending at ingest.prep
-                    with telemetry_spans.flow_scope(fid):
-                        with telemetry_spans.span(
-                            "ingest.upload", pipeline="device_uploader"
-                        ):
-                            staged = upload_fn(prepped)
-                else:
+                # span (not a hand-built emit): an upload_fn failure
+                # still closes the event with an `error` attr, so the
+                # traced flow shows WHERE it died instead of silently
+                # ending at ingest.prep
+                with telemetry_spans.flow_scope(fid), telemetry_spans.span(
+                    "ingest.upload", pipeline="device_uploader"
+                ):
                     staged = upload_fn(prepped)
                 if tel is not None:
                     hit_bytes = (
@@ -2087,9 +2083,9 @@ class DeviceUploader:
                             - hit_bytes,
                         )
                     )
-                    tel["stage_seconds"].labels(stage="upload").observe(
-                        time.perf_counter() - t0
-                    )
+                    tel["stage_seconds"].labels(
+                        stage="upload", pipeline="device_uploader"
+                    ).observe(time.perf_counter() - t0)
                 self._flows.append(fid)
                 yield staged, n
 
@@ -3141,7 +3137,8 @@ class AsyncSGDWorker(ISGDCompNode):
                     # key heat rides the FEEDER thread (this generator
                     # runs on the ingest pipeline's feeder) — the
                     # stateless-or-feeder home for the stateful sketch
-                    self._note_heat(batch)
+                    with telemetry_spans.span("ingest.heat"):
+                        self._note_heat(batch)
                     group.append(batch)
                     if len(group) >= T:
                         yield group
@@ -3183,13 +3180,13 @@ class AsyncSGDWorker(ISGDCompNode):
                     )
             uploader = DeviceUploader(flattened(), upload_fn, depth=2)
             try:
-                from ...telemetry import spans as telemetry_spans
-
-                for staged_batch, n in uploader:
+                for staged_batch, n in self._awaited(uploader):
                     # submit under the batch's flow id (popped FIFO from
                     # the uploader) so the executor.step span correlates
                     # back through upload → prep → read in the timeline
-                    with telemetry_spans.flow_scope(uploader.next_flow()):
+                    with telemetry_spans.flow_scope(
+                        uploader.next_flow()
+                    ), self._loop_phase("submit"):
                         pending.append(
                             (self._submit_prepped(
                                 staged_batch, with_aux=True),
@@ -3214,10 +3211,14 @@ class AsyncSGDWorker(ISGDCompNode):
         def flush_group():
             if not group:
                 return
-            pending.extend(self.submit_group(list(group), with_aux=True))
+            # unpipelined: prep and upload run here, inside the submit
+            with self._loop_phase("submit"):
+                pending.extend(
+                    self.submit_group(list(group), with_aux=True)
+                )
             group.clear()
 
-        for batch in batches:
+        for batch in self._awaited(batches):
             self._note_heat(batch)
             group.append(batch)
             if len(group) >= T:

@@ -39,12 +39,15 @@ per-stage latency histograms, queue-depth gauges, and volume counters.
 
 from __future__ import annotations
 
-import time
+import contextlib
 from typing import Callable, Iterator, Optional
 
 from ..system import faults
 from ..telemetry import spans as telemetry_spans
 from ..utils.concurrent import OrderedStagePool, iter_on_thread
+
+
+_END = object()  # end of a stage's input, where None may be an item
 
 
 def pipeline_instruments():
@@ -100,63 +103,50 @@ class IngestPipeline:
         self._thread_it = None
         self._it: Optional[Iterator] = None
         self._closed = False
-        # timeline tracing (telemetry/timeline.py): decided once at
-        # start() — when a span sink is installed, every batch gets a
-        # flow id on the feeder and rides it through filter → prep →
-        # the consumer (items travel internally as (flow, batch) pairs;
-        # the consumer-facing iterator unwraps). Off = zero overhead.
-        self._trace = False
+        # timeline tracing (telemetry/timeline.py): while a span sink
+        # is installed every batch gets a flow id on the feeder and
+        # rides it through filter → prep → the consumer. Items always
+        # travel internally as (flow, batch) pairs, flow None with
+        # tracing off; the consumer-facing iterator unwraps.
 
     # -- stage bodies --------------------------------------------------
 
-    def _observe(self, stage: str, seconds: float) -> None:
+    @contextlib.contextmanager
+    def _stage(self, stage: str, flow=None):
+        """One stage's work on one item: ``ps_ingest_stage_seconds`` and
+        the ``ingest.<stage>`` span under the item's flow, or under none
+        (a feeder that consumes another pipeline still has the flow of
+        that one's last item active)."""
+        hist = None
         if self._tel is not None:
-            self._tel["stage_seconds"].labels(stage=stage).observe(seconds)
+            hist = self._tel["stage_seconds"].labels(
+                stage=stage, pipeline=self._name
+            )
+        with telemetry_spans.flow_scope(flow), telemetry_spans.span(
+            "ingest." + stage, histogram=hist, flow=flow,
+            pipeline=self._name,
+        ) as found:
+            yield found
 
     def _produced(self) -> Iterator:
-        """Feeder-side serial stages: read (source next) + filter.
-        When tracing, each batch is born here with a flow id and every
-        stage span carries it — items flow on as (flow, batch)."""
+        """Feeder-side serial stages: read (source next) + filter. Each
+        batch is born here, with a flow id when tracing, and every
+        stage span carries it."""
         src = self._source
         while True:
-            # pslint: disable=determinism — trace/telemetry birth timestamp only; it rides span metadata, never the encoded batch bytes the replay contract covers
-            t_wall = time.time()
-            t0 = time.perf_counter()
-            try:
-                batch = next(src)
-            except StopIteration:
-                return
-            read_s = time.perf_counter() - t0
-            self._observe("read", read_s)
             fid = None
-            if self._trace:
-                fid = telemetry_spans.new_flow()
-                telemetry_spans.emit(
-                    {
-                        "kind": "span",
-                        "name": "ingest.read",
-                        "pipeline": self._name,
-                        "t_wall": t_wall,
-                        "dur_s": read_s,
-                        "flow": fid,
-                    }
-                )
+            with self._stage("read") as found:
+                batch = next(src, _END)
+                if found is not None and batch is not _END:
+                    # the read that finds the end of the stream is no
+                    # unit of work: only a batch is born with a flow
+                    fid = found["flow"] = telemetry_spans.new_flow()
+            if batch is _END:
+                return
             if self._filter_fn is not None:
-                if self._trace:
-                    with telemetry_spans.flow_scope(fid):
-                        with telemetry_spans.span(
-                            "ingest.filter", pipeline=self._name
-                        ):
-                            t0 = time.perf_counter()
-                            batch = self._filter_fn(batch)
-                            self._observe(
-                                "filter", time.perf_counter() - t0
-                            )
-                else:
-                    t0 = time.perf_counter()
+                with self._stage("filter", fid):
                     batch = self._filter_fn(batch)
-                    self._observe("filter", time.perf_counter() - t0)
-            yield (fid, batch) if self._trace else batch
+            yield fid, batch
 
     def _prep(self, item):
         # fault point (doc/ROBUSTNESS.md): an armed raise dies mid-batch
@@ -164,20 +154,9 @@ class IngestPipeline:
         # worker exceptions forward to the consumer at the position they
         # occurred and close() still joins every thread
         faults.inject("ingest.prep", detail=self._name)
-        if self._trace:
-            fid, batch = item
-            with telemetry_spans.flow_scope(fid):
-                with telemetry_spans.span(
-                    "ingest.prep", pipeline=self._name
-                ):
-                    t0 = time.perf_counter()
-                    out = self._prep_fn(batch)
-                    self._observe("prep", time.perf_counter() - t0)
-            return fid, out
-        t0 = time.perf_counter()
-        out = self._prep_fn(item)
-        self._observe("prep", time.perf_counter() - t0)
-        return out
+        fid, batch = item
+        with self._stage("prep", fid):
+            return fid, self._prep_fn(batch)
 
     # -- lifecycle -----------------------------------------------------
 
@@ -187,7 +166,6 @@ class IngestPipeline:
             raise RuntimeError(f"{self._name}: start() after close()")
         if self._it is not None:
             return self
-        self._trace = telemetry_spans.get_sink() is not None
         if self._prep_fn is not None and self._workers > 0:
             self._pool = OrderedStagePool(
                 self._prep,
@@ -223,15 +201,25 @@ class IngestPipeline:
                 "first (or use the pipeline as a context manager)"
             )
         tel = self._tel
+        wait_hist = None
+        if tel is not None:
+            wait_hist = tel["wait_seconds"].labels(queue=self._name)
         try:
-            for item in self._it:
-                # tracing wraps items as (flow, batch) internally; the
+            while True:
+                # the consumer blocked on this pipeline's queue: which
+                # thread waits for which feeder (a chained pipeline's
+                # feeder is the consumer of the one before it)
+                with telemetry_spans.span(
+                    "ingest.wait", histogram=wait_hist, pipeline=self._name
+                ):
+                    item = next(self._it, _END)
+                if item is _END:
+                    return
+                # items travel as (flow, batch) internally; the
                 # consumer sees the bare batch, with the batch's flow
                 # active on its thread until it advances to the next
                 # item (so a downstream stage's spans correlate)
-                fid = None
-                if self._trace:
-                    fid, item = item
+                fid, item = item
                 if tel is not None:
                     tel["queue_depth"].labels(queue=self._name).set(
                         self.qsize()

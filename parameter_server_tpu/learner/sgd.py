@@ -12,6 +12,7 @@
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Dict, Iterator, List, Optional
 
@@ -22,9 +23,20 @@ from ..filter.frequency import FrequencyFilter
 from ..parameter.replica import Checkpointable
 from ..system.customer import App
 from ..system.monitor import MonitorMaster, MonitorSlaver
+from ..telemetry import spans as telemetry_spans
 from ..utils.localizer import Localizer
 from ..utils.sparse import SparseBatch
 from .workload_pool import WorkloadPool
+
+
+# phase of ps_train_loop_seconds -> the span of the same interval
+_LOOP_SPANS = {
+    "wait_ingest": "train.wait_ingest",
+    "submit": "train.submit",
+    "collect_wait": "train.collect.wait",
+    "collect_host": "train.collect.host",
+}
+_END = object()  # end of an iterator whose items may be None
 
 
 @dataclasses.dataclass
@@ -120,30 +132,69 @@ class ISGDCompNode(App, Checkpointable):
         self._consistency = None
         from ..telemetry import registry as telemetry_registry
 
+        # ps_train_loop_seconds children by phase: where the trainer
+        # thread's time in the loop goes (_loop_phase)
+        self._loop_seconds: Dict[str, object] = {}
         if telemetry_registry.enabled():
             from ..telemetry.instruments import app_instruments
 
-            self._examples_counter = app_instruments(
-                telemetry_registry.default_registry()
-            )["examples"]
+            tel = app_instruments(telemetry_registry.default_registry())
+            self._examples_counter = tel["examples"]
+            self._loop_seconds = {
+                phase: tel["loop_seconds"].labels(phase=phase)
+                for phase in _LOOP_SPANS
+            }
 
     def attach_monitor(self, scheduler: ISGDScheduler) -> None:
         self.reporter = MonitorSlaver(scheduler.monitor, self.name)
 
+    @contextlib.contextmanager
+    def _loop_phase(self, phase: str):
+        """One phase of the training loop on the trainer's thread:
+        ``ps_train_loop_seconds{phase}`` and its ``train.*`` span. The
+        phases are siblings, so their sum is the thread's time in the
+        loop: waiting for ingest, submitting, waiting for the device,
+        and the host work of a collect."""
+        with telemetry_spans.span(
+            _LOOP_SPANS[phase], histogram=self._loop_seconds.get(phase)
+        ):
+            yield
+
+    def _awaited(self, items):
+        """``items``, with the time this thread blocks for each one
+        under the loop's ``wait_ingest`` phase."""
+        it = iter(items)
+        while True:
+            with self._loop_phase("wait_ingest"):
+                item = next(it, _END)
+            if item is _END:
+                return
+            yield item
+
     def collect(self, ts: int) -> SGDProgress:
         """Wait for a step and fold its metrics into progress (the
         worker's reporter_.Report path)."""
-        from ..utils import evaluation
-
-        self.po.beat(self.name)  # liveness signal (ref heartbeat thread)
-        hb = self.po.aux.info(self.name) if self.po.aux is not None else None
-        if hb is not None:
-            hb.start_timer()  # dashboard busy-time (ref heartbeat_info.h)
-        metrics = self.executor.wait(ts)
-        if hb is not None:
-            hb.stop_timer()
+        with self._loop_phase("collect_wait"):
+            self.po.beat(self.name)  # liveness signal (ref heartbeat thread)
+            hb = (
+                self.po.aux.info(self.name) if self.po.aux is not None
+                else None
+            )
+            if hb is not None:
+                hb.start_timer()  # dashboard busy-time (ref heartbeat_info.h)
+            metrics = self.executor.wait(ts)
+            if hb is not None:
+                hb.stop_timer()
         if metrics is None:
             return self.progress
+        with self._loop_phase("collect_host"):
+            return self._fold(metrics)
+
+    def _fold(self, metrics) -> SGDProgress:
+        """The host work of a collect: counters, the learning plane,
+        per-minibatch AUC, the reporter."""
+        from ..utils import evaluation
+
         if self._examples_counter is not None:
             self._examples_counter.inc(int(metrics["num_ex"]))
         if self._learning is not None:

@@ -432,6 +432,8 @@ class Executor:
                 # thread; flow_scope(None) is a free passthrough
                 with telemetry_spans.flow_scope(
                     *(step_flow or (None, None))
+                ), telemetry_spans.span(
+                    "executor.run", ts=ts, executor=self.name
                 ):
                     result = step()
                 err = None
@@ -508,12 +510,15 @@ class Executor:
         the stores to mark superseded timestamps explicitly.
         """
         t0 = time.perf_counter()
-        try:
-            jax.block_until_ready(fut)
-        except RuntimeError as e:
-            msg = str(e)
-            if "deleted" not in msg and "donated" not in msg:
-                raise
+        with telemetry_spans.span(
+            "executor.materialize", ts=ts, executor=self.name
+        ):
+            try:
+                jax.block_until_ready(fut)
+            except RuntimeError as e:
+                msg = str(e)
+                if "deleted" not in msg and "donated" not in msg:
+                    raise
         self._note_materialize(ts, time.perf_counter() - t0)
 
     def _record_finished(self, ts: int, num_pending: int) -> None:

@@ -177,8 +177,16 @@ def ingest_instruments(reg: MetricsRegistry) -> Dict[str, object]:
         "stage_seconds": reg.ensure_histogram(
             "ps_ingest_stage_seconds",
             "per-minibatch wall time inside one ingest stage "
-            "(read/filter/prep/upload)",
-            labelnames=("stage",),
+            "(read/filter/prep/upload) of one pipeline; a chained "
+            "pipeline's read is its wait on the pipeline before it",
+            labelnames=("stage", "pipeline"),
+            buckets=PHASE_BUCKETS,
+        ),
+        "wait_seconds": reg.ensure_histogram(
+            "ps_ingest_wait_seconds",
+            "wall time the consumer of an ingest queue blocked for its "
+            "next item: which thread waits on which feeder",
+            labelnames=("queue",),
             buckets=PHASE_BUCKETS,
         ),
         "queue_depth": reg.ensure_gauge(
@@ -954,6 +962,14 @@ def app_instruments(reg: MetricsRegistry) -> Dict[str, object]:
         "examples": reg.ensure_counter(
             "app_examples_total",
             "training examples submitted to device steps",
+        ),
+        "loop_seconds": reg.ensure_histogram(
+            "ps_train_loop_seconds",
+            "trainer-thread wall time per phase of the training loop "
+            "(wait_ingest/submit/collect_wait/collect_host): siblings, "
+            "their sum is the thread's time in the loop",
+            labelnames=("phase",),
+            buckets=PHASE_BUCKETS,
         ),
     }
 

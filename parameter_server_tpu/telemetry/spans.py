@@ -265,28 +265,68 @@ def abandoned(name: str, reason: str, flow: Optional[int] = None, **attrs) -> No
     emit(event)
 
 
+def _capture_interval(name: str, ts, flow):
+    """The span as an interval of a running ``jax.profiler`` capture:
+    ``ps.<name>`` on the emitting thread's host track, ``flow``/``ts``
+    (those that are set) as its args, on the device trace's clock.
+    ``TraceMe`` records nothing outside a profiler session, so the
+    program never asks whether anyone is capturing."""
+    from ..utils.profiling import annotate
+
+    keys = {}
+    if flow is not None:
+        keys["flow"] = flow
+    if ts is not None:
+        keys["ts"] = ts
+    return annotate("ps." + name, **keys)
+
+
+_INHERIT = object()  # span(flow=...): take the thread's active flow
+
+
 @contextlib.contextmanager
-def span(name: str, ts: Optional[int] = None, histogram=None, **attrs):
+def span(name: str, ts: Optional[int] = None, histogram=None,
+         flow=_INHERIT, **attrs):
     """Time a host-side block and emit it as one JSONL event.
 
     ``ts`` is the executor logical timestamp the block serves — the
     correlation key between host spans and device steps. ``histogram``
     (a telemetry Histogram or labeled child) additionally records the
     duration, so the same interval feeds both the trace and the
-    registry. Extra keyword attrs ride along verbatim.
+    registry. Extra keyword attrs ride along verbatim, and so does what
+    the block learns only inside it: ``with span("x") as found:
+    found["bytes"] = n`` (``found`` is None when nothing is emitted).
+
+    While a sink is installed the block also runs inside a profiler
+    annotation ``ps.<name>`` (:func:`_capture_interval`), so a capture
+    taken meanwhile holds the span beside the device ops it explains
+    (doc/OBSERVABILITY.md "Reading a capture"). With no sink and no
+    ``histogram`` the block just runs: call sites need no traced and
+    untraced branch of their own.
 
     The thread's active :func:`flow_scope` id is attached as ``flow``
-    (pass an explicit ``flow=`` attr to override). A block that exits
+    (an explicit ``flow=`` overrides it; ``flow=None`` says the span
+    belongs to no flow, whatever scope the thread is in). A block that exits
     via an exception still emits its event — with ``error`` naming the
     exception type — so the timeline never holds open-ended spans;
     MUST be used as a ``with`` statement (the pslint ``spans`` pass
     flags bare calls, whose block would otherwise never run).
     """
+    traced = _sink is not None
+    if not traced and histogram is None:
+        yield None
+        return
+    fid = current_flow() if flow is _INHERIT else flow
+    capture = contextlib.nullcontext()
+    if traced:
+        with contextlib.suppress(Exception):
+            capture = _capture_interval(name, ts, fid)
     t_wall = time.time()
     t0 = time.perf_counter()
     error: Optional[str] = None
     try:
-        yield
+        with capture:
+            yield attrs if traced else None
     except BaseException as e:
         # only an exception that actually unwound THIS block is an
         # error of the span — sys.exc_info() in the finally would also
@@ -298,16 +338,18 @@ def span(name: str, ts: Optional[int] = None, histogram=None, **attrs):
         if histogram is not None:
             with contextlib.suppress(Exception):
                 histogram.observe(dur)
-        event = {"kind": "span", "name": name, "t_wall": t_wall, "dur_s": dur}
-        if ts is not None:
-            event["ts"] = ts
-        fid = current_flow()
-        if fid is not None:
-            event["flow"] = fid
-            fnode = current_flow_node()
-            if fnode is not None:
-                event["flow_node"] = fnode
-        if error is not None:
-            event["error"] = error
-        event.update(attrs)
-        emit(event)
+        if traced:
+            event = {
+                "kind": "span", "name": name, "t_wall": t_wall, "dur_s": dur,
+            }
+            if ts is not None:
+                event["ts"] = ts
+            if fid is not None:
+                event["flow"] = fid
+                fnode = current_flow_node() if flow is _INHERIT else None
+                if fnode is not None:
+                    event["flow_node"] = fnode
+            if error is not None:
+                event["error"] = error
+            event.update(attrs)
+            emit(event)

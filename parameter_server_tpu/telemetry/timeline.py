@@ -20,15 +20,14 @@ instant events so a worker-exception tombstone is visible exactly where
 the batch died. ``doc/OBSERVABILITY.md`` ("Reading a timeline") walks
 a rendered example.
 
-On-TPU runs can interleave device-side context: wrap launches in
-:func:`device_annotation` and capture a ``jax.profiler`` trace beside
-the host timeline (``bench.py --profile``) — the annotation names show
-up inside the profiler's device tracks, keyed by the same step names.
+Inside a ``jax.profiler`` capture no merging is needed: while a sink is
+installed every span is also an interval ``ps.<name>`` of the capture's
+host process, on the device trace's own clock (``spans.span``,
+doc/OBSERVABILITY.md "Reading a capture").
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -390,17 +389,3 @@ def export_chrome_trace(
     with open(out_path, "w", encoding="utf-8") as f:
         json.dump(trace, f)
     return trace
-
-
-def device_annotation(name: str):
-    """Optional ``jax.profiler`` device-side annotation: inside a
-    profiler capture on TPU, names the enclosed launches so the device
-    trace's tracks line up with the host timeline's step names. Returns
-    a null context when jax (or the profiler) is unavailable — safe to
-    use unconditionally."""
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()

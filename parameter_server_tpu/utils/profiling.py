@@ -36,12 +36,17 @@ def device_trace(log_dir: str | None) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named region inside a capture (shows as a track annotation).
-    Usable as a context manager: ``with annotate("push"): ...``"""
+def annotate(name: str, **keys):
+    """Named region inside a capture: an ``X`` event on the calling
+    thread's track of the host process, on the device trace's clock,
+    with ``keys`` as its ``args``. Usable as a context manager:
+    ``with annotate("push"): ...``. The one wrapper of
+    ``jax.profiler.TraceAnnotation``; ``telemetry/spans.span`` calls it
+    for every span while a sink is installed. Outside a capture it
+    records nothing."""
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **keys)
 
 
 # -- trace post-processing ---------------------------------------------------

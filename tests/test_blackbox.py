@@ -453,7 +453,10 @@ class TestFlightRecorder:
         out = blackbox.overhead_ab(reps=2, n=100)
         assert out["file_io"] is False
         assert out["ratio_median"] > 0
-        assert out["armed_ns_per_event"] > out["added_ns_per_event"] > 0
+        # added_ns_per_event is a difference of two timings, which noise
+        # on a shared host makes negative: its sign is no contract
+        assert out["armed_ns_per_event"] > 0
+        assert isinstance(out["added_ns_per_event"], float)
         assert out["reps"] == 2
 
 

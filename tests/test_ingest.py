@@ -460,7 +460,8 @@ class TestIngestTelemetry:
         total = snap["ps_ingest_examples_total"]["values"]["pipeline=tel_test"]
         assert total - base_n == 384
         stages = set(snap["ps_ingest_stage_seconds"]["values"])
-        assert "stage=read" in stages
+        assert "stage=read,pipeline=tel_test" in stages
+        assert "queue=tel_test" in snap["ps_ingest_wait_seconds"]["values"]
         assert "queue=tel_test" in snap["ps_ingest_queue_depth"]["values"]
 
     def test_instruments_in_catalog(self):
@@ -471,6 +472,7 @@ class TestIngestTelemetry:
         names = set(install_all(MetricsRegistry()))
         assert {
             "ps_ingest_stage_seconds",
+            "ps_ingest_wait_seconds",
             "ps_ingest_queue_depth",
             "ps_ingest_examples_total",
             "ps_ingest_batches_total",

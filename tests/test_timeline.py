@@ -146,9 +146,11 @@ class TestPipelineFlows:
         ]
         assert len(chains) == 5
         for chain in chains:
+            # executor.step is placed at its submit; executor.run is the
+            # dispatch thread's interval inside it, under the same flow
             assert chain == [
                 "ingest.read", "ingest.filter", "ingest.prep",
-                "executor.step",
+                "executor.step", "executor.run",
             ]
         # the stages really ran on different threads
         threads_per_flow = [
@@ -160,13 +162,17 @@ class TestPipelineFlows:
         from parameter_server_tpu.learner import ingest as ingest_mod
         from parameter_server_tpu.learner.ingest import IngestPipeline
 
-        # tracing off must mean span() is never even ENTERED — read,
-        # filter and prep alike (the filter branch once paid the span
-        # machinery unconditionally)
+        # tracing off must mean a stage pays its histogram and nothing
+        # else: one code path per stage, on which no event is built and
+        # no profiler annotation is made — read, filter and prep alike
         def boom(*a, **k):
-            raise AssertionError("span() entered with tracing off")
+            raise AssertionError("a span was emitted with tracing off")
 
-        monkeypatch.setattr(ingest_mod.telemetry_spans, "span", boom)
+        monkeypatch.setattr(ingest_mod.telemetry_spans, "emit", boom)
+        monkeypatch.setattr(
+            ingest_mod.telemetry_spans, "_capture_interval", boom
+        )
+        monkeypatch.setattr(ingest_mod.telemetry_spans, "new_flow", boom)
         pipe = IngestPipeline(
             range(4),
             filter_fn=lambda x: x,
@@ -175,7 +181,7 @@ class TestPipelineFlows:
             name="off",
         ).start()
         assert list(pipe) == [1, 2, 3, 4]
-        assert pipe._trace is False
+        assert current_flow() is None
 
     def test_device_uploader_hands_flow_to_consumer(self, tmp_path):
         from parameter_server_tpu.apps.linear.async_sgd import DeviceUploader
@@ -744,6 +750,28 @@ class TestChromeExport:
         assert json.load(open(out)) == trace
 
 
-def test_device_annotation_is_safe_everywhere():
-    with timeline.device_annotation("unit.block"):
+def test_device_annotation_is_safe_everywhere(tmp_path):
+    """The bridge (``spans.span`` inside ``utils.profiling.annotate``)
+    is safe with no capture running, with and without a sink, and a
+    block that raises still closes its event."""
+    from parameter_server_tpu.utils.profiling import annotate
+
+    with annotate("unit.block", flow=1, ts=2):
         pass
+    with telemetry_spans.span("unit.block", ts=2):  # no sink: nothing but the block
+        pass
+    sink = JsonlSink(str(tmp_path / "t.jsonl"))
+    prev = install_sink(sink)
+    try:
+        with flow_scope(7), telemetry_spans.span("unit.block", ts=2):
+            pass
+        with pytest.raises(KeyError):
+            with telemetry_spans.span("unit.raises"):
+                raise KeyError("x")
+    finally:
+        install_sink(prev)
+        sink.close()
+    events = timeline.load_events(str(tmp_path / "t.jsonl"))
+    assert [(e["name"], e.get("flow"), e.get("error")) for e in events] == [
+        ("unit.block", 7, None), ("unit.raises", None, "KeyError"),
+    ]
