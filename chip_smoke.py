@@ -10,7 +10,8 @@ call, on whatever ``jax.devices()`` reports, and checks what comes out:
 - **K** each Pallas kernel alone (quantize, dense FTRL f32/bf16, fused
   sparse FTRL, flash forward+backward at d_head 128 and 64) against its
   XLA reference, so that a kernel the compiler refuses costs seconds and
-  not a leg;
+  not a leg; and the row write-back (``ops/rows.py``) at 2^30 slots and
+  639,488 rows against the undeclared scatter, every slot bit-equal;
 - **A** the Criteo trainer, ``apps.linear.main``: a dense-sweep conf at
   2^26 slots that this script writes, then
   ``configs/criteo/online_l1lr_bigtable.conf`` as committed (2^30 slots),
@@ -444,9 +445,12 @@ def leg_kernels(run: Run) -> dict:
     )
     ok = jnp.asarray(np.arange(u) < len(live))
     g_u = jnp.asarray(rng.normal(size=u).astype(np.float32))
-    want = jax.jit(functools.partial(ftrl_sparse_rows_ref, **hp))(
-        z, n32, rel, ok, g_u
-    )
+    # the oracle writes its rows back under the order promise (live
+    # rows ascending, padding behind them): the kernel, which orders
+    # its rows itself, is then also the check of that scatter
+    want = jax.jit(
+        functools.partial(ftrl_sparse_rows_ref, rows_ascend=True, **hp)
+    )(z, n32, rel, ok, g_u)
     sparse_step = jax.jit(
         functools.partial(ftrl_sparse_update, **hp, **pin),
         donate_argnums=(0, 1),
@@ -470,6 +474,70 @@ def leg_kernels(run: Run) -> dict:
     )
     out["ftrl_sparse_f32"]["compile_s"] = compile_s()
     del z, n32, g, z1, n1, zr, nr, want, got, z_in, n_in
+
+    # -- the row write-back at the big table's own shapes --------------
+    # ops/rows.py (indices ascending and unique, declared) against the
+    # plain scatter built here: every dropped entry at the one index
+    # one-past-the-end, nothing declared. Every slot of a 2^30 f32 and
+    # of a 2^30 bf16 table bit-equal. What each costs is
+    # script/price_row_writeback.py's to say.
+    from parameter_server_tpu.ops.rows import write_index, write_rows
+
+    pb = run.sizes.slots_big
+    ub = 1024 if r else 639488
+    live_b = np.unique(rng.integers(0, pb, 2 * ub, dtype=np.int64))
+    live_b = np.sort(rng.permutation(live_b)[: ub - ub // 64])
+    rel_b = jnp.asarray(np.concatenate(
+        [live_b, np.full(ub - len(live_b), pb - 1)]
+    ).astype(np.int32))
+    ok_b = jnp.asarray(np.arange(ub) < len(live_b))
+
+    def bits(a):
+        return jax.lax.bitcast_convert_type(
+            a, jnp.uint16 if a.dtype.itemsize == 2 else jnp.uint32
+        )
+
+    declared = jax.jit(
+        lambda t, v: write_rows(
+            t, write_index(rel_b, ok_b, pb), v, rows_ascend=True
+        ),
+        donate_argnums=(0,),
+    )
+    plain = jax.jit(
+        lambda t, v: t.at[
+            jnp.where(ok_b, rel_b.astype(jnp.uint32), jnp.uint32(pb))
+        ].set(v, mode="drop"),
+        donate_argnums=(0,),
+    )
+    for tname, dtype in (("z_f32", jnp.float32), ("sqrt_n_bf16",
+                                                  jnp.bfloat16)):
+        fill = jax.jit(
+            lambda dtype=dtype: (
+                jax.lax.iota(jnp.int32, pb) % 977
+            ).astype(dtype) / 8
+        )
+        vals = jnp.asarray(rng.normal(size=ub), dtype)
+        t_new = declared(fill(), vals)
+        t_old = plain(fill(), vals)
+        differ = int(jax.jit(
+            lambda a, b: jnp.sum(bits(a) != bits(b))
+        )(t_old, t_new))
+        del t_old
+        written = int(jax.jit(
+            lambda a: jnp.sum(bits(a) != bits(fill()))
+        )(t_new))
+        del t_new
+        out[f"row_write_back_{tname}"] = {
+            "slots": pb, "rows": ub, "live_rows": len(live_b),
+            "slots_that_differ": differ, "slots_written": written,
+        }
+        # nearly every live row lands on a value that is not the
+        # fill's; a write-back that wrote nothing would read 0
+        require(
+            differ == 0 and len(live_b) * 0.9 < written <= len(live_b),
+            out[f"row_write_back_{tname}"],
+        )
+        out[f"row_write_back_{tname}"]["compile_s"] = compile_s()
 
     # -- quantize (on-core PRNG; no interpret form) --------------------
     if run.on_tpu:

@@ -1512,7 +1512,8 @@ def _make_exact_mini_step(
     ``significance`` (ops/significance.SignificanceSpec, sparse-only):
     the in-jit KKT filter — slots whose aggregated update provably
     leaves the FTRL proximal weight at zero are masked out of the
-    update entirely (their rows are scatter-dropped, bit-untouched).
+    update entirely (their gradient is zeroed, so their rows are
+    written back with the bits they were read with).
     ``None`` traces the literal pre-filter program (the off =
     bit-identical contract).
     """
@@ -1546,6 +1547,14 @@ def _make_exact_mini_step(
         def mini_step_sparse(live, pulled, seed, y, mask, rows, ucols,
                              vals, uslots, umask):
             rel, ok = localize(uslots, shard)
+            # static at trace time, and what the write-back's order
+            # promise rests on (ops/rows.py): prep hands every shard
+            # the SAME ascending unique ``uslots`` with a sentinel tail
+            # (prep_batch_shared's np.unique; the wire's delta decode),
+            # so on ONE server shard the owned rows ascend and every
+            # non-owned entry sits behind them. On a later shard of a
+            # wider server axis the ids a lower shard owns come first.
+            rows_ascend = jax.lax.axis_size(SERVER_AXIS) == 1
             with jax.named_scope("ps_pull"):
                 # derive weights from the GATHERED rows of the pull
                 # state — no whole-table weight derivation. Exact:
@@ -1571,7 +1580,6 @@ def _make_exact_mini_step(
                 # no dense scatter, no shard-sized temp
                 g_local = g_u
                 g_u = jax.lax.psum(g_u, DATA_AXIS)
-            ok_upd = ok
             if significance is not None:
                 with jax.named_scope("ps_kkt"):
                     from ...ops.significance import kkt_mask
@@ -1586,13 +1594,16 @@ def _make_exact_mini_step(
                         z_u, g_u, w_u, umask, seed, spec=significance
                     )
                     # suppressed slots leave the push entirely: their
-                    # aggregated gradient zeroes AND their rows are
-                    # scatter-dropped below — state bit-untouched
+                    # aggregated gradient zeroes, and a row with g = 0
+                    # is written back with the bits it was read with —
+                    # state bit-untouched. They keep their place in the
+                    # index vector (dropping them would leave holes
+                    # between the kept rows and break its order)
                     g_u = jnp.where(keep, g_u, 0.0)
-                    ok_upd = ok & keep
             with jax.named_scope("ps_update"):
                 new_state = apply_state_rows(
-                    updater, live, rel, ok_upd, g_u, seed=seed
+                    updater, live, rel, ok, g_u, seed=seed,
+                    rows_ascend=rows_ascend,
                 )
             with jax.named_scope("ps_metrics"):
                 metrics = _progress_metrics(loss, y, xw, mask, with_aux)

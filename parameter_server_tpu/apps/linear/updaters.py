@@ -154,7 +154,8 @@ class SGDUpdater:
 
 
 def apply_state_rows(updater, state, rel, ok, g_u, seed=None, *,
-                     force_pallas=False, interpret=False):
+                     rows_ascend=False, force_pallas=False,
+                     interpret=False):
     """Sparse-touched update: run ``updater.apply`` on just the gathered
     rows ``rel`` of a server shard and scatter the results back.
 
@@ -172,16 +173,24 @@ def apply_state_rows(updater, state, rel, ok, g_u, seed=None, *,
     ``rel`` must be unique among ``ok`` entries — host prep dedups at
     slot level (hash collisions included) because the update is
     nonlinear in the summed gradient. Non-owned/padding entries
-    (``ok`` False) are routed to the one-past-the-end row in UNSIGNED
-    index space and dropped by the scatter (``mode='drop'``): a signed
-    -1 would WRAP to the shard's real last row and scatter-set a stale
-    value over its genuine update (observed: the last slot of every
-    shard losing its step), and uint32 both never wraps and still
-    represents one-past-end for the maximal 2^31-row shard. Their
-    gradient is zeroed so the rows they DO gather (clipped indices)
-    can't perturb anything. Scalar state leaves (e.g. SGDUpdater's
-    step count) take the updated value directly — there is nothing to
-    scatter.
+    (``ok`` False) are dropped by the write-back (``ops/rows.py``: the
+    one scatter this body shares with ``ftrl_sparse_rows_ref``, and the
+    unsigned past-the-end index that drops them). Their gradient is
+    zeroed so the rows they DO gather (clipped indices) can't perturb
+    anything. An ``ok`` row whose ``g_u`` is exactly 0 — a slot the KKT
+    filter masked, a feature whose examples cancelled — is written back
+    with the bits it was read with: every updater's membership is
+    ``g != 0``, and the bf16 narrow of an unchanged value round-trips.
+    Scalar state leaves (e.g. SGDUpdater's step count) take the updated
+    value directly — there is nothing to scatter.
+
+    ``rows_ascend`` is the caller's promise, made from what is static
+    at trace time, that the ``ok`` entries of ``rel`` ascend and every
+    non-``ok`` entry sits behind them: ``localize`` of prep's sorted
+    unique ``uslots`` on ONE server shard. The write-back then tells
+    XLA its indices are sorted, which is most of what a scatter into a
+    2^30-slot table costs (``ops/rows.py``); the result is bit-equal
+    either way.
 
     FTRL/decay takes the FUSED path when the shapes allow it
     (ops/ftrl_sparse.py — one Pallas gather→update→scatter pass over
@@ -196,13 +205,24 @@ def apply_state_rows(updater, state, rel, ok, g_u, seed=None, *,
     # host arrays — direct calls and tests; traced production inputs
     # are guaranteed by prep's slot-level np.unique): the update is
     # nonlinear in the summed gradient, so a duplicated ok row would
-    # silently double-apply in BOTH formulations
+    # silently double-apply in BOTH formulations. The order promise is
+    # checked the same way: the CPU ignores a wrong one, the chip does
+    # not
     if isinstance(rel, np.ndarray) and isinstance(ok, np.ndarray):
-        r = rel[np.asarray(ok, bool)]
+        okb = np.asarray(ok, bool)
+        r = rel[okb]
         assert len(np.unique(r)) == len(r), (
             "apply_state_rows: rel must be duplicate-free among ok "
             "entries (host prep dedups at slot level)"
         )
+        assert not rows_ascend or (
+            np.all(np.diff(r.astype(np.int64)) > 0)
+            and np.all(okb[1:] <= okb[:-1])
+        ), (
+            "apply_state_rows: rows_ascend promises ascending ok rows "
+            "with every non-ok entry behind them"
+        )
+    from ...ops.rows import write_index, write_rows
     from .learning_rate import LearningRate
 
     if (
@@ -226,15 +246,14 @@ def apply_state_rows(updater, state, rel, ok, g_u, seed=None, *,
             return {"z": z_new, "sqrt_n": n_new}
     state_u = jax.tree.map(lambda a: a[rel] if a.ndim >= 1 else a, state)
     new_u = updater.apply(state_u, jnp.where(ok, g_u, 0.0), None, seed=seed)
-    rel_u32 = rel.astype(jnp.uint32)
 
-    def _scatter(full, new_leaf):
+    def _write_back(full, new_leaf):
         if full.ndim < 1:
             return new_leaf
-        oob = jnp.where(ok, rel_u32, jnp.uint32(full.shape[0]))
-        return full.at[oob].set(new_leaf.astype(full.dtype), mode="drop")
+        idx = write_index(rel, ok, full.shape[0])
+        return write_rows(full, idx, new_leaf, rows_ascend=rows_ascend)
 
-    return jax.tree.map(_scatter, state, new_u)
+    return jax.tree.map(_write_back, state, new_u)
 
 
 def create_updater(algo: str, ada_grad: bool, lr: LearningRate,

@@ -2800,7 +2800,11 @@ def ftrl_sparse_ab(smoke: bool = False) -> dict:
 
     arms = {
         "xla_rows": jax.jit(
-            lambda z, n: ftrl_sparse_rows_ref(z, n, rel, ok, g, **kw),
+            # the touch pattern ascends with its padding behind it, so
+            # this arm makes the order promise a one-shard step makes
+            lambda z, n: ftrl_sparse_rows_ref(
+                z, n, rel, ok, g, rows_ascend=True, **kw
+            ),
             donate_argnums=(0, 1),
         ),
         "fused": jax.jit(

@@ -1,11 +1,15 @@
 """Fused sparse FTRL-proximal update — Pallas TPU gather→update→scatter.
 
 The big-table row path (``update='sparse'``, updaters.apply_state_rows)
-today runs as four separate XLA ops — gather z, gather √n, scatter z',
-scatter √n' — each a full trip through the memory system with
-intermediate row vectors materialized between them. This kernel is the
-IO-aware formulation (the FlashAttention lesson, arXiv:2205.14135): ONE
-pass over exactly the touched rows —
+runs as four separate XLA ops — gather z, gather √n, scatter z',
+scatter √n' — with intermediate row vectors materialized between them.
+What each costs is per index, not per byte (TPU v5 lite, 639,488 rows
+of a 2^30-slot table, PERF.md §6, PR 27): a gather 8 ms, a scatter
+58 ms while XLA knows nothing of the index vector and 15–17 ms once it
+is told the indices ascend — which host prep guarantees on one server
+shard and ``ops/rows.py`` declares. This kernel is the IO-aware
+formulation (the FlashAttention lesson, arXiv:2205.14135): ONE pass
+over exactly the touched rows —
 
 - the deduped slot ids are reduced to unique 128-lane TABLE ROWS and
   scalar-prefetched (``PrefetchScalarGridSpec``), so the kernel can
@@ -58,6 +62,7 @@ from .ftrl import (
     _ftrl_math,
     ftrl_update,
 )
+from .rows import write_index, write_rows
 
 #: update-path names reported by :func:`resolve_update_path` and the
 #: ``ps_ftrl_update_path_total`` telemetry counter / bench records
@@ -132,7 +137,7 @@ def resolve_update_path(update_mode: str, *, on_tpu: bool, shard: int,
 
 
 def ftrl_sparse_rows_ref(z, sqrt_n, rel, ok, g_u, *, alpha, beta, l1,
-                         l2, seed=None):
+                         l2, seed=None, rows_ascend=False):
     """XLA rows reference: the exact gather→apply→scatter formulation
     ``updaters.apply_state_rows`` runs for the FTRL/decay case, inlined
     here so kernel tests and the A/B bench can call it without an
@@ -141,9 +146,11 @@ def ftrl_sparse_rows_ref(z, sqrt_n, rel, ok, g_u, *, alpha, beta, l1,
     (same ``_ftrl_math``, same position-hash bf16 narrow; calling the
     un-jitted reference here instead would diverge in the last bit at
     EAGER call sites — XLA contracts the z-accumulator multiply-add
-    under jit), scatters back with non-``ok`` entries routed
-    one-past-the-end in UNSIGNED index space and dropped
-    (``mode='drop'`` — the apply_state_rows sentinel contract)."""
+    under jit), and writes back through the one scatter the two
+    formulations share (``ops/rows.py``: non-``ok`` entries past the
+    end in UNSIGNED index space and dropped; ``rows_ascend`` is the
+    caller's promise that the ``ok`` entries of ``rel`` ascend with
+    every non-``ok`` entry behind them)."""
     z_u = z[rel]
     n_u = sqrt_n[rel]
     g = jnp.where(ok, g_u, 0.0)
@@ -151,21 +158,26 @@ def ftrl_sparse_rows_ref(z, sqrt_n, rel, ok, g_u, *, alpha, beta, l1,
         z_u, n_u, g, None, alpha=alpha, beta=beta, l1=l1, l2=l2,
         seed=seed,
     )
-    oob = jnp.where(ok, rel.astype(jnp.uint32), jnp.uint32(z.shape[0]))
+    idx = write_index(rel, ok, z.shape[0])
     return (
-        z.at[oob].set(z_new.astype(z.dtype), mode="drop"),
-        sqrt_n.at[oob].set(n_new.astype(sqrt_n.dtype), mode="drop"),
+        write_rows(z, idx, z_new, rows_ascend=rows_ascend),
+        write_rows(sqrt_n, idx, n_new, rows_ascend=rows_ascend),
     )
 
 
 def _row_gradient(rel, ok, g_u, width: int):
     """Unique-row decomposition of the deduped slot vector (in-program,
     O(U) elementwise/scan work — small next to the row traffic it
-    organizes). The ``ok`` subsequence of ``rel`` is non-decreasing
-    (localize of a sorted unique ``uslots``); non-``ok`` entries are
-    clip artifacts and may land OUT of order — the ≥2^31-slot sentinel
-    is -1 (``slot_sentinel``), so the padding tail clips to rel 0
-    BELOW the ascending owned ids. Every non-``ok`` entry carries g=0
+    organizes). The ``ok`` subsequence of ``rel`` ascends strictly:
+    host prep guarantees it (``prep_batch_shared``'s ``np.unique``, the
+    wire's sorted-delta decode) and ``localize`` keeps the order.
+    Non-``ok`` entries are clip artifacts and may land OUT of order in
+    ``rel`` itself — the ≥2^31-slot sentinel is -1 (``slot_sentinel``),
+    so the padding tail clips to rel 0 BELOW the ascending owned ids,
+    and on a later shard of a multi-server mesh the ids a lower shard
+    owns clip to rel 0 AHEAD of them (``ops/rows.write_index`` is what
+    turns the tail into an ascending vector for the XLA rows path; this
+    kernel orders rows itself). Every non-``ok`` entry carries g=0
     and merges into whichever row group absorbs it, so each is
     remapped to the running max of the ok rows (``cummax``): the row
     sequence is monotone again and the neighbor-compare dedup can
@@ -400,10 +412,11 @@ def ftrl_sparse_update(
     """Fused sparse-touched FTRL update over a 1-D slot shard.
 
     ``rel``/``ok`` are ``localize``'s shard-relative ids + ownership
-    mask for the batch's globally-deduped ``uslots`` (NON-DECREASING —
-    clip of a sorted unique vector — and duplicate-free among ``ok``
-    entries: the update is nonlinear in the summed gradient, so host
-    prep dedups at slot level; the same apply_state_rows contract).
+    mask for the batch's globally-deduped ``uslots`` (strictly
+    ascending among ``ok`` entries — localize of a sorted unique vector
+    — hence duplicate-free there: the update is nonlinear in the summed
+    gradient, so host prep dedups at slot level; the same
+    apply_state_rows contract).
     ``g_u`` is the per-unique-slot aggregated gradient. Returns
     ``(z', sqrt_n')`` — bit-identical to
     ``updaters.apply_state_rows(FTRLUpdater(decay), ...)``.
@@ -425,7 +438,8 @@ def ftrl_sparse_update(
 
     Falls back to :func:`ftrl_sparse_rows_ref` off-TPU and for shapes
     the kernel does not cover (``use_sparse_kernel``), so any caller
-    can use it unconditionally.
+    can use it unconditionally; the fallback makes no order promise
+    about ``rel``.
     """
     p = z.shape[0]
     u = rel.shape[0]

@@ -56,6 +56,7 @@ SCOPE = (
     "parameter_server_tpu/ops/wire_codec.py",
     "parameter_server_tpu/ops/ftrl.py",
     "parameter_server_tpu/ops/ftrl_sparse.py",
+    "parameter_server_tpu/ops/rows.py",
     "parameter_server_tpu/ops/significance.py",
     "parameter_server_tpu/learner/consistency.py",
     "parameter_server_tpu/parameter/kv_vector.py",
