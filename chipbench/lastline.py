@@ -7,8 +7,11 @@ run). ``metrics`` gives each metric of the cell as ``{value, unit}``: the
 end-to-end metrics in an untraced run, and in a traced run those and the
 cell's per-layer metrics. ``device`` gives ``platform``, ``kind``,
 ``count`` and ``memory_peak_bytes``, and in a traced run ``window_s`` and
-``busy_s`` with ``0 < busy_s <= window_s``. A line that fails a check is
-not printed: the faults go out on earlier lines and the run exits 1.
+``busy_s`` with ``0 < busy_s <= window_s``. ``checks`` comes last: each
+check that decided ``correct`` by its name, as ``{ok, value, limit}``,
+the number compared beside what it was held to. A line that fails a
+check is not printed: the faults go out on earlier lines and the run
+exits 1.
 """
 
 from __future__ import annotations
@@ -51,7 +54,7 @@ def faults(obj: dict, bench: dict, workload: str, traced: bool) -> list:
     """Every way in which ``obj`` is not the contract's object for this
     cell and mode; empty when it is."""
     out = []
-    allowed = TOP_KEYS + (("breakdown",) if traced else ())
+    allowed = TOP_KEYS + (("breakdown",) if traced else ()) + ("checks",)
     out += [f"key {k!r} is missing" for k in TOP_KEYS if k not in obj]
     out += [f"key {k!r} does not belong" for k in obj if k not in allowed]
     if out:
@@ -93,12 +96,18 @@ def faults(obj: dict, bench: dict, workload: str, traced: bool) -> list:
             ) for v in b.values()
         ):
             out.append("breakdown is not two lists of at most 10 [name, s]")
+    if "checks" in obj:
+        if list(obj)[-1] != "checks":
+            out.append("'checks' is not the last key")
+        if any(set(c) != {"ok", "value", "limit"}
+               for c in obj["checks"].values()):
+            out.append("a check is not {ok, value, limit}")
     return out
 
 
 def build(bench: dict, workload: str, traced: bool, *, correct: bool,
           attempted: int, failed: int, values: dict, device: dict,
-          breakdown=None) -> dict:
+          breakdown=None, checks=None) -> dict:
     """The object, from plain values: units come from BENCHMARK.json."""
     units = expected(bench, workload, traced)
     obj = {
@@ -111,6 +120,8 @@ def build(bench: dict, workload: str, traced: bool, *, correct: bool,
     }
     if traced and breakdown is not None:
         obj["breakdown"] = breakdown
+    if checks is not None:
+        obj["checks"] = checks
     return obj
 
 

@@ -5,6 +5,9 @@ in the trace, so a renamed step is still found. A device's first and
 last event of it are left out: the profiler cuts the module that runs
 when it starts or stops (fixtures/: 1,164 and 60 ms beside three whole
 launches of 1,229 to 1,284 ms).
+
+``ministeps_per_launch`` comes from the runner's ``ctx``; a runner whose
+launch is one step of its program gives none, and an event is a step.
 """
 
 
@@ -21,9 +24,8 @@ def read(ctx: dict, spec: dict):
     for mods in tr.modules.values():
         whole = [dur for name, _, dur in mods if name == step][1:-1]
         if whole:
-            per_device.append(
-                sum(whole) / (len(whole) * ctx["ministeps_per_launch"])
-            )
+            ministeps = len(whole) * ctx.get("ministeps_per_launch", 1)
+            per_device.append(sum(whole) / ministeps)
     if not per_device:
         return None
     return 1e3 * sum(per_device) / len(per_device)

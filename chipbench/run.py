@@ -3,27 +3,63 @@
     python3 chipbench/run.py --workload <configuration>.<mix> --seed N \\
         --seconds S --trace 0|1 [--rehearsal]
 
-Sets up the configuration's trainer through ``apps/linear``'s normal
-objects, warms up the cell's own shapes, measures a closed loop for
-``--seconds``, checks the result and prints the contract's object as the
-last line of stdout (``lastline.emit``, the only place that prints it).
-Everything that belongs to one configuration, one traffic mix or one
-per-layer metric is a file found by the name in BENCHMARK.json; see
-README.md. ``--rehearsal`` (toy sizes on the CPU, never passed by the
-driver) is how the harness is debugged without a chip.
+The part of a run that is the same for every application: the clock
+(``T0``, ``setup_s``), the arguments, the look for the chips, the compile
+listener and the span sink, the window (which launches count, the
+deadline, when the counters are read, where the traced stretch starts and
+stops), the end-to-end formulas over the window's rows, the checks that
+hold for any program, ``device``, the trace reduction and the last line
+(``lastline.emit``, the only place that prints it). The trainer, its
+data, its reference and its own checks belong to the runner that the
+configuration's file names: ``"app": "<name>"`` is
+``chipbench/apps/<name>.py``, found by that name as a reader is by its
+own (README.md, "The runner's contract"). Everything that belongs to one
+configuration, one traffic mix or one per-layer metric is a file found
+by the name in BENCHMARK.json. ``--rehearsal`` (toy sizes on the CPU,
+never passed by the driver) is how the harness is debugged without a
+chip.
 """
 
 import time
 
 T0 = time.perf_counter()  # process start, as near as Python can say
 
+
+def steady_malloc() -> None:
+    """Fix glibc malloc's thresholds, as an operator does with
+    ``MALLOC_TOP_PAD_=67108864 MALLOC_TRIM_THRESHOLD_=1073741824
+    MALLOC_MMAP_THRESHOLD_=33554432`` (the driver starts this file
+    itself, so no environment can carry them). With the defaults a
+    thread's arena gives a 64 MB heap back to the kernel whenever it
+    stands empty and maps a new one at the next large block. A host
+    thread that makes and frees tens of MB of NumPy temporaries a batch
+    then either stays inside one heap or maps, faults in and unmaps one
+    every batch, by where its long-lived blocks happen to lie: a run
+    reads at one of two levels 13% apart, on any seed (PERF.md sections
+    2 and 6). A kept top (``top_pad`` of a heap's size stops the give-
+    back, the trim threshold the shrinking) and no block mapped alone
+    make every run the first kind. Before the first import, so that
+    every arena is born under the same rule; elsewhere than glibc there
+    is nothing to fix."""
+    try:
+        import ctypes
+
+        mallopt = ctypes.CDLL("libc.so.6").mallopt
+    except (OSError, AttributeError):
+        return
+    mallopt(-2, 64 << 20)  # M_TOP_PAD: an empty heap is kept
+    mallopt(-1, 1 << 30)  # M_TRIM_THRESHOLD: a free top is kept
+    mallopt(-3, 32 << 20)  # M_MMAP_THRESHOLD: the most glibc allows
+
+
+steady_malloc()
+
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import importlib  # noqa: E402
-import itertools  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
-import re  # noqa: E402
 import shutil  # noqa: E402
 import statistics  # noqa: E402
 import sys  # noqa: E402
@@ -33,7 +69,7 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 CACHE = os.path.join(HERE, "cache")  # listed in chipbench/.gitignore
 
-from chipbench import lastline, oracle, synth, trace  # noqa: E402
+from chipbench import hostspans, lastline, trace  # noqa: E402
 from chipbench.readers import registry_delta  # noqa: E402
 
 
@@ -45,21 +81,6 @@ def note(kind: str, **fields) -> None:
 def load_json(*parts) -> dict:
     with open(os.path.join(ROOT, *parts)) as f:
         return json.load(f)
-
-
-def conf_text(cfg: dict, rehearsal: bool) -> str:
-    """The configuration's ``.conf`` as it is run. A rehearsal swaps the
-    sizes the configuration's file lists for toy ones, same keys."""
-    with open(os.path.join(ROOT, cfg["conf"])) as f:
-        text = f.read()
-    if rehearsal:
-        for key, value in cfg["rehearsal"]["conf"].items():
-            text, n = re.subn(
-                rf"(?m)^(\s*{key}:\s*)\S+", rf"\g<1>{value}", text
-            )
-            if n != 1:
-                raise ValueError(f"{cfg['conf']}: {n} lines set {key}")
-    return text
 
 
 def counter_total(state: dict, name: str) -> float:
@@ -94,73 +115,50 @@ class ListSink:
 
 
 class Window:
-    """The benchmark's own spans around the trainer's two calls per
-    launch, ``_submit_prepped`` and ``collect``, wrapped on the instance
-    (the program's code is untouched), and what hangs on them: which
-    launches count, when the feed ends, when the counters are read and
-    when the profiler runs.
+    """Which launches count, when the feed may end, when the counters are
+    read and when the profiler runs. The runner tells it of each launch:
+    ``submitted()`` as the launch is dispatched, ``collected(row, ...)``
+    once its results are on the host.
 
     Until ``open()`` every launch is warm-up. The window's time runs
     from its first submit, where set-up ends; a launch counts if it was
     submitted before the deadline, and the feed ends at the first launch
-    boundary after it."""
+    boundary after it (``expired()``)."""
 
-    def __init__(self, worker, registry, mix: dict, seconds: float,
-                 launch_minibatches: int, trace_dir):
+    def __init__(self, registry, mix: dict, seconds: float, trace_dir):
         self.rows = []  # one dict per launch, in submission order
-        self.first = []  # the first minibatches fed, for the oracle
-        self.fed = self.batches = 0
         self.before = self.after = self.deadline = None
         self.trace_t0 = self.trace_t1 = None
         self.trace_cost = {}
         self._registry, self._mix = registry, mix
-        self._T, self._trace_dir = launch_minibatches, trace_dir
+        self._seconds, self._trace_dir = seconds, trace_dir
         self._opened = False
-        by_ts = {}
-        submit, collect = worker._submit_prepped, worker.collect
-        # the worker's running totals, not the record collect() returns:
-        # the scheduler's progress printer empties that one
-        total = worker.progress
 
-        def timed_submit(prepped, **kw):
-            now = time.perf_counter()
-            if self._opened and self.deadline is None:
-                self.deadline = now + seconds
-            row = {"submit": now, "collect": None,
-                   "counted": self._opened and now < self.deadline}
-            ts = submit(prepped, **kw)
-            by_ts[ts] = row
-            self.rows.append(row)
-            return ts
+    def submitted(self) -> dict:
+        """A launch is about to be dispatched: its row."""
+        now = time.perf_counter()
+        if self._opened and self.deadline is None:
+            self.deadline = now + self._seconds
+        row = {"submit": now, "collect": None,
+               "counted": self._opened and now < self.deadline}
+        self.rows.append(row)
+        return row
 
-        def timed_collect(ts):
-            n0, k0 = total.num_examples_processed, len(total.objective)
-            prog = collect(ts)
-            row = by_ts.pop(ts)
-            row["collect"] = time.perf_counter()
-            row["examples"] = total.num_examples_processed - n0
-            row["objective"] = sum(total.objective[k0:])
-            if self._opened:
-                self._after_collect(row["collect"])
-            return prog
+    def collected(self, row: dict, examples: int, objective: float) -> None:
+        """The launch of ``row`` is in: what it trained on and the sum of
+        its examples' losses."""
+        row["collect"] = time.perf_counter()
+        row["examples"], row["objective"] = examples, objective
+        if self._opened:
+            self._after_collect(row["collect"])
 
-        worker._submit_prepped = timed_submit
-        worker.collect = timed_collect
-
-    def feed(self, source):
-        """What the trainer reads. Runs on the ingest feeder thread."""
-        for batch in source:
-            if len(self.first) < self._mix["parity_minibatches"]:
-                self.first.append(batch)
-            self.batches += 1
-            self.fed += batch.n
-            yield batch
-            if (
-                self.deadline is not None
-                and self.batches % self._T == 0
-                and time.perf_counter() >= self.deadline
-            ):
-                return
+    def expired(self) -> bool:
+        """True once the deadline has passed: the runner then ends its
+        feed at the next launch boundary."""
+        return (
+            self.deadline is not None
+            and time.perf_counter() >= self.deadline
+        )
 
     def open(self) -> None:
         self.before = self._registry.export_state()
@@ -185,8 +183,8 @@ class Window:
             return
         import jax
 
-        # the traced stretch begins and ends here, on the trainer's
-        # thread, at a collect: a launch is queued behind, so the device
+        # the traced stretch begins and ends here, on the thread that
+        # collects: a launch is queued behind, so the device
         # does not wait for the profiler
         if self.trace_t0 is None:
             if done >= self._mix["trace_after_launches"]:
@@ -228,125 +226,56 @@ def prepare(args, bench: dict):
     return cell, entry, cfg, mix
 
 
-def make_data(cell: dict, mix: dict, seed: int) -> str:
-    """The cell's file, made from ``--seed`` and kept by seed, so that
-    only the first run with a seed generates it."""
-    from parameter_server_tpu.data.text_parser import ExampleParser
+@dataclasses.dataclass
+class Run:
+    """What a runner is given: the cell's entries and files as they are
+    run, ``--seed``, where the checkout and its cache are, and ``note``."""
 
-    if not ExampleParser(mix["format"]).use_native:
-        raise RuntimeError(
-            f"format {mix['format']!r} would take the Python line path"
-        )
-    path = os.path.join(
-        CACHE, "data",
-        f"{cell['traffic']}.r{mix['rows']}.v{mix['vocabulary']}.s{seed}.txt",
-    )
-    if not os.path.exists(path):
-        synth.write_criteo_file(path, mix["rows"], mix["vocabulary"], seed)
-    return path
+    cell: dict  # the entry under ``workloads``
+    entry: dict  # the entry under ``configs``
+    cfg: dict  # the configuration's file
+    mix: dict  # the traffic mix's file (a rehearsal's toy values merged)
+    seed: int
+    rehearsal: bool
+    root: str = ROOT
+    cache: str = CACHE
+    note: object = note  # note(kind, **fields): a line of evidence
 
 
-def build_trainer(entry: dict, cfg: dict, mix: dict, data: str,
-                  rehearsal: bool):
-    """Postoffice, scheduler, worker and reader as ``apps/linear/main.py``
-    builds them, from the benchmark's copy of the conf."""
-    from parameter_server_tpu.apps.linear.async_sgd import (
-        AsyncSGDScheduler,
-        AsyncSGDWorker,
-    )
-    from parameter_server_tpu.apps.linear.config import parse_conf
-    from parameter_server_tpu.learner.sgd import MinibatchReader
-    from parameter_server_tpu.system.postoffice import Postoffice
+class Checks:
+    """The checks that decide ``correct``, each printed with its numbers
+    as it is made: ``value`` is the number compared and ``limit`` what it
+    is held to."""
 
-    conf = parse_conf(conf_text(cfg, rehearsal))
-    sgd = conf.async_sgd
-    if not rehearsal:
-        # the configuration's file states the sizes; the .conf runs them
-        differ = {k: (v, getattr(sgd, k)) for k, v in cfg["async_sgd"].items()
-                  if getattr(sgd, k) != v}
-        if differ:
-            raise ValueError(f"{entry['file']} and {cfg['conf']}: {differ}")
-    po = Postoffice.instance().start(**cfg["mesh"])
-    # main.py's --heartbeat-timeout, set as an operator of these tables
-    # would: a worker beats only in collect(), and a cold compile of the
-    # step (18 s at 2^29) sits inside one
-    aux = po.start_aux(heartbeat_timeout=120.0)
-    aux.start(check_interval=2.0, dashboard_interval=0.0)
-    sched = AsyncSGDScheduler(conf)
-    sched.run()
-    worker = AsyncSGDWorker(conf)
-    worker.attach_monitor(sched)
-    aux.register(worker.name)
-    reader = MinibatchReader(
-        # one file reread in passes. The reader globs every entry, and a
-        # stat is dear in the chip machine's sandbox: 65,536 entries took
-        # 11 s of every set-up
-        files=[data] * 1024,
-        minibatch_size=sgd.minibatch,
-        data_format=mix["format"],
-    )
-    if sgd.tail_feature_freq > 0:
-        reader.init_filter(
-            sgd.countmin_n, sgd.countmin_k, sgd.tail_feature_freq
-        )
-    return conf, po, worker, reader
+    def __init__(self):
+        self.made = {}
+
+    def __call__(self, name, ok, *, value, limit, **numbers) -> None:
+        if isinstance(value, float) and not math.isfinite(value):
+            value = repr(value)  # the line stays JSON
+        self.made[name] = {"ok": bool(ok), "value": value, "limit": limit}
+        note("check", name=name, **self.made[name], **numbers)
+
+    def all_hold(self) -> bool:
+        return all(c["ok"] for c in self.made.values())
+
+    def to_stderr(self) -> None:
+        """Each number compared beside its limit, as the run's last
+        lines on standard error."""
+        for name, c in self.made.items():
+            print(
+                f"chipbench check {name}: value {c['value']!r} "
+                f"limit {c['limit']!r} ok {c['ok']}", file=sys.stderr,
+            )
+        sys.stderr.flush()
 
 
-def run_checks(win: Window, warm: list, rows: list, conf, cfg: dict,
-               worker, compiled_in_window: list, inv0: dict,
-               rehearsal: bool) -> bool:
-    """The checks that decide ``correct``, each printed with its
-    numbers."""
+def any_program_checks(check: Checks, win: Window, warm: list, rows: list,
+                       compiled_in_window: list, inv0: dict) -> None:
+    """What holds for any program: nothing compiled or fell back inside
+    the window, and every launch's loss is finite."""
     from parameter_server_tpu.telemetry import device as device_tel
-    from parameter_server_tpu.telemetry import learning
 
-    sgd = conf.async_sgd
-    T = max(1, sgd.steps_per_launch)
-    results = []
-
-    def check(name, ok, **numbers):
-        results.append(bool(ok))
-        note("check", name=name, ok=bool(ok), **numbers)
-
-    t = time.perf_counter()
-    dev_ll = sum(r["objective"] for r in warm) / sum(
-        r["examples"] for r in warm
-    )
-    lambdas = list(conf.penalty.lambda_) + [0.0]
-    ref_ll = oracle.progressive_logloss(
-        win.first, sgd.num_slots, conf.learning_rate.alpha,
-        conf.learning_rate.beta, lambdas[0], lambdas[1],
-    )
-    tol = max(0.01, 0.02 * ref_ll)
-    check(
-        "logloss_parity",
-        len(warm) * T == len(win.first) and abs(dev_ll - ref_ll) <= tol,
-        device=dev_ll, oracle=ref_ll, tolerance=tol,
-        minibatches=len(win.first), oracle_s=time.perf_counter() - t,
-    )
-    plane = learning.snapshot_all()[worker.name]
-    st = plane["staleness"]
-    tau = cfg["guarantees"]["max_delay"]
-    check(
-        "staleness_within_max_delay",
-        sgd.max_delay == tau and st["observed_max"] <= tau
-        and st["within_bound"],
-        max_delay=tau, conf_max_delay=sgd.max_delay,
-        observed_max=st["observed_max"], live_tau=st.get("live_tau"),
-    )
-    check(
-        "examples_confirmed", plane["examples"] == win.fed,
-        confirmed=plane["examples"], fed=win.fed,
-    )
-    paths = {
-        s["labels"]["path"]: s["value"]
-        for s in win.after["ps_ftrl_update_path_total"]["series"]
-    }
-    check(
-        "update_path_on_device",
-        paths and (rehearsal or not paths.get("ref")),
-        ministeps_by_path=paths, note=cfg.get("update_path_today"),
-    )
     inv = device_tel.snapshot()
     fallbacks = "ps_device_dispatch_fallbacks_total"
     numbers = {
@@ -359,19 +288,31 @@ def run_checks(win: Window, warm: list, rows: list, conf, cfg: dict,
     }
     check(
         "nothing_compiles_or_falls_back_in_window",
-        not any(numbers.values()), **numbers,
+        not any(numbers.values()),
+        value=sum(len(v) if isinstance(v, list) else v or 0
+                  for v in numbers.values()),
+        limit=0, **numbers,
         programs={n: f["calls"] for n, f in inv["functions"].items()
                   if f["calls"]},
     )
-    deaths = counter_total(win.after, "ps_recovery_deaths_total")
-    check("no_node_declared_dead", deaths == 0, deaths=deaths)
     bad = sum(not math.isfinite(r["objective"]) for r in warm + rows)
     check(
-        "losses_finite", bad == 0, launches=len(warm + rows), not_finite=bad,
+        "losses_finite", bad == 0, value=bad, limit=0,
+        launches=len(warm + rows),
         first=warm[0]["objective"] / warm[0]["examples"],
         last=rows[-1]["objective"] / rows[-1]["examples"],
     )
-    return all(results)
+
+
+def idle_buckets(bench: dict, workload: str) -> list:
+    """The ordered host-span buckets by which the cell's idle shares
+    split the device's idle time (``buckets`` of their metric files):
+    the breakdown names its gaps by the same ones."""
+    for m in lastline.cell_metrics(bench, workload, "per_layer"):
+        spec = load_json("chipbench", "metrics", m["name"] + ".json")
+        if "buckets" in spec:
+            return spec["buckets"]
+    return []
 
 
 def per_layer(bench: dict, workload: str, ctx: dict) -> dict:
@@ -407,37 +348,36 @@ def run_cell(args, bench: dict) -> int:
     from parameter_server_tpu.telemetry import registry as telreg
     from parameter_server_tpu.telemetry import spans
 
+    # the configuration's file names its application; without the key it
+    # is the one the benchmark began with
+    app = importlib.import_module(
+        "chipbench.apps." + cfg.get("app", "linear")
+    )
+    runner = app.Runner(
+        Run(cell, entry, cfg, mix, args.seed, args.rehearsal)
+    )
     compiles = Compiles(jax)
     t_imports = time.perf_counter()
-    data = make_data(cell, mix, args.seed)
+    runner.make_data()
     t_data = time.perf_counter()
-    conf, po, worker, reader = build_trainer(
-        entry, cfg, mix, data, args.rehearsal
-    )
-    t_built = time.perf_counter()
-    T = max(1, conf.async_sgd.steps_per_launch)
     trace_dir = (
         os.path.join(CACHE, "trace", args.workload) if args.trace else None
     )
-    win = Window(worker, telreg.default_registry(), mix, args.seconds, T,
-                 trace_dir)
+    win = Window(telreg.default_registry(), mix, args.seconds, trace_dir)
+    runner.build(win)
+    t_built = time.perf_counter()
     sink = ListSink()
     if args.trace:
         spans.install_sink(sink)
     try:
-        with reader:
-            source = win.feed(iter(reader))
-            # warm-up: this cell's shapes and no others
-            worker.train(
-                itertools.islice(source, mix["warmup_launches"] * T)
-            )
-            warm = list(win.rows)
-            device_tel.mark_warmup()
-            inv0 = device_tel.snapshot()
-            compiled_before = len(compiles.names)
-            wall0 = time.time()
-            win.open()
-            worker.train(source)
+        runner.warm_up()
+        warm = list(win.rows)
+        device_tel.mark_warmup()
+        inv0 = device_tel.snapshot()
+        compiled_before = len(compiles.names)
+        wall0 = time.time()
+        win.open()
+        runner.feed()
         t_drained = time.perf_counter()
         win.close()
         if args.trace and win.trace_t1 is None:
@@ -475,29 +415,15 @@ def run_cell(args, bench: dict) -> int:
         seconds=args.seconds, measured_s=last_collect - first_submit,
         launches=len(rows),
         launches_after_deadline=len(win.rows) - len(warm) - len(rows),
-        examples=examples, ministeps_per_launch=T,
-        launch_ms_min=1e3 * min(latencies),
+        examples=examples, launch_ms_min=1e3 * min(latencies),
         launch_ms_max=1e3 * max(latencies),
-        drain_s=t_drained - last_collect, data_passes=win.fed / mix["rows"],
+        drain_s=t_drained - last_collect, **runner.window_note(),
     )
-    counters = {"before": win.before, "after": win.after}
-    stages = {
-        stage: {
-            name: registry_delta.read(counters, {
-                "metric": "ps_ingest_stage_seconds", "field": field,
-                "labels": {"stage": stage},
-            })
-            for name, field in (("s", "sum"), ("batches", "count"))
-        }
-        for stage in sorted({
-            x["labels"]["stage"]
-            for x in win.after["ps_ingest_stage_seconds"]["series"]
-        })
-    }
-    note("ingest_stages_in_window", **stages)
-    correct = run_checks(
-        win, warm, rows, conf, cfg, worker,
-        compiles.names[compiled_before:], inv0, args.rehearsal,
+    runner.notes(win)
+    check = Checks()
+    runner.checks(win, warm, rows, check)
+    any_program_checks(
+        check, win, warm, rows, compiles.names[compiled_before:], inv0
     )
 
     device = {
@@ -514,15 +440,19 @@ def run_cell(args, bench: dict) -> int:
             # a CPU capture has no device track: rehearse the reduction
             # and the readers on the recorded TPU trace instead
             trace_file, kind = trace.FIXTURE, trace.FIXTURE_DEVICE_KIND
-        tr = trace.load(trace_file)
+        # host and device side of the one file, parsed once: the readers
+        # of host spans find the same capture in hostspans' cache
+        capture = hostspans.load(trace_file)
+        tr = capture.trace
         device.update(busy_s=tr.busy_s(), window_s=tr.window_s)
-        breakdown = trace.breakdown(tr)
+        breakdown = trace.breakdown(
+            capture, idle_buckets(bench, args.workload)
+        )
         values.update(per_layer(bench, args.workload, {
             "before": win.before, "after": win.after, "trace": tr,
             "spans": [e for e in sink.events
                       if e.get("t_wall", 0.0) >= wall0],
-            "ministeps_per_launch": T, "config": cfg,
-            "conf": conf.async_sgd, "device_kind": kind,
+            "config": cfg, "device_kind": kind, **runner.ctx(),
         }))
         note(
             "trace", file=os.path.relpath(trace_file, ROOT),
@@ -532,12 +462,14 @@ def run_cell(args, bench: dict) -> int:
             **win.trace_cost,
         )
 
-    po.stop()  # stops the aux runtime and the executors' threads
+    runner.stop()
+    check.to_stderr()
     return lastline.emit(
-        bench, args.workload, bool(args.trace), correct=correct,
+        bench, args.workload, bool(args.trace), correct=check.all_hold(),
         attempted=len(rows),
         failed=sum(not math.isfinite(r["objective"]) for r in rows),
         values=values, device=device, breakdown=breakdown,
+        checks=check.made,
     )
 
 

@@ -17,7 +17,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
-from chipbench import arith, lastline, trace  # noqa: E402
+from chipbench import arith, hostspans, lastline, trace  # noqa: E402
 from chipbench.readers import (  # noqa: E402
     trace_collective_exposed,
     trace_idle_share,
@@ -33,6 +33,15 @@ KNOWN = {
     "update_share": 85.96, "busy_s": 4.981068, "window_s": 4.998525,
     "step_device_ms": 156.563,
 }
+
+
+# hostspans.FIXTURE's idle gaps as ``breakdown`` names them under the
+# idle shares' buckets: the three long ones lie between programs while the
+# trainer waits for the uploader, the short ones inside a program
+KNOWN_GAPS = [
+    ("ingest", 0.15989466), ("ingest", 0.06984482), ("ingest", 0.054001905),
+    ("in_program", 2.3e-06), ("in_program", 2.253e-06),
+]
 
 
 def near(name, got, want, tol):
@@ -65,7 +74,7 @@ def check_trace() -> None:
     if trace_collective_exposed.read(ctx, spec) is not None:
         raise SystemExit("selfcheck: one chip's trace read as having collectives")
     print("ok  absent scope and absent collectives read as nothing")
-    top = trace.breakdown(tr)["device_ops"][0]
+    top = trace.breakdown(hostspans.load(trace.FIXTURE))["device_ops"][0]
     if top[0] != "ps_update/scatter fusion.46":
         raise SystemExit(f"selfcheck: top op is {top}")
     print(f"ok  top op {top[0]} {top[1]:.4f} s")
@@ -79,6 +88,27 @@ def check_trace() -> None:
         print("ok  an unlisted device_kind raises")
     else:
         raise SystemExit("selfcheck: an unlisted device_kind has a peak")
+
+
+def check_idle_gaps() -> None:
+    with open(os.path.join(
+        HERE, "metrics", "idle_waiting_ingest_share.json"
+    )) as f:
+        buckets = json.load(f)["buckets"]
+    capture = hostspans.load(hostspans.FIXTURE)
+    gaps = trace.breakdown(capture, buckets)["idle_gaps"]
+    if len(gaps) != 10:
+        raise SystemExit(f"selfcheck: {len(gaps)} idle gaps, expected 10")
+    for i, (name, seconds) in enumerate(KNOWN_GAPS):
+        if gaps[i][0] != name:
+            raise SystemExit(f"selfcheck: idle gap {i} is {gaps[i]}")
+        near(f"idle gap {i} ({name}) s", gaps[i][1], seconds, 1e-9)
+    bare = trace.breakdown(capture)["idle_gaps"]
+    if {g[0] for g in bare} != {"in_program", "unattributed"} or [
+        g[1] for g in bare
+    ] != [g[1] for g in gaps]:
+        raise SystemExit(f"selfcheck: gaps without buckets are {bare}")
+    print("ok  without buckets the same gaps read in_program or unattributed")
 
 
 def check_last_line() -> None:
@@ -95,6 +125,7 @@ def check_last_line() -> None:
             values={n: 1.5 for n in lastline.expected(bench, cell, traced)},
             device=device,
             breakdown={"device_ops": [["a b", 1.0]], "idle_gaps": []},
+            checks={"losses_finite": {"ok": True, "value": 0, "limit": 0}},
         )
         if lastline.faults(good, bench, cell, traced):
             raise SystemExit(
@@ -122,6 +153,10 @@ def check_last_line() -> None:
             lambda o: o["metrics"].update(x={"value": 1, "unit": "s"}))
         bad("an extra top-level key", lambda o: o.update(seconds=20))
         bad("a missing device key", lambda o: o["device"].pop("count"))
+        bad("a check without its limit",
+            lambda o: o["checks"]["losses_finite"].pop("limit"))
+        bad("a key after checks", lambda o: o.update(breakdown=o.pop(
+            "breakdown", {"device_ops": [], "idle_gaps": []})))
         if traced:
             bad("busy_s 0", lambda o: o["device"].update(busy_s=0.0))
             bad("busy_s > window_s", lambda o: o["device"].update(busy_s=5.1))
@@ -137,5 +172,6 @@ def check_last_line() -> None:
 
 if __name__ == "__main__":
     check_trace()
+    check_idle_gaps()
     check_last_line()
     print("selfcheck: passed")

@@ -189,32 +189,34 @@ def op_label(op: Op) -> str:
     return f"{head}{prim} {op.name}"
 
 
-def breakdown(trace: Trace, top: int = 10) -> dict:
-    """The contract's optional ``breakdown``: the device ops that took
-    most self time (summed over devices and calls), and the longest idle
-    gaps of any device. A gap is ``unattributed`` until host spans sit
-    on the trace's clock (PERF.md, Open questions)."""
+def breakdown(capture, buckets=(), top: int = 10) -> dict:
+    """The contract's optional ``breakdown`` of one capture
+    (``hostspans.Capture``: host and device side of one file): the device
+    ops that took most self time (summed over devices and calls), and the
+    longest idle gaps of any device, each named by what the host was
+    doing in it.
+
+    ``buckets`` is the ordered ``{name, spans}`` list of the idle shares'
+    metric files. A gap takes the name under which
+    ``readers/trace_idle_by_host`` counts most of it (``in_program``, a
+    bucket's name, ``unattributed``), so that the breakdown and the
+    ``idle_*`` shares tell one story."""
+    # here and not at the top: the reader imports this module
+    from chipbench.readers import trace_idle_by_host as by_host
+
     by_label: dict = {}
-    for ops in trace.ops.values():
+    for ops in capture.trace.ops.values():
         for o in ops:
             if o.self_s > 0:
                 k = op_label(o)
                 by_label[k] = by_label.get(k, 0.0) + o.self_s
-    gaps = []
-    for ops in trace.ops.values():
-        edge = trace.begin
-        for a, b in sorted((o.start, o.start + o.dur) for o in ops):
-            if a > edge:
-                gaps.append(a - edge)
-            edge = max(edge, b)
-        if trace.end > edge:
-            gaps.append(trace.end - edge)
+    gaps = by_host.gaps_line(
+        capture, by_host.by_bucket(capture, list(buckets)), top
+    )["gaps"]
     return {
         "device_ops": [
             [k, v] for k, v in
             sorted(by_label.items(), key=lambda kv: -kv[1])[:top]
         ],
-        "idle_gaps": [
-            ["unattributed", g] for g in sorted(gaps, reverse=True)[:top]
-        ],
+        "idle_gaps": [[g["bucket"], g["s"]] for g in gaps],
     }
