@@ -7,7 +7,7 @@ file (or a built-in synthetic corpus) and generate from it:
         [--remat] [--bf16] [--moe-every K] [--num-servers T] \
         [--ckpt-dir DIR] [--save-every N] [--resume] \
         [--prompt "text"] [--gen-tokens N] [--temperature T] [--top-k K] \
-        [--top-p P] [--n-kv-heads G]
+        [--top-p P] [--n-kv-heads G] [--model-config FILE]
 
 The model family's end-to-end surface, like apps/linear (conf CLI) and
 apps/nn: tokens are raw bytes (vocab 256, no tokenizer dependency), the
@@ -15,12 +15,18 @@ sequence axis shards over every available device, and every parallelism/
 memory knob of models/transformer.py is reachable from the command line.
 Without --data it trains on a synthetic periodic-byte corpus so the demo
 runs anywhere.
+
+``--model-config FILE`` takes the model from a description file instead
+of the width flags (``trainer.model_from_description``: latent attention,
+a dropless top-k expert layer beside a shared expert, RMSNorm, an untied
+head over the file's vocabulary); bytes are then ids below 256 of that
+vocabulary. Such a model trains here; the generation flags refuse it by
+name, because the serving forwards have no latent cache.
 """
 
 from __future__ import annotations
 
 import argparse
-import functools
 import sys
 
 import numpy as np
@@ -83,6 +89,12 @@ def run(argv=None) -> dict:
     ap.add_argument("--bf16", action="store_true",
                     help="bfloat16 decoder activations")
     ap.add_argument("--moe-every", type=int, default=0)
+    ap.add_argument("--model-config", metavar="FILE", default=None,
+                    help="take the model from a description file (the "
+                    "published config.json keys, which experts and "
+                    "vocabulary rows this program holds) instead of the "
+                    "width flags; see chipbench/configs/"
+                    "mistral_small4_ep16.json")
     ap.add_argument("--zero1", action="store_true",
                     help="ZeRO-1: shard Adam moments over the data axis "
                     "(per-device optimizer memory / n_data; composes "
@@ -183,15 +195,19 @@ def run(argv=None) -> dict:
 
     from ...models.transformer import (
         LMConfig,
-        init_lm,
         lm_generate,
         lm_loss,
         lm_loss_with_targets,
-        shard_lm_params,
+        refuse_serving,
         shard_tokens,
         zigzag_lm_arrays,
     )
     from ...parallel import mesh as meshlib
+    from .trainer import (
+        build_trainer,
+        load_description,
+        model_from_description,
+    )
 
     n_dev = len(jax.devices())
     if args.num_servers < 1 or n_dev % args.num_servers:
@@ -202,19 +218,40 @@ def run(argv=None) -> dict:
     n_data = n_dev // args.num_servers
     mesh = meshlib.make_mesh(num_data=n_data, num_server=args.num_servers)
     try:
-        cfg = LMConfig(
-            vocab=256, d_model=args.d_model, n_heads=args.n_heads,
-            n_layers=args.n_layers, d_ff=args.d_ff, attention=args.attention,
-            window=args.window, remat=args.remat,
-            compute_dtype="bfloat16" if args.bf16 else "float32",
-            moe_every=args.moe_every, n_kv_heads=args.n_kv_heads,
-            rope=args.rope, rope_theta=args.rope_theta,
-            kv_cache_dtype=None if args.kv_cache == "auto" else args.kv_cache,
-        )
+        if args.model_config:
+            cfg = model_from_description(
+                load_description(args.model_config),
+                attention=args.attention, remat=args.remat, bf16=args.bf16,
+            )
+        else:
+            cfg = LMConfig(
+                vocab=256, d_model=args.d_model, n_heads=args.n_heads,
+                n_layers=args.n_layers, d_ff=args.d_ff,
+                attention=args.attention,
+                window=args.window, remat=args.remat,
+                compute_dtype="bfloat16" if args.bf16 else "float32",
+                moe_every=args.moe_every, n_kv_heads=args.n_kv_heads,
+                rope=args.rope, rope_theta=args.rope_theta,
+                kv_cache_dtype=(
+                    None if args.kv_cache == "auto" else args.kv_cache
+                ),
+            )
     except ValueError as e:
         # LMConfig rejects invalid combinations (e.g. --window with
         # --attention a2a); surface them as flag errors, not tracebacks
         ap.error(str(e))
+    if args.model_config:
+        if args.num_servers > 1 or args.fsdp:
+            ap.error(
+                "--model-config: the new layer kinds have no tensor-"
+                "parallel or FSDP placement yet (--num-servers 1, no "
+                "--fsdp)"
+            )
+        if args.prompt is not None:
+            try:
+                refuse_serving(cfg, "--prompt")
+            except NotImplementedError as e:
+                ap.error(str(e))
     zig = args.attention == "ring_zigzag"
     if args.seq_len % (2 * n_data if zig else n_data):
         ap.error(f"--seq-len must divide by {2 * n_data if zig else n_data}")
@@ -229,8 +266,10 @@ def run(argv=None) -> dict:
     if args.top_k is not None:
         if args.temperature == 0:
             ap.error("--top-k requires --temperature > 0 (sampling)")
-        if not 1 <= args.top_k <= 256:
-            ap.error(f"--top-k must be in [1, 256], got {args.top_k}")
+        if not 1 <= args.top_k <= cfg.vocab:
+            ap.error(
+                f"--top-k must be in [1, {cfg.vocab}], got {args.top_k}"
+            )
     if args.top_p is not None:
         if args.temperature == 0:
             ap.error("--top-p requires --temperature > 0 (sampling)")
@@ -291,29 +330,10 @@ def run(argv=None) -> dict:
                 f"for --seq-len {args.seq_len} "
                 f"(train {corpus.size} / eval {eval_corpus.size} bytes)"
             )
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    params = init_lm(jax.random.PRNGKey(args.seed), cfg)
-    if args.num_servers > 1:
-        # Megatron column/row placement; GSPMD inserts the psums and the
-        # adam update preserves the sharding
-        params = shard_lm_params(params, mesh, "server")
-    else:
-        # explicitly REPLICATED over the mesh (not an uncommitted
-        # single-device default): checkpoint restore places leaves onto
-        # the template's sharding, so the template must carry the real
-        # training placement or a resumed run would train mis-placed
-        params = jax.device_put(params, NamedSharding(mesh, PartitionSpec()))
-    if args.fsdp:
-        from ...models.transformer import fsdp_shard_lm_params
-
-        # ZeRO-3: params (and, via tx.init inheritance, grads + moments)
-        # sharded over the data axis; composes with --num-servers (TP
-        # leaves keep their server dim and gain the data axis elsewhere)
-        params = fsdp_shard_lm_params(params, mesh, "data")
-    # LR schedule -> clip -> adam -> (optional) microbatch accumulation.
-    # The schedule/accumulation counters live in the optimizer state, so
-    # checkpoint resume continues the schedule where it left off.
+    # LR schedule -> clip -> optimizer -> (optional) microbatch
+    # accumulation. The schedule/accumulation counters live in the
+    # optimizer state, so checkpoint resume continues the schedule where
+    # it left off.
     lr_sched = (
         optax.warmup_cosine_decay_schedule(
             init_value=0.0, peak_value=args.lr,
@@ -324,38 +344,15 @@ def run(argv=None) -> dict:
         if args.warmup
         else args.lr
     )
-    chain = []
-    if args.clip_norm:
-        chain.append(optax.clip_by_global_norm(args.clip_norm))
-    if args.optimizer == "adafactor":
-        # factored second moment: the per-param optimizer state is
-        # O(rows+cols), the low-memory choice beside --zero1/--fsdp
-        chain.append(optax.adafactor(learning_rate=lr_sched))
-    elif args.optimizer == "lion":
-        chain.append(optax.lion(lr_sched))
-    else:
-        chain.append(optax.adam(lr_sched))
-    tx = optax.chain(*chain)
-    if args.grad_accum > 1:
-        # each CLI "step" is one microbatch; the inner optimizer (and
-        # its schedule) advances every grad_accum-th
-        tx = optax.MultiSteps(tx, every_k_schedule=args.grad_accum)
-    opt = tx.init(params)  # zeros_like inherits each param's placement
-    if args.zero1:
-        from ...models.transformer import zero1_shard_opt_state
-
-        # ZeRO-1: moments sharded over the data axis (every leaf comes
-        # back mesh-committed, scalars replicated)
-        opt = zero1_shard_opt_state(opt, mesh, "data")
-    else:
-        # freshly-created leaves (adam's step count) aren't mesh-placed —
-        # pin them replicated so the restore template is fully committed
-        opt = jax.tree.map(
-            lambda x: x
-            if isinstance(getattr(x, "sharding", None), NamedSharding)
-            else jax.device_put(x, NamedSharding(mesh, PartitionSpec())),
-            opt,
-        )
+    trainer = build_trainer(
+        cfg, mesh, optimizer=args.optimizer, lr=lr_sched,
+        clip_norm=args.clip_norm, grad_accum=args.grad_accum,
+        steps_per_launch=spl,
+    )
+    trainer.init(
+        args.seed, tensor_parallel=args.num_servers > 1, fsdp=args.fsdp,
+        zero1=args.zero1,
+    )
 
     mgr = None
     start_step = 0
@@ -367,12 +364,13 @@ def run(argv=None) -> dict:
             latest = mgr.latest_step()
             if latest is not None:
                 tree = mgr.restore(
-                    latest, like={"params": params, "opt": opt}
+                    latest,
+                    like={"params": trainer.params, "opt": trainer.opt},
                 )
                 # restore device_puts every leaf onto the template's
                 # sharding — which carries the real training placement
                 # (replicated, or Megatron-split under --num-servers)
-                params, opt = tree["params"], tree["opt"]
+                trainer.params, trainer.opt = tree["params"], tree["opt"]
                 start_step = latest
                 print(f"resumed from step {latest}", flush=True)
     elif args.save_every or args.resume:
@@ -391,45 +389,10 @@ def run(argv=None) -> dict:
             f"--steps-per-launch {spl}"
         )
 
-    # donate params + opt state: this loop always rebinds both, and the
-    # aliasing halves the model-state HBM footprint (params + Adam
-    # moments are the dominant buffers at scale). One optimizer step:
-    def one(p, opt, *data):
-        if zig:
-            loss, g = jax.value_and_grad(lm_loss_with_targets)(
-                p, *data, cfg, mesh, "data"
-            )
-        else:
-            loss, g = jax.value_and_grad(lm_loss)(p, *data, cfg, mesh, "data")
-        up, opt = tx.update(g, opt, p)
-        return optax.apply_updates(p, up), opt, loss
-
-    if spl == 1:
-        step = jax.jit(one, donate_argnums=(0, 1))
-    else:
-        # launch = spl sequential steps in one program (scan carries
-        # params+opt; each data array gains a leading [spl] dim) —
-        # identical trajectory, spl-1 fewer dispatch round trips
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def step(p, opt, *stacks):
-            def body(carry, xs):
-                p2, opt2, loss = one(*carry, *xs)
-                return (p2, opt2), loss
-            (p, opt), losses = jax.lax.scan(body, (p, opt), stacks)
-            return p, opt, losses[-1]
-
     def launch_data():
         """Sharded device arrays for one launch ([spl, ...] when fused)."""
-        batches = [sample_tokens() for _ in range(spl)]
-        if zig:
-            arrs = [zigzag_lm_arrays(t, n_data) for t in batches]
-            grouped = list(zip(*arrs))  # (toks), (tgts), (wts)
-        else:
-            grouped = [batches]
-        return tuple(
-            shard_tokens(g[0] if spl == 1 else np.stack(g), mesh)
-            for g in grouped
-        )
+        with trainer.loop_phase("wait_ingest"):
+            return trainer.place([sample_tokens() for _ in range(spl)])
 
     eval_fn = None
     if args.eval_every:
@@ -485,15 +448,23 @@ def run(argv=None) -> dict:
     loop_raised = False
     losses = []
     generated = None
+    pending = None  # the launch dispatched last, not yet collected
     try:
         with device_trace(args.profile):
             for i in range(start_step + spl, args.steps + 1, spl):
-                params, opt, loss = step(params, opt, *launch_data())
+                # the next launch is queued before the last one is waited
+                # for, so collecting (the loss, the counters) costs the
+                # device nothing
+                launch = trainer.submit(launch_data())
+                if pending is not None:
+                    trainer.collect(pending)
+                pending = launch
                 report = i % args.report_every < spl or i == args.steps
                 ev = None
                 rec = None
                 if report:
-                    ll = float(loss)
+                    ll, _ = trainer.collect(pending)
+                    pending = None
                     losses.append((i, ll))
                     print(f"{i:>5} {ll:>9.4f} {ll / np.log(2):>10.4f}",
                           flush=True)
@@ -516,7 +487,7 @@ def run(argv=None) -> dict:
                     i % args.eval_every < spl or i == args.steps
                 ):
                     ev_t0 = _time.perf_counter()
-                    ev = eval_fn(params)
+                    ev = eval_fn(trainer.params)
                     # shift the open window past the eval's wall time
                     last_t += _time.perf_counter() - ev_t0
                     print(
@@ -547,7 +518,9 @@ def run(argv=None) -> dict:
                     # --save-every. Async: the host snapshot is copied
                     # here (donation-safe), the disk write overlaps the
                     # next training steps.
-                    mgr.save_async(i, {"params": params, "opt": opt})
+                    mgr.save_async(
+                        i, {"params": trainer.params, "opt": trainer.opt}
+                    )
     except BaseException:
         # an explicit flag, NOT sys.exc_info(): inside the drain's
         # except handler below exc_info reports the exception BEING
@@ -576,6 +549,7 @@ def run(argv=None) -> dict:
                 print(f"async checkpoint failure during shutdown: {e}",
                       file=sys.stderr)
 
+    params = trainer.params
     if args.prompt is not None:
         prompt = np.frombuffer(
             args.prompt.encode("utf-8", "replace") or b"\n", np.uint8

@@ -1,0 +1,365 @@
+"""The LM trainer the CLI builds, reachable by any caller in the process:
+model from flags or from a description file, optimizer chain, donated
+step, ``steps_per_launch``, and a launch's two halves (``submit``,
+``collect``) with their spans and counters.
+
+``apps/lm/main.py`` and the benchmark's runner (``chipbench/apps/lm.py``)
+both go through :func:`build_trainer`.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import functools
+import json
+from typing import Optional
+
+import numpy as np
+
+# trainer-thread phases, named as the linear trainer names its own
+# (learner/sgd.py): the idle buckets of a device trace read these spans
+_LOOP_SPANS = {
+    "wait_ingest": "train.wait_ingest",
+    "submit": "train.submit",
+    "collect_wait": "train.collect.wait",
+    "collect_host": "train.collect.host",
+}
+
+
+def load_description(path: str) -> dict:
+    """A model description file: the published ``config.json`` keys at the
+    top level, ``published`` (counts that were cut), ``share`` (which
+    experts this program holds) and ``train`` (what the CLI's flags
+    would say)."""
+    with open(path) as f:
+        return json.load(f)
+
+
+def model_from_description(desc: dict, *, attention: str = "ring_flash",
+                           remat: bool = False, bf16: bool = False):
+    """An ``LMConfig`` for a ``mistral4`` description: latent attention
+    and a top-k expert layer beside shared experts in every layer,
+    RMSNorm, an untied head."""
+    from ...models.latent_attention import MLAConfig, YarnRope
+    from ...models.moe import TopKMoEConfig
+    from ...models.transformer import LMConfig
+
+    if desc.get("model_type") != "mistral4":
+        raise ValueError(
+            f"model_type {desc.get('model_type')!r}: only mistral4 is "
+            "described here"
+        )
+    if desc["hidden_act"] != "silu" or desc.get("attention_bias") or desc.get(
+        "mlp_bias"
+    ):
+        raise ValueError("the layer is a gated SiLU FFN without biases")
+    if desc.get("n_group", 1) != 1 or desc.get("topk_group", 1) != 1:
+        raise ValueError("group-limited routing is not built")
+    if desc.get("first_k_dense_replace", 0) != 0:
+        raise ValueError(
+            "first_k_dense_replace != 0: leading dense layers are not "
+            "described here"
+        )
+    rp = desc["rope_parameters"]
+    yarn = None
+    if rp.get("rope_type", rp.get("type")) == "yarn":
+        yarn = YarnRope(
+            factor=rp["factor"],
+            original_max_position=rp["original_max_position_embeddings"],
+            beta_fast=rp["beta_fast"], beta_slow=rp["beta_slow"],
+            mscale=rp.get("mscale", 1.0),
+            mscale_all_dim=rp.get("mscale_all_dim", 0.0),
+            position_scale_beta=rp.get("llama_4_scaling_beta", 0.0),
+        )
+    published, share = desc.get("published", {}), desc.get("share", {})
+    n_layers = desc["num_hidden_layers"]
+    return LMConfig(
+        vocab=desc["vocab_size"], d_model=desc["hidden_size"],
+        n_heads=desc["num_attention_heads"], n_layers=n_layers,
+        d_ff=desc["intermediate_size"], attention=attention, remat=remat,
+        compute_dtype="bfloat16" if bf16 else "float32",
+        rope_theta=rp["rope_theta"], tie_head=desc["tie_word_embeddings"],
+        norm="rmsnorm", norm_eps=desc["rms_norm_eps"], ffn_act="swiglu",
+        scale_emb=False, layers=(("mla", "moe"),) * n_layers,
+        mla=MLAConfig(
+            q_lora_rank=desc["q_lora_rank"], kv_lora_rank=desc["kv_lora_rank"],
+            qk_nope_head_dim=desc["qk_nope_head_dim"],
+            qk_rope_head_dim=desc["qk_rope_head_dim"],
+            v_head_dim=desc["v_head_dim"],
+            rope_interleave=desc["rope_interleave"], yarn=yarn,
+        ),
+        moe=TopKMoEConfig(
+            n_experts=published.get(
+                "n_routed_experts", desc["n_routed_experts"]
+            ),
+            top_k=desc["num_experts_per_tok"],
+            d_expert=desc["moe_intermediate_size"],
+            n_shared=desc["n_shared_experts"],
+            experts_held=desc["n_routed_experts"],
+            expert_offset=share.get("expert_offset", 0),
+            norm_topk_prob=desc["norm_topk_prob"],
+            routed_scaling_factor=float(desc["routed_scaling_factor"]),
+        ),
+    )
+
+
+def make_optimizer(name: str, lr, *, clip_norm: Optional[float] = None,
+                   grad_accum: int = 1):
+    """clip -> adam | adafactor | lion -> (optional) microbatch
+    accumulation. ``lr`` is a number or an optax schedule; the schedule
+    and accumulation counters live in the optimizer state."""
+    import optax
+
+    chain = []
+    if clip_norm:
+        chain.append(optax.clip_by_global_norm(clip_norm))
+    # adafactor: factored second moment, the per-param optimizer state is
+    # O(rows+cols), the low-memory choice beside --zero1/--fsdp
+    makers = {
+        "adam": optax.adam, "adafactor": optax.adafactor, "lion": optax.lion,
+    }
+    if name not in makers:
+        raise ValueError(f"optimizer {name!r}: adam, adafactor or lion")
+    chain.append(makers[name](learning_rate=lr))
+    tx = optax.chain(*chain)
+    if grad_accum > 1:
+        # each "step" is one microbatch; the inner optimizer (and its
+        # schedule) advances every grad_accum-th
+        tx = optax.MultiSteps(tx, every_k_schedule=grad_accum)
+    return tx
+
+
+@dataclasses.dataclass
+class Launch:
+    """One dispatched launch: what ``collect`` waits for."""
+
+    loss: object  # device scalar: the launch's last step's loss
+    # device arrays (``lm_forward_with_stats``): ``expert_rows`` summed
+    # over the launch's steps; its last step's choices and router probes
+    stats: dict
+    tokens: int
+
+
+class Trainer:
+    """Params, optimizer state and the donated step of one LM.
+
+    A launch is ``steps_per_launch`` optimizer steps in one program
+    (``lax.scan`` carries params and optimizer state): ``submit(data)``
+    dispatches it and rebinds the state, ``collect(launch)`` waits for
+    its loss and counts what it computed."""
+
+    def __init__(self, cfg, mesh, tx, *, steps_per_launch: int = 1):
+        import jax
+        import optax
+
+        from ...models.transformer import (
+            lm_loss_and_stats,
+            next_token_targets,
+        )
+        from ...telemetry import registry as telemetry_registry
+
+        self.cfg, self.mesh, self.tx = cfg, mesh, tx
+        self.spl = steps_per_launch
+        self.zig = cfg.attention == "ring_zigzag"
+        self.n_data = mesh.shape["data"]
+        self.params = self.opt = None
+        self._counters, self._loop_seconds = None, {}
+        if telemetry_registry.enabled():
+            from ...telemetry.instruments import (
+                app_instruments,
+                lm_instruments,
+            )
+
+            reg = telemetry_registry.default_registry()
+            self._counters = lm_instruments(reg)
+            loop = app_instruments(reg)["loop_seconds"]
+            self._loop_seconds = {
+                phase: loop.labels(phase=phase) for phase in _LOOP_SPANS
+            }
+
+        # donate params + opt state: a launch always rebinds both, and
+        # the aliasing halves the model-state HBM footprint. One step:
+        def one(p, opt, tokens, *targets):
+            if not targets:
+                targets = next_token_targets(tokens)
+            (loss, stats), g = jax.value_and_grad(
+                lm_loss_and_stats, has_aux=True
+            )(p, tokens, *targets, cfg, mesh, "data")
+            with jax.named_scope("lm_opt"):
+                up, opt = tx.update(g, opt, p)
+                p = optax.apply_updates(p, up)
+            return p, opt, loss, stats
+
+        if self.spl == 1:
+            self.step = jax.jit(one, donate_argnums=(0, 1))
+        else:
+            # launch = spl sequential steps in one program (each data
+            # array gains a leading [spl] dim) — identical trajectory,
+            # spl-1 fewer dispatch round trips
+            @functools.partial(jax.jit, donate_argnums=(0, 1))
+            def step(p, opt, *stacks):
+                def body(carry, xs):
+                    p2, opt2, loss, stats = one(*carry, *xs)
+                    return (p2, opt2), (loss, stats)
+
+                (p, opt), (losses, stats) = jax.lax.scan(
+                    body, (p, opt), stacks
+                )
+                # counts add up over the launch's steps; the choices
+                # and probes kept are the last step's
+                return p, opt, losses[-1], {
+                    k: v.sum(0) if k == "expert_rows" else v[-1]
+                    for k, v in stats.items()
+                }
+
+            self.step = step
+
+    # -- state -------------------------------------------------------------
+
+    def init(self, seed: int, **placement) -> None:
+        """Weights from ``seed``, made on the device (``init_lm``);
+        ``placement``: see :meth:`load`."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from ...models.transformer import init_lm
+
+        # explicitly REPLICATED over the mesh (not an uncommitted
+        # single-device default): checkpoint restore places leaves onto
+        # the template's sharding, so the template must carry the real
+        # training placement or a resumed run would train mis-placed
+        replicated = NamedSharding(self.mesh, PartitionSpec())
+        self.load(jax.jit(
+            init_lm, static_argnums=1, out_shardings=replicated
+        )(jax.random.PRNGKey(seed), self.cfg), **placement)
+
+    def load(self, params: dict, *, tensor_parallel: bool = False,
+             fsdp: bool = False, zero1: bool = False) -> None:
+        """Adopt weights a caller made, replicated over the mesh (the
+        leaves of ``init_lm``, by name and shape, or it raises), place
+        them, and start the optimizer's state from them."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from ...models.transformer import (
+            fsdp_shard_lm_params,
+            init_lm,
+            shard_lm_params,
+            zero1_shard_opt_state,
+        )
+
+        want = jax.eval_shape(
+            lambda: init_lm(jax.random.PRNGKey(0), self.cfg)
+        )
+        got = {k: (v.shape, v.dtype) for k, v in params.items()}
+        if got != {k: (v.shape, v.dtype) for k, v in want.items()}:
+            odd = sorted(
+                k for k in set(got) | set(want)
+                if k not in want or got.get(k) != (want[k].shape, want[k].dtype)
+            )
+            raise ValueError(
+                f"these are not the model's leaves: {odd[:4]} differ in "
+                "name, shape or dtype"
+            )
+        replicated = NamedSharding(self.mesh, PartitionSpec())
+        if tensor_parallel:
+            # Megatron column/row placement; GSPMD inserts the psums and
+            # the optimizer update preserves the sharding
+            params = shard_lm_params(params, self.mesh, "server")
+        if fsdp:
+            # ZeRO-3: params (and, via tx.init inheritance, grads +
+            # moments) sharded over the data axis; composes with tensor
+            # parallelism (those leaves keep their server dim)
+            params = fsdp_shard_lm_params(params, self.mesh, "data")
+        opt = self.tx.init(params)  # zeros_like inherits each placement
+        if zero1:
+            # ZeRO-1: moments sharded over the data axis (every leaf
+            # comes back mesh-committed, scalars replicated)
+            opt = zero1_shard_opt_state(opt, self.mesh, "data")
+        else:
+            # freshly-created leaves (a step count) aren't mesh-placed —
+            # pin them replicated so a restore template is committed
+            opt = jax.tree.map(
+                lambda x: x
+                if isinstance(getattr(x, "sharding", None), NamedSharding)
+                else jax.device_put(x, replicated),
+                opt,
+            )
+        self.params, self.opt = params, opt
+
+    # -- a launch ----------------------------------------------------------
+
+    @contextlib.contextmanager
+    def loop_phase(self, phase: str):
+        """One phase of the training loop on the trainer's thread:
+        ``ps_train_loop_seconds{phase}`` and its ``train.*`` span."""
+        from ...telemetry import spans
+
+        with spans.span(
+            _LOOP_SPANS[phase], histogram=self._loop_seconds.get(phase)
+        ):
+            yield
+
+    def place(self, batches) -> tuple:
+        """Device arrays for one launch from its ``steps_per_launch``
+        host batches [B, S] (a leading [spl] dim when fused), the
+        sequence sharded over the data axis."""
+        from ...models.transformer import shard_tokens, zigzag_lm_arrays
+
+        if len(batches) != self.spl:
+            raise ValueError(
+                f"a launch is {self.spl} batches, got {len(batches)}"
+            )
+        if self.zig:
+            grouped = list(zip(
+                *(zigzag_lm_arrays(t, self.n_data) for t in batches)
+            ))  # (toks), (tgts), (wts)
+        else:
+            grouped = [batches]
+        return tuple(
+            shard_tokens(g[0] if self.spl == 1 else np.stack(g), self.mesh)
+            for g in grouped
+        )
+
+    def submit(self, data: tuple) -> Launch:
+        with self.loop_phase("submit"):
+            self.params, self.opt, loss, stats = self.step(
+                self.params, self.opt, *data
+            )
+        return Launch(loss, stats, int(np.prod(data[0].shape)))
+
+    def collect(self, launch: Launch):
+        """``(loss, counts)`` of a launch on the host, counted: its
+        ``expert_rows`` where the model has the dropless layer. The
+        launch's choices and probes stay on the device
+        (``launch.stats``)."""
+        with self.loop_phase("collect_wait"):
+            loss = float(launch.loss)
+        with self.loop_phase("collect_host"):
+            counts = {
+                k: np.asarray(v) for k, v in launch.stats.items()
+                if k == "expert_rows"
+            }
+            if self._counters is not None:
+                self._counters["tokens"].inc(launch.tokens)
+                for j, n in enumerate(counts.get("expert_rows", ())):
+                    self._counters["expert_rows"].labels(
+                        expert=str(self.cfg.moe.expert_offset + j)
+                    ).inc(int(n))
+        return loss, counts
+
+
+def build_trainer(cfg, mesh, *, optimizer: str = "adam", lr=3e-3,
+                  clip_norm: Optional[float] = None, grad_accum: int = 1,
+                  steps_per_launch: int = 1) -> Trainer:
+    """THE builder: the CLI's flags and a description file's ``train``
+    block both end here."""
+    if steps_per_launch < 1:
+        raise ValueError(
+            f"steps_per_launch must be >= 1, got {steps_per_launch}"
+        )
+    tx = make_optimizer(
+        optimizer, lr, clip_norm=clip_norm, grad_accum=grad_accum
+    )
+    return Trainer(cfg, mesh, tx, steps_per_launch=steps_per_launch)

@@ -1,31 +1,38 @@
-"""Mixture-of-experts FFN with expert parallelism (TPU-native).
+"""Mixture-of-experts FFNs: two layers, one grouped product.
 
-Beyond-parity extension rounding out the parallelism modes: dp (data
-axis), table/model parallel (server axis), sp (ring + all-to-all
-attention) — and here ep: experts sharded over a mesh axis, tokens
-routed to them with two ``all_to_all`` collectives (the standard
-Switch/GShard dispatch, jax-native).
+``moe_ffn`` is the switch layer of ``LMConfig.moe_every``: top-1 routing
+with a per-token-shard capacity, experts sharded over a mesh axis, tokens
+routed with two ``all_to_all`` collectives (the Switch/GShard dispatch):
+each shard builds a [tokens, E, C] dispatch one-hot (C = capacity per
+expert per shard), the 2-layer FFN runs as dense [E/n, n*C, d] batched
+matmuls, and tokens over capacity pass through on the residual path.
 
-Top-1 (switch) routing with a per-token-shard capacity: each shard of
-tokens computes router gates locally, builds a [tokens, E, C] dispatch
-one-hot (C = capacity per expert per shard), and einsum-dispatches its
-tokens to expert buffers; an all_to_all re-shards the EXPERT axis so
-every device holds the full token buffers of its E/n local experts, the
-2-layer FFN runs as dense [E/n, n*C, d] batched matmuls (MXU-shaped),
-and the inverse all_to_all + combine einsum route outputs back. Dropped
-tokens (over capacity) pass through on the residual path, as in Switch.
+``topk_moe_ffn`` is the dropless layer of ``LMConfig.moe``
+(``TopKMoEConfig``): softmax over ALL ``n_experts``, the ``top_k``
+largest renormalised, no capacity and no dropped token, gated-SiLU
+experts beside an optional shared expert. The layer is told which
+experts it holds (``experts_held`` from ``expert_offset``): it routes
+over all of them and computes its own experts' part, which is what one
+chip of an expert-parallel deployment computes before the exchange; what
+the absent experts would add is left out. Its tokens are sorted by
+expert into one buffer with a row for every assignment (tokens x
+``top_k``) and the experts run as ONE grouped matrix product over it (``grouped_matmul``: every caller of a grouped product in this
+repo goes through that function). On one chip it runs without its
+exchange; an exchange over a mesh axis is not built.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from jax import shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -130,3 +137,227 @@ def moe_ffn(
         out_specs=P(None, axis, None),
         check_vma=False,
     )(params["router"], params["w_in"], params["w_out"], x)
+
+
+# ---------------------------------------------------------------------------
+# the dropless top-k layer
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class TopKMoEConfig:
+    n_experts: int  # the router's width: every expert of the layer
+    top_k: int
+    d_expert: int  # width of one expert's gated FFN
+    n_shared: int = 0  # shared experts, computed for every token
+    # the share this program holds: ``experts_held`` experts from
+    # ``expert_offset``; None = all of them
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+
+    def __post_init__(self):
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError(
+                f"top_k {self.top_k} must be in [1, n_experts="
+                f"{self.n_experts}]"
+            )
+        if not (0 <= self.expert_offset
+                and self.expert_offset + self.held <= self.n_experts):
+            raise ValueError(
+                f"experts [{self.expert_offset}, {self.expert_offset} + "
+                f"{self.held}) are not among the {self.n_experts}"
+            )
+
+    @property
+    def held(self) -> int:
+        return (
+            self.n_experts if self.experts_held is None else self.experts_held
+        )
+
+
+def init_topk_moe(key, d_model: int, cfg: TopKMoEConfig, std: float):
+    ks = jax.random.split(key, 7)
+    e, f, fs = cfg.held, cfg.d_expert, cfg.n_shared * cfg.d_expert
+    shapes = {
+        "router": (d_model, cfg.n_experts),
+        "we_gate": (e, d_model, f), "we_up": (e, d_model, f),
+        "we_down": (e, f, d_model),
+    }
+    if cfg.n_shared:
+        shapes.update(
+            ws_gate=(d_model, fs), ws_up=(d_model, fs), ws_down=(fs, d_model)
+        )
+    return {
+        name: std * jax.random.normal(k, shape, jnp.float32)
+        for k, (name, shape) in zip(ks, shapes.items())
+    }
+
+
+def grouped_matmul(x, w, group_sizes):
+    """``x[rows of group g] @ w[g]`` for every group, f32 out: x [R, k]
+    sorted by group, w [G, k, n], ``group_sizes`` [G] int32 with sum <=
+    R. Rows past the sum come out zero."""
+    return jax.lax.ragged_dot(
+        x, w, group_sizes, preferred_element_type=jnp.float32
+    )
+
+
+def swiglu(h, w_gate, w_up, w_down):
+    """W_down(SiLU(W_gate h) * W_up h), activations in ``h.dtype``."""
+    return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
+
+
+# the name of each token's choices for ``jax.checkpoint`` policies
+# (``save_only_these_names``): a recomputed forward that chose again
+# would decide a near-tie by its own rounding, and the backward pass
+# would differentiate another routing than the forward pass computed
+TOP_E = "moe_top_e"
+
+
+def route_topk(h32, router, cfg: TopKMoEConfig):
+    """softmax over all experts in f32 (the matmul too: HIGHEST, or the
+    TPU rounds its inputs to bf16), the ``top_k`` largest, renormalised:
+    (weights [T, k] f32, experts [T, k] int32). The weights are read at
+    the choices as named (``TOP_E``), so that a layer rematerialised
+    under a policy that saves them routes as its forward pass did."""
+    logits = jnp.dot(
+        h32, router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    p = jax.nn.softmax(logits, axis=-1)
+    top_e = checkpoint_name(
+        jax.lax.top_k(p, cfg.top_k)[1].astype(jnp.int32), TOP_E
+    )
+    top_p = jnp.take_along_axis(p, top_e, axis=-1)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p * cfg.routed_scaling_factor, top_e
+
+
+@jax.custom_vjp
+def _dispatch(h, row_token, slot_row):
+    """xs[r] = h[row_token[r]]: each buffer row's token."""
+    del slot_row
+    return h[row_token]
+
+
+def _combine_rows(ys, slot_row):
+    """y[t] = sum_j ys[slot_row[t, j]], a slot with no row (== len(ys))
+    adding nothing."""
+    ext = jnp.concatenate([ys, jnp.zeros_like(ys[:1])])
+    return jnp.sum(ext[slot_row], axis=1)
+
+
+@jax.custom_vjp
+def _combine(ys, row_token, slot_row):
+    del row_token
+    return _combine_rows(ys, slot_row)
+
+
+# dispatch and combine are each other's transpose, and ``slot_row`` is
+# the inverse of ``row_token``: both backward passes are gathers, where
+# autodiff would scatter-add (T*k rows onto the one empty slot's row)
+def _dispatch_fwd(h, row_token, slot_row):
+    return h[row_token], (row_token, slot_row)
+
+
+def _dispatch_bwd(res, g):
+    _, slot_row = res
+    return _combine_rows(g, slot_row), None, None
+
+
+def _combine_fwd(ys, row_token, slot_row):
+    return _combine_rows(ys, slot_row), (row_token, slot_row)
+
+
+def _combine_bwd(res, g):
+    row_token, _ = res
+    return g[row_token], None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def sort_by_expert(top_e, cfg: TopKMoEConfig):
+    """The sorted-token buffer's index arrays for assignments ``top_e``
+    [T, k]: one row for every assignment (T * k rows, so none can be
+    dropped whatever the router does), those whose expert is held here
+    first, sorted by expert.
+
+    Returns ``row_token`` [T*k] (the token of each row; rows past the
+    last held assignment point at token 0 and are masked by
+    ``row_valid``), ``row_slot`` [T*k] (which of the token's k choices),
+    ``row_valid`` [T*k] bool, ``slot_row`` [T, k] (the row of each
+    assignment, or ``T*k`` where its expert is not held) and
+    ``group_sizes`` [held]."""
+    t, k = top_e.shape
+    local = top_e.reshape(-1) - cfg.expert_offset
+    held = (local >= 0) & (local < cfg.held)
+    key = jnp.where(held, local, cfg.held)  # not held: sorted to the end
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # [T*k]
+    # counted by comparison, inverted by a second sort: a scatter of T*k
+    # single elements is the slow way to do either on a TPU
+    group_sizes = jnp.sum(
+        key[:, None] == jnp.arange(cfg.held, dtype=key.dtype), axis=0
+    ).astype(jnp.int32)
+    row_valid = jnp.arange(t * k, dtype=jnp.int32) < jnp.sum(group_sizes)
+    picked = jnp.where(row_valid, order, 0)
+    # the inverse permutation: assignment -> its row, if it has one
+    rank = jnp.argsort(order).astype(jnp.int32)
+    slot_row = jnp.where(held, rank, t * k).reshape(t, k)
+    return picked // k, picked % k, row_valid, slot_row, group_sizes
+
+
+# the router's input, choices and weights are returned at this many
+# tokens (evenly strided), for a caller that holds the router's
+# arithmetic to a reference on the same input
+PROBE_TOKENS = 256
+
+
+def topk_moe_ffn(lp, h2, cfg: TopKMoEConfig, dtype):
+    """The dropless top-k layer on ``h2`` [..., d] (the normed input):
+    this program's experts' part plus the shared expert, in ``dtype``,
+    and stats: ``expert_rows`` [held] int32, ``top_e`` [T, k] int32 (the
+    experts each token chose, of all ``n_experts``), and at every
+    ``T // PROBE_TOKENS``-th token ``probe_x`` (the router's input as it
+    read it), ``probe_e`` and ``probe_w`` (its choices and weights).
+
+    Named scopes: ``lm_moe_route`` (router, top-k, sort, gather and
+    combine), ``lm_moe_experts`` (the grouped products), ``lm_moe_shared``.
+    """
+    shape = h2.shape
+    x = h2.reshape(-1, shape[-1]).astype(dtype)
+    with jax.named_scope("lm_moe_route"):
+        top_w, top_e = route_topk(x.astype(jnp.float32), lp["router"], cfg)
+        row_token, row_slot, row_valid, slot_row, group_sizes = (
+            sort_by_expert(top_e, cfg)
+        )
+        row_w = top_w[row_token, row_slot]
+        xs = _dispatch(x, row_token, slot_row)
+    with jax.named_scope("lm_moe_experts"):
+        gm = functools.partial(grouped_matmul, group_sizes=group_sizes)
+        mid = jax.nn.silu(gm(xs, lp["we_gate"].astype(dtype))) * gm(
+            xs, lp["we_up"].astype(dtype)
+        )
+        ys = gm(mid.astype(dtype), lp["we_down"].astype(dtype))
+    with jax.named_scope("lm_moe_route"):
+        # rows past the last assignment hold whatever the product left
+        ys = jnp.where(row_valid[:, None], ys * row_w[:, None], 0.0)
+        y = _combine(ys.astype(dtype), row_token, slot_row)
+    if cfg.n_shared:
+        with jax.named_scope("lm_moe_shared"):
+            y = y + swiglu(
+                x, lp["ws_gate"].astype(dtype), lp["ws_up"].astype(dtype),
+                lp["ws_down"].astype(dtype),
+            )
+    stride = max(1, x.shape[0] // PROBE_TOKENS)
+    stats = {
+        "expert_rows": group_sizes, "top_e": top_e,
+        "probe_x": jax.lax.stop_gradient(x[::stride]),
+        "probe_e": top_e[::stride],
+        "probe_w": jax.lax.stop_gradient(top_w[::stride]),
+    }
+    return y.reshape(shape), stats

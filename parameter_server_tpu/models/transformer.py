@@ -25,8 +25,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from . import latent_attention as latent
 from .attention import ring_attention, ulysses_attention
-from .moe import init_moe, moe_ffn
+from .latent_attention import MLAConfig, rms_norm
+from .moe import (
+    TOP_E, TopKMoEConfig, init_moe, init_topk_moe, moe_ffn, swiglu,
+    topk_moe_ffn,
+)
+
+ATTENTION_KINDS = ("mha", "mla")
+FFN_KINDS = ("dense", "switch", "moe")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,8 +107,63 @@ class LMConfig:
     # cache traffic; dequantization fuses into the attention einsum.
     # Scores/softmax still accumulate f32. Training is unaffected.
     kv_cache_dtype: "str | None" = None
+    # -- what a published decoder needs beyond the byte LM ----------------
+    # False: an untied output head ``head`` [d_model, vocab]
+    tie_head: bool = True
+    # "layernorm" (mean and variance) or "rmsnorm", with ``norm_eps``
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    # the dense FFN: "gelu" (w1, w2) or "swiglu" (w_gate, w_up, w_down:
+    # W_down(SiLU(W_gate h) * W_up h))
+    ffn_act: str = "gelu"
+    # multiply the embedding rows by sqrt(d_model) (the byte LM does)
+    scale_emb: bool = True
+    # the per-layer description the training forward reads: one
+    # ``(attention kind, FFN kind)`` per layer, attention "mha" (q/k/v
+    # heads, GQA and rope as above) or "mla" (latent attention, ``mla``
+    # below), FFN "dense", "switch" (models/moe.py's top-1 capacity
+    # layer, ``n_experts``/``capacity_factor`` above) or "moe" (its
+    # dropless top-k layer, ``moe`` below). None = n_layers of "mha"
+    # with every ``moe_every``-th FFN "switch". The serving forwards run
+    # "mha" with "dense" or "switch" and refuse the others by name.
+    layers: "tuple | None" = None
+    mla: "MLAConfig | None" = None
+    moe: "TopKMoEConfig | None" = None
 
     def __post_init__(self):
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(
+                f"LMConfig.norm must be 'layernorm' or 'rmsnorm', got "
+                f"{self.norm!r}"
+            )
+        if self.ffn_act not in ("gelu", "swiglu"):
+            raise ValueError(
+                f"LMConfig.ffn_act must be 'gelu' or 'swiglu', got "
+                f"{self.ffn_act!r}"
+            )
+        if self.layers is not None:
+            if len(self.layers) != self.n_layers:
+                raise ValueError(
+                    f"LMConfig.layers describes {len(self.layers)} layers, "
+                    f"n_layers is {self.n_layers}"
+                )
+            for att, ffn in self.layers:
+                if att not in ATTENTION_KINDS or ffn not in FFN_KINDS:
+                    raise ValueError(
+                        f"LMConfig.layers: ({att!r}, {ffn!r}) is not one of "
+                        f"{ATTENTION_KINDS} x {FFN_KINDS}"
+                    )
+        kinds = self.layer_kinds
+        if any(a == "mla" for a, _ in kinds):
+            if self.mla is None:
+                raise ValueError("an 'mla' layer needs LMConfig.mla")
+            if self.attention == "a2a" or self.window is not None:
+                raise ValueError(
+                    "an 'mla' layer runs the ring schedules without a "
+                    "window: a2a and sliding windows are not built for it"
+                )
+        if any(f == "moe" for _, f in kinds) and self.moe is None:
+            raise ValueError("a 'moe' layer needs LMConfig.moe")
         if self.kv_cache_dtype not in (None, "int8"):
             raise ValueError(
                 f"LMConfig.kv_cache_dtype must be None or 'int8', got "
@@ -150,6 +213,43 @@ class LMConfig:
     def kv_heads(self) -> int:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
 
+    @property
+    def layer_kinds(self) -> tuple:
+        """``(attention kind, FFN kind)`` of every layer."""
+        if self.layers is not None:
+            return tuple(tuple(kinds) for kinds in self.layers)
+        return tuple(
+            ("mha", "switch" if _is_moe_layer(self, i) else "dense")
+            for i in range(self.n_layers)
+        )
+
+
+def refuse_serving(cfg: LMConfig, where: str) -> None:
+    """The cached forwards compute "mha" attention over a K/V cache and
+    a dense or switch FFN. A layer of another kind is refused by name:
+    decoding it through those would be a different model."""
+    for att, ffn in cfg.layer_kinds:
+        if att == "mla":
+            raise NotImplementedError(
+                f"{where}: latent attention ('mla') has no decode cache "
+                "yet (a latent K/V cache is not built); this model trains "
+                "through lm_forward only"
+            )
+        if ffn == "moe":
+            raise NotImplementedError(
+                f"{where}: the dropless top-k expert layer ('moe', "
+                "TopKMoEConfig) is not built into the serving forwards; "
+                "this model trains through lm_forward only"
+            )
+    if cfg.norm != "layernorm" or cfg.ffn_act != "gelu" or not (
+        cfg.tie_head and cfg.scale_emb
+    ):
+        raise NotImplementedError(
+            f"{where}: the serving forwards compute LayerNorm, a GELU FFN "
+            "and a tied, scaled embedding; rmsnorm / swiglu / an untied "
+            "head train through lm_forward only"
+        )
+
 
 def init_lm(key: jax.Array, cfg: LMConfig) -> Dict[str, jax.Array]:
     ks = jax.random.split(key, 2 + 4 * cfg.n_layers)
@@ -158,26 +258,47 @@ def init_lm(key: jax.Array, cfg: LMConfig) -> Dict[str, jax.Array]:
         "emb": s * jax.random.normal(ks[0], (cfg.vocab, cfg.d_model)),
         "ln_f": jnp.ones((cfg.d_model,)),
     }
-    for i in range(cfg.n_layers):
+    if not cfg.tie_head:
+        p["head"] = s * jax.random.normal(ks[1], (cfg.d_model, cfg.vocab))
+    for i, (att_kind, ffn_kind) in enumerate(cfg.layer_kinds):
         k1, k2, k3, k4 = ks[2 + 4 * i : 6 + 4 * i]
         p[f"l{i}/ln1"] = jnp.ones((cfg.d_model,))
         p[f"l{i}/ln2"] = jnp.ones((cfg.d_model,))
-        # separate q/k/v projections (not a fused [d, 3d]): under tensor
-        # parallelism each projection column-shards on its own, so the
-        # qkv split boundaries stay shard-local (the fused-QKV TP pitfall
-        # puts K across two shards and forces per-layer reshards)
-        wqkv = s * jax.random.normal(k1, (cfg.d_model, 3 * cfg.d_model))
-        p[f"l{i}/wq"], p[f"l{i}/wk"], p[f"l{i}/wv"] = jnp.split(wqkv, 3, axis=1)
-        if cfg.kv_heads != cfg.n_heads:  # GQA: narrow K/V projections
-            kv_w = cfg.kv_heads * (cfg.d_model // cfg.n_heads)
-            p[f"l{i}/wk"] = p[f"l{i}/wk"][:, :kv_w]
-            p[f"l{i}/wv"] = p[f"l{i}/wv"][:, :kv_w]
-        p[f"l{i}/wo"] = s * jax.random.normal(k2, (cfg.d_model, cfg.d_model))
-        if _is_moe_layer(cfg, i):
+        if att_kind == "mla":
+            for name, w in latent.init_mla(
+                k1, cfg.d_model, cfg.n_heads, cfg.mla, s
+            ).items():
+                p[f"l{i}/{name}"] = w
+        else:
+            # separate q/k/v projections (not a fused [d, 3d]): under
+            # tensor parallelism each projection column-shards on its
+            # own, so the qkv split boundaries stay shard-local (the
+            # fused-QKV TP pitfall puts K across two shards and forces
+            # per-layer reshards)
+            wqkv = s * jax.random.normal(k1, (cfg.d_model, 3 * cfg.d_model))
+            p[f"l{i}/wq"], p[f"l{i}/wk"], p[f"l{i}/wv"] = jnp.split(
+                wqkv, 3, axis=1
+            )
+            if cfg.kv_heads != cfg.n_heads:  # GQA: narrow K/V projections
+                kv_w = cfg.kv_heads * (cfg.d_model // cfg.n_heads)
+                p[f"l{i}/wk"] = p[f"l{i}/wk"][:, :kv_w]
+                p[f"l{i}/wv"] = p[f"l{i}/wv"][:, :kv_w]
+            p[f"l{i}/wo"] = s * jax.random.normal(
+                k2, (cfg.d_model, cfg.d_model)
+            )
+        if ffn_kind == "switch":
             moe = init_moe(k3, cfg.d_model, cfg.d_ff, cfg.n_experts)
             p[f"l{i}/moe_router"] = moe["router"]
             p[f"l{i}/moe_w_in"] = moe["w_in"]
             p[f"l{i}/moe_w_out"] = moe["w_out"]
+        elif ffn_kind == "moe":
+            for name, w in init_topk_moe(k3, cfg.d_model, cfg.moe, s).items():
+                p[f"l{i}/{name}"] = w
+        elif cfg.ffn_act == "swiglu":
+            kg, ku = jax.random.split(k3)
+            p[f"l{i}/w_gate"] = s * jax.random.normal(kg, (cfg.d_model, cfg.d_ff))
+            p[f"l{i}/w_up"] = s * jax.random.normal(ku, (cfg.d_model, cfg.d_ff))
+            p[f"l{i}/w_down"] = s * jax.random.normal(k4, (cfg.d_ff, cfg.d_model))
         else:
             p[f"l{i}/w1"] = s * jax.random.normal(k3, (cfg.d_model, cfg.d_ff))
             p[f"l{i}/w2"] = s * jax.random.normal(k4, (cfg.d_ff, cfg.d_model))
@@ -218,9 +339,10 @@ def _moe_ffn_dropless(lp, h2, n_experts: int):
     weight gather is materialized. COST NOTE: this computes every
     expert's FFN over all T tokens (n_experts x the dense-FFN FLOPs),
     which is the right trade for the one-token decode step but makes
-    MoE PREFILL compute-heavy on long prompts; a sort/gather-by-expert
-    prefill variant is the known optimization if MoE serving becomes a
-    measured bottleneck."""
+    MoE PREFILL compute-heavy on long prompts. The sorted form exists:
+    ``models/moe.py`` sorts tokens by expert and runs ONE grouped
+    product over them (``grouped_matmul``, used by ``topk_moe_ffn``);
+    a prefill that needs it calls that function, not a second one."""
     shape = h2.shape
     x = h2.reshape(-1, shape[-1]).astype(jnp.float32)  # [T, d]
     router = lp["moe_router"].astype(jnp.float32)
@@ -304,26 +426,60 @@ def lm_forward(
 ) -> jax.Array:
     """Logits [B, S, vocab] (always float32; decoder activations run in
     ``cfg.compute_dtype``, rematerialized per layer when ``cfg.remat``)."""
+    return lm_forward_with_stats(params, tokens, cfg, mesh, axis)[0]
+
+
+def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
+                          axis: str = "data"):
+    """THE training forward: ``(logits, stats)``. Each layer is what
+    ``cfg.layer_kinds`` says it is. ``stats`` holds what the dropless
+    expert layers returned (``models/moe.topk_moe_ffn``): summed over
+    them ``expert_rows`` [held] int32 (rows each held expert computed),
+    stacked by layer ``top_e`` [layers, T, k] (the experts each token
+    chose) and the router probes ``probe_x``, ``probe_e``, ``probe_w``;
+    empty for a model with no such layer.
+
+    Named scopes, for the device trace: ``lm_attn`` (projections and
+    attention), ``lm_ffn`` or ``lm_moe_*`` (models/moe.py), ``lm_head``.
+    """
     b, s = tokens.shape
     hd = cfg.d_model // cfg.n_heads
     dtype = jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else jnp.float32
+    kinds = cfg.layer_kinds
+    if cfg.norm == "rmsnorm":
+        norm = functools.partial(rms_norm, eps=cfg.norm_eps)
+    else:
+        norm = lambda x, scale: _ln(x, scale.astype(x.dtype))  # noqa: E731
 
     # RoPE tables, computed ONCE on the GLOBAL sequence view (GSPMD
     # shards them with the tokens; zigzag's position ids are its
     # permutation) and closed over by every layer — under remat they
     # enter jax.checkpoint as inputs, not per-layer recomputation
+    positions = _rope_position_ids(cfg, s, mesh, axis)
     rope_cs = (
-        _rope_tables(
-            _rope_position_ids(cfg, s, mesh, axis)[None, :, None],
-            hd, cfg.rope_theta,
-        )
+        _rope_tables(positions[None, :, None], hd, cfg.rope_theta)
         if cfg.rope
         else None
     )
+    mla_cs = mla_qscale = None
+    if any(a == "mla" for a, _ in kinds):
+        mla_cs = latent.rope_tables(
+            positions[None, :, None], cfg.mla, cfg.rope_theta
+        )
+        # the kernels scale by 1/sqrt(D): the query carries the rest
+        mla_qscale = latent.softmax_scale(cfg.mla) * np.sqrt(
+            cfg.mla.qk_head_dim
+        )
+        pos_scale = latent.position_scale(positions, cfg.mla.yarn)
+        if pos_scale is not None:
+            mla_qscale = mla_qscale * pos_scale
+    impl = {
+        "ring": "xla", "ring_flash": "flash", "ring_zigzag": "zigzag",
+        "a2a": None,
+    }[cfg.attention]
 
-    def layer(x, lp, is_moe):
+    def mha(h, lp):
         cast = lambda k: lp[k].astype(dtype)  # noqa: E731
-        h = _ln(x, cast("ln1"))
         q = h @ cast("wq")
         k = h @ cast("wk")
         v = h @ cast("wv")
@@ -355,26 +511,43 @@ def lm_forward(
 
         if cfg.attention == "a2a":
             # Ulysses: q/k/v stay [B, S, d]; the layer splits heads itself
-            att = ulysses_attention(
+            return ulysses_attention(
                 q, k, v, mesh=mesh, axis=axis, n_heads=cfg.n_heads,
                 causal=True,
             )
-        else:
-            impl = {
-                "ring": "xla", "ring_flash": "flash", "ring_zigzag": "zigzag"
-            }[cfg.attention]
-            att = ring_attention(
-                heads(q), heads(k), heads(v), mesh=mesh, axis=axis,
-                causal=True, impl=impl, window=cfg.window,
-            )
-            att = (
-                att.reshape(b, cfg.n_heads, s, hd)
-                .transpose(0, 2, 1, 3)
-                .reshape(b, s, cfg.d_model)
-            )
-        x = x + att.astype(dtype) @ cast("wo")
-        h2 = _ln(x, cast("ln2"))
-        if is_moe:
+        att = ring_attention(
+            heads(q), heads(k), heads(v), mesh=mesh, axis=axis,
+            causal=True, impl=impl, window=cfg.window,
+        )
+        return (
+            att.reshape(b, cfg.n_heads, s, hd)
+            .transpose(0, 2, 1, 3)
+            .reshape(b, s, cfg.d_model)
+        )
+
+    def mla(h, lp):
+        m = cfg.mla
+        q, k, v = latent.mla_qkv(
+            h, lp, m, cfg.n_heads, cfg.norm_eps, mla_cs, mla_qscale, dtype
+        )
+        att = ring_attention(
+            q, k, v, mesh=mesh, axis=axis, causal=True, impl=impl,
+        )  # [B*H, S, qk_head_dim]: v's zero pad comes back as zeros
+        return (
+            att.reshape(b, cfg.n_heads, s, m.qk_head_dim)[..., : m.v_head_dim]
+            .transpose(0, 2, 1, 3)
+            .reshape(b, s, cfg.n_heads * m.v_head_dim)
+        )
+
+    def layer(x, lp, att_kind, ffn_kind):
+        cast = lambda k: lp[k].astype(dtype)  # noqa: E731
+        stats = {}
+        with jax.named_scope("lm_attn"):
+            h = norm(x, lp["ln1"])
+            att = mla(h, lp) if att_kind == "mla" else mha(h, lp)
+            x = x + att.astype(dtype) @ cast("wo")
+        h2 = norm(x, lp["ln2"])
+        if ffn_kind == "switch":
             moe_p = {
                 "router": lp["moe_router"],
                 "w_in": lp["moe_w_in"],
@@ -382,22 +555,58 @@ def lm_forward(
             }
             # MoE routing (top-1 argmax + capacity bookkeeping) stays in
             # the params' dtype — f32 — for stable expert selection
-            x = x + moe_ffn(
-                moe_p, h2.astype(jnp.float32), mesh=mesh, axis=axis,
-                capacity_factor=cfg.capacity_factor,
-            ).astype(dtype)
+            with jax.named_scope("lm_ffn"):
+                x = x + moe_ffn(
+                    moe_p, h2.astype(jnp.float32), mesh=mesh, axis=axis,
+                    capacity_factor=cfg.capacity_factor,
+                ).astype(dtype)
+        elif ffn_kind == "moe":
+            y, stats = topk_moe_ffn(lp, h2, cfg.moe, dtype)
+            x = x + y
         else:
-            x = x + jax.nn.gelu(h2 @ cast("w1")) @ cast("w2")
-        return x
+            with jax.named_scope("lm_ffn"):
+                if cfg.ffn_act == "swiglu":
+                    x = x + swiglu(
+                        h2, cast("w_gate"), cast("w_up"), cast("w_down")
+                    )
+                else:
+                    x = x + jax.nn.gelu(h2 @ cast("w1")) @ cast("w2")
+        return x, stats
 
     if cfg.remat:
-        layer = jax.checkpoint(layer, static_argnums=(2,))
+        # everything is recomputed but the expert layer's choices: a
+        # near-tie decided again could fall the other way
+        layer = jax.checkpoint(
+            layer, static_argnums=(2, 3),
+            policy=jax.checkpoint_policies.save_only_these_names(TOP_E),
+        )
 
-    x = (params["emb"][tokens] * np.sqrt(cfg.d_model)).astype(dtype)
-    for i in range(cfg.n_layers):
-        x = layer(x, _layer_params(params, i), _is_moe_layer(cfg, i))
-    x32 = x.astype(jnp.float32)
-    return _ln(x32, params["ln_f"]) @ params["emb"].T
+    x = params["emb"][tokens]
+    if cfg.scale_emb:
+        x = x * np.sqrt(cfg.d_model)
+    x = x.astype(dtype)
+    per_layer = []
+    for i, (att_kind, ffn_kind) in enumerate(kinds):
+        x, stats = layer(x, _layer_params(params, i), att_kind, ffn_kind)
+        if stats:
+            per_layer.append(stats)
+    total = {}
+    if per_layer:
+        total = {
+            k: sum(s[k] for s in per_layer) if k == "expert_rows"
+            else jnp.stack([s[k] for s in per_layer])
+            for k in per_layer[0]
+        }
+    with jax.named_scope("lm_head"):
+        xn = norm(x.astype(jnp.float32), params["ln_f"])
+        if cfg.tie_head:
+            logits = xn @ params["emb"].T
+        else:
+            logits = jnp.dot(
+                xn.astype(dtype), params["head"].astype(dtype),
+                preferred_element_type=jnp.float32,
+            )
+    return logits, total
 
 
 def _quant_kv_i8(x):
@@ -442,6 +651,7 @@ def _alloc_kv_caches(cfg: LMConfig, b: int, total: int):
     per-token cache streaming) or, under ``kv_cache_dtype="int8"``, as
     (int8 data, f32 per-row scale); ``cfg.kv_heads`` not n_heads —
     under GQA the cache carries only the K/V heads."""
+    refuse_serving(cfg, "_alloc_kv_caches")
     hd = cfg.d_model // cfg.n_heads
     shape = (cfg.n_layers, b, cfg.kv_heads, total, hd)
     dtype = (
@@ -481,6 +691,7 @@ def _chunk_decode(params, cfg: LMConfig, toks, kcache, vcache, pos):
     ``cfg.compute_dtype`` like the training forward (softmax and
     logits in f32), so decode matches training numerics dtype for
     dtype."""
+    refuse_serving(cfg, "_chunk_decode")
     b, c = toks.shape
     nh = cfg.n_heads
     kvh = cfg.kv_heads
@@ -544,6 +755,7 @@ def _decode_step(params, cfg: LMConfig, tok, kcache, vcache, pos):
     must stay semantically identical; tests/test_transformer.py pins
     ``_decode_step == _chunk_decode`` output across rope/GQA/window/
     int8 variants so they cannot drift."""
+    refuse_serving(cfg, "_decode_step")
     b = tok.shape[0]
     nh = cfg.n_heads
     kvh = cfg.kv_heads
@@ -678,6 +890,7 @@ def _prefill(params, cfg: LMConfig, prompt, kcache, vcache):
     scores/softmax/logits in f32, caches stored in the caller's cache
     dtype (the compute dtype — bf16 under bfloat16); attention runs in
     query chunks so transient memory stays bounded."""
+    refuse_serving(cfg, "_prefill")
     b, p_len = prompt.shape
     nh = cfg.n_heads
     kvh = cfg.kv_heads
@@ -1453,12 +1666,8 @@ def lm_loss(params, tokens, cfg, mesh, axis="data"):
             "zigzag layout breaks that adjacency — use "
             "zigzag_lm_arrays + lm_loss_with_targets instead"
         )
-    targets = jnp.concatenate(
-        [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], axis=1
-    )
-    weights = jnp.ones(tokens.shape, jnp.float32).at[:, -1].set(0.0)
     return lm_loss_with_targets(
-        params, tokens, targets, weights, cfg, mesh, axis
+        params, tokens, *next_token_targets(tokens), cfg, mesh, axis
     )
 
 
@@ -1467,13 +1676,34 @@ def lm_loss_with_targets(params, tokens, targets, weights, cfg, mesh, axis="data
     targets — the layout-agnostic loss: under a permuted token layout
     (zigzag) "next token" is not position+1 locally, so the caller maps
     labels (see :func:`zigzag_lm_arrays`) instead of the loss shifting."""
-    logits = lm_forward(params, tokens, cfg, mesh, axis)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    w = weights.astype(jnp.float32)
-    # eps only guards all-zero weights (loss 0); fractional weight sums
-    # must divide through unscaled
-    return (nll * w).sum() / jnp.maximum(w.sum(), 1e-9)
+    return lm_loss_and_stats(
+        params, tokens, targets, weights, cfg, mesh, axis
+    )[0]
+
+
+def lm_loss_and_stats(params, tokens, targets, weights, cfg, mesh,
+                      axis="data"):
+    """``(loss, stats)``: :func:`lm_loss_with_targets` beside what
+    :func:`lm_forward_with_stats` counted (``jax.value_and_grad(...,
+    has_aux=True)`` takes it as it is)."""
+    logits, stats = lm_forward_with_stats(params, tokens, cfg, mesh, axis)
+    with jax.named_scope("lm_head"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        w = weights.astype(jnp.float32)
+        # eps only guards all-zero weights (loss 0); fractional weight
+        # sums must divide through unscaled
+        return (nll * w).sum() / jnp.maximum(w.sum(), 1e-9), stats
+
+
+def next_token_targets(tokens):
+    """``(targets, weights)`` of the natural layout: position i's target
+    is token i+1, and the last position weighs nothing."""
+    targets = jnp.concatenate(
+        [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], axis=1
+    )
+    weights = jnp.ones(tokens.shape, jnp.float32).at[:, -1].set(0.0)
+    return targets, weights
 
 
 def zigzag_lm_arrays(tokens: np.ndarray, n: int):

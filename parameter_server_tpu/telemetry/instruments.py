@@ -974,6 +974,25 @@ def app_instruments(reg: MetricsRegistry) -> Dict[str, object]:
     }
 
 
+def lm_instruments(reg: MetricsRegistry) -> Dict[str, object]:
+    """The LM trainer (apps/lm/trainer.py): what a launch computed,
+    counted at its collect from numbers the step returns beside its
+    loss."""
+    return {
+        "tokens": reg.ensure_counter(
+            "ps_lm_tokens_total",
+            "tokens of the launches collected by the LM trainer",
+        ),
+        "expert_rows": reg.ensure_counter(
+            "ps_lm_expert_rows_total",
+            "rows of the sorted-token buffer each held expert of the "
+            "dropless top-k layers computed (forward count: one per "
+            "token routed to the expert, summed over the layers)",
+            labelnames=("expert",),
+        ),
+    }
+
+
 def heartbeat_instruments(reg: MetricsRegistry) -> Dict[str, object]:
     """Node liveness/traffic as last-report gauges (aux_runtime.beat)."""
     return {
@@ -1064,6 +1083,7 @@ INSTRUMENT_FAMILIES = (
     partition_instruments,
     consistency_instruments,
     app_instruments,
+    lm_instruments,
     heartbeat_instruments,
 )
 

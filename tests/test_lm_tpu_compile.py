@@ -1,0 +1,97 @@
+"""The benchmark's language-model step, compiled at its real size for a
+TPU v5e that is described and not attached (no chip time, no result, no
+timing): what the chip's compiler would refuse, and the memory it plans.
+
+The topology is described inside a fixture, never while a module is
+imported, and only in this file: one process at a time may load the
+TPU's library.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG = os.path.join(ROOT, "chipbench", "configs", "mistral_small4_ep16.json")
+CHIP_BYTES = 16_909_336_064
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def test_the_cells_step_compiles_for_one_v5e_chip(
+    topo, no_compile_cache, monkeypatch
+):
+    from parameter_server_tpu.apps.lm import trainer as lm_trainer
+    from parameter_server_tpu.models.transformer import init_lm
+    from parameter_server_tpu.ops import flash_attention as fa
+
+    # the code asks the backend which attention to take, and the backend
+    # here is the CPU: steer it to the kernels the chip runs
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    desc = lm_trainer.load_description(CONFIG)
+    t = desc["train"]
+    cfg = lm_trainer.model_from_description(
+        desc, attention=t["attention"], remat=t["remat"], bf16=t["bf16"]
+    )
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "server"))
+    trainer = lm_trainer.build_trainer(
+        cfg, mesh, optimizer=t["optimizer"], lr=t["lr"]
+    )
+    here = NamedSharding(mesh, P())
+    spec = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=here
+    )
+    params = jax.tree.map(
+        spec, jax.eval_shape(lambda k: init_lm(k, cfg), jax.random.PRNGKey(0))
+    )
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params)) == (
+        1_154_524_160
+    )
+    opt = jax.tree.map(spec, jax.eval_shape(trainer.tx.init, params))
+    tokens = jax.ShapeDtypeStruct(
+        (t["batch"], t["seq_len"]), jnp.int32,
+        sharding=NamedSharding(mesh, P(None, "data")),
+    )
+    compiled = trainer.step.lower(params, opt, tokens).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3  # the flash kernels are in
+    memory = compiled.memory_analysis()
+    planned = (
+        memory.argument_size_in_bytes + memory.output_size_in_bytes
+        + memory.temp_size_in_bytes - memory.alias_size_in_bytes
+    )
+    # weights once (donated) and the step's temporaries, the sorted-token
+    # buffers of every assignment among them: 11.2 GB when written. A plan
+    # over 80% of the chip leaves the allocator no room
+    assert 0.25 * CHIP_BYTES < planned < 0.80 * CHIP_BYTES, planned
