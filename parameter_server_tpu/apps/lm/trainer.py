@@ -135,8 +135,9 @@ class Launch:
     """One dispatched launch: what ``collect`` waits for."""
 
     loss: object  # device scalar: the launch's last step's loss
-    # device arrays (``lm_forward_with_stats``): ``expert_rows`` summed
-    # over the launch's steps; its last step's choices and router probes
+    # device arrays (``lm_forward_with_stats``): the counts
+    # (``moe.MOE_COUNTS``: ``expert_rows``, ``buffer_passes``) summed over
+    # the launch's steps; its last step's choices and router probes
     stats: dict
     tokens: int
 
@@ -153,6 +154,7 @@ class Trainer:
         import jax
         import optax
 
+        from ...models.moe import MOE_COUNTS
         from ...models.transformer import (
             lm_loss_and_stats,
             next_token_targets,
@@ -209,7 +211,7 @@ class Trainer:
                 # counts add up over the launch's steps; the choices
                 # and probes kept are the last step's
                 return p, opt, losses[-1], {
-                    k: v.sum(0) if k == "expert_rows" else v[-1]
+                    k: v.sum(0) if k in MOE_COUNTS else v[-1]
                     for k, v in stats.items()
                 }
 
@@ -331,15 +333,17 @@ class Trainer:
 
     def collect(self, launch: Launch):
         """``(loss, counts)`` of a launch on the host, counted: its
-        ``expert_rows`` where the model has the dropless layer. The
-        launch's choices and probes stay on the device
-        (``launch.stats``)."""
+        ``expert_rows`` and ``buffer_passes`` where the model has the
+        dropless layer. The launch's choices and probes stay on the
+        device (``launch.stats``)."""
+        from ...models.moe import MOE_COUNTS
+
         with self.loop_phase("collect_wait"):
             loss = float(launch.loss)
         with self.loop_phase("collect_host"):
             counts = {
                 k: np.asarray(v) for k, v in launch.stats.items()
-                if k == "expert_rows"
+                if k in MOE_COUNTS
             }
             if self._counters is not None:
                 self._counters["tokens"].inc(launch.tokens)
@@ -347,6 +351,12 @@ class Trainer:
                     self._counters["expert_rows"].labels(
                         expert=str(self.cfg.moe.expert_offset + j)
                     ).inc(int(n))
+                for part, n in zip(
+                    ("head", "tail"), counts.get("buffer_passes", ())
+                ):
+                    self._counters["buffer_passes"].labels(part=part).inc(
+                        int(n)
+                    )
         return loss, counts
 
 

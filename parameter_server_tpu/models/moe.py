@@ -16,9 +16,27 @@ over all of them and computes its own experts' part, which is what one
 chip of an expert-parallel deployment computes before the exchange; what
 the absent experts would add is left out. Its tokens are sorted by
 expert into one buffer with a row for every assignment (tokens x
-``top_k``) and the experts run as ONE grouped matrix product over it (``grouped_matmul``: every caller of a grouped product in this
-repo goes through that function). On one chip it runs without its
-exchange; an exchange over a mesh axis is not built.
+``top_k``), the held ones first, and the experts run as grouped matrix
+products over it (``grouped_matmul``: every caller of a grouped product
+in this repo goes through that function). On one chip it runs without
+its exchange; an exchange over a mesh axis is not built.
+
+A layer that holds few of the experts fills few of those rows, and only
+the grouped product skips an empty one: gathers, SiLU, casts and masks
+move it. So the buffer is computed in two parts of static size
+(``head_rows``): the HEAD, ``HEAD_FACTOR`` times the rows the held
+experts expect (tokens x ``top_k`` x held / ``n_experts``), always; the
+TAIL, every row after it, under ``lax.cond`` in the steps in which a
+held assignment falls there, its output added to the head's. Every
+assignment still has its row whatever the router does. With a quarter
+of the experts or more held the head is the whole buffer, and there is
+no tail and no conditional. The branch not taken costs nothing because
+``_head_and_tail`` differentiates the tail itself: autodiff would carry
+the tail's residuals out of the conditional and fill them with zeros
+where it did not run, and add three zero weight gradients; here the
+backward pass is again a conditional, which recomputes the tail from
+its inputs and adds its gradients to the head's inside the branch
+taken, so that the other branch is an identity.
 """
 
 from __future__ import annotations
@@ -285,7 +303,10 @@ def sort_by_expert(top_e, cfg: TopKMoEConfig):
     """The sorted-token buffer's index arrays for assignments ``top_e``
     [T, k]: one row for every assignment (T * k rows, so none can be
     dropped whatever the router does), those whose expert is held here
-    first, sorted by expert.
+    first, sorted by expert. So rows [0, held assignments) carry a token
+    and the rest are empty: ``split_buffer`` cuts the arrays at
+    ``head_rows`` into the head that is always computed and the tail
+    that is computed when a held assignment falls in it.
 
     Returns ``row_token`` [T*k] (the token of each row; rows past the
     last held assignment point at token 0 and are masked by
@@ -311,19 +332,143 @@ def sort_by_expert(top_e, cfg: TopKMoEConfig):
     return picked // k, picked % k, row_valid, slot_row, group_sizes
 
 
+# the stats of ``topk_moe_ffn`` that are counts: they add up over layers
+# and steps, where the others are kept by layer and of the last step
+MOE_COUNTS = ("expert_rows", "buffer_passes")
+
 # the router's input, choices and weights are returned at this many
 # tokens (evenly strided), for a caller that holds the router's
 # arithmetic to a reference on the same input
 PROBE_TOKENS = 256
 
+# The head of the sorted buffer, in multiples of the rows the held
+# experts expect (T * k * held / n_experts). The records (PERF.md, PR 29
+# and 30, mistral_small4_ep16.packed8k: 8 of 128 experts held, 65,536
+# rows): only the held experts' outputs reach the loss, so the router
+# moves their load to 43-190% of the expected within a window of 25
+# steps; past 400% it went in one step of PR 29's fifty runs (484%) and
+# in 7 of the 1,960 passes of PR 30's sixteen. A buffer row costs
+# 2.2 ms a step per 1,024: a head of 2x would buy 18 ms of 760 more and
+# flip between the paths inside ordinary windows; at 4x the tail runs
+# in the rare step. A layer, forward and backward: the head alone 70 ms
+# where the whole buffer took 93.5; head and tail 24 ms more than the
+# whole buffer at the same load (each part's combine gathers every
+# slot).
+HEAD_FACTOR = 4
+# the head is rounded up to this many rows: a whole number of the tiles
+# the gathers and ``ragged_dot`` work in
+ROW_TILE = 512
+
+
+def head_rows(tokens: int, cfg: TopKMoEConfig) -> int:
+    """Rows of the sorted buffer's head for ``tokens`` tokens, from the
+    share of the experts held; ``tokens * top_k`` (no tail) where that
+    share is a quarter or more."""
+    rows = tokens * cfg.top_k
+    expected = -(-rows * cfg.held // cfg.n_experts)
+    return min(rows, -(-HEAD_FACTOR * expected // ROW_TILE) * ROW_TILE)
+
+
+def split_buffer(index, n_head: int):
+    """``sort_by_expert``'s arrays cut into rows [0, n_head) and the
+    rest, each part indexed from its own row 0: a group that straddles
+    the cut has rows in both, in the order it had; an assignment whose
+    row is in the other part has none in this one."""
+    row_token, row_slot, row_valid, slot_row, group_sizes = index
+    ends = jnp.cumsum(group_sizes)
+    head_sizes = jnp.minimum(ends, n_head) - jnp.minimum(
+        ends - group_sizes, n_head
+    )
+
+    def part(lo, hi, sizes):
+        mine = (slot_row >= lo) & (slot_row < hi)
+        return (
+            row_token[lo:hi], row_slot[lo:hi], row_valid[lo:hi],
+            jnp.where(mine, slot_row - lo, hi - lo), sizes,
+        )
+
+    return (
+        part(0, n_head, head_sizes),
+        part(n_head, row_token.shape[0], group_sizes - head_sizes),
+    )
+
+
+def _buffer_part(x, top_w, weights, part):
+    """What the held experts add for the assignments with a row in
+    ``part`` (index arrays as ``sort_by_expert`` returns them) [T, d]:
+    dispatch, the three grouped products, weighting, combine, all in
+    ``x.dtype``, which is the ``weights``' too."""
+    row_token, row_slot, row_valid, slot_row, group_sizes = part
+    w_gate, w_up, w_down = weights
+    with jax.named_scope("lm_moe_route"):
+        row_w = top_w[row_token, row_slot]
+        xs = _dispatch(x, row_token, slot_row)
+    with jax.named_scope("lm_moe_experts"):
+        gm = functools.partial(grouped_matmul, group_sizes=group_sizes)
+        mid = jax.nn.silu(gm(xs, w_gate)) * gm(xs, w_up)
+        ys = gm(mid.astype(x.dtype), w_down)
+    with jax.named_scope("lm_moe_route"):
+        # rows past the last assignment hold whatever the product left
+        ys = jnp.where(row_valid[:, None], ys * row_w[:, None], 0.0)
+        return _combine(ys.astype(x.dtype), row_token, slot_row)
+
+
+def _add_tail(y, x, top_w, weights, tail, need_tail):
+    """``y`` plus the tail's ``_buffer_part`` where ``need_tail``; the
+    other branch hands ``y`` back."""
+    return jax.lax.cond(
+        need_tail,
+        lambda y: y + _buffer_part(x, top_w, weights, tail),
+        lambda y: y,
+        y,
+    )
+
+
+@jax.custom_vjp
+def _head_and_tail(x, top_w, weights, head, tail, need_tail):
+    """``_buffer_part`` of the head, plus that of the tail where
+    ``need_tail``."""
+    y = _buffer_part(x, top_w, weights, head)
+    return _add_tail(y, x, top_w, weights, tail, need_tail)
+
+
+def _head_and_tail_fwd(x, top_w, weights, head, tail, need_tail):
+    y, head_vjp = jax.vjp(
+        lambda *a: _buffer_part(*a, head), x, top_w, weights
+    )
+    # of the tail only its inputs: the backward pass computes it again
+    return _add_tail(y, x, top_w, weights, tail, need_tail), (
+        head_vjp, x, top_w, weights, tail, need_tail
+    )
+
+
+def _head_and_tail_bwd(res, g):
+    head_vjp, x, top_w, weights, tail, need_tail = res
+
+    def add_the_tails(grads):
+        tail_vjp = jax.vjp(
+            lambda *a: _buffer_part(*a, tail), x, top_w, weights
+        )[1]
+        return jax.tree.map(jnp.add, grads, tail_vjp(g))
+
+    grads = jax.lax.cond(
+        need_tail, add_the_tails, lambda grads: grads, head_vjp(g)
+    )
+    return (*grads, None, None, None)
+
+
+_head_and_tail.defvjp(_head_and_tail_fwd, _head_and_tail_bwd)
+
 
 def topk_moe_ffn(lp, h2, cfg: TopKMoEConfig, dtype):
     """The dropless top-k layer on ``h2`` [..., d] (the normed input):
     this program's experts' part plus the shared expert, in ``dtype``,
-    and stats: ``expert_rows`` [held] int32, ``top_e`` [T, k] int32 (the
-    experts each token chose, of all ``n_experts``), and at every
-    ``T // PROBE_TOKENS``-th token ``probe_x`` (the router's input as it
-    read it), ``probe_e`` and ``probe_w`` (its choices and weights).
+    and stats: ``expert_rows`` [held] int32, ``buffer_passes`` [2] int32
+    (1 for the head of the sorted buffer, and 1 or 0 for its tail: did
+    this pass need it), ``top_e`` [T, k] int32 (the experts each token
+    chose, of all ``n_experts``), and at every ``T // PROBE_TOKENS``-th
+    token ``probe_x`` (the router's input as it read it), ``probe_e``
+    and ``probe_w`` (its choices and weights).
 
     Named scopes: ``lm_moe_route`` (router, top-k, sort, gather and
     combine), ``lm_moe_experts`` (the grouped products), ``lm_moe_shared``.
@@ -332,21 +477,25 @@ def topk_moe_ffn(lp, h2, cfg: TopKMoEConfig, dtype):
     x = h2.reshape(-1, shape[-1]).astype(dtype)
     with jax.named_scope("lm_moe_route"):
         top_w, top_e = route_topk(x.astype(jnp.float32), lp["router"], cfg)
-        row_token, row_slot, row_valid, slot_row, group_sizes = (
-            sort_by_expert(top_e, cfg)
-        )
-        row_w = top_w[row_token, row_slot]
-        xs = _dispatch(x, row_token, slot_row)
+        index = sort_by_expert(top_e, cfg)
     with jax.named_scope("lm_moe_experts"):
-        gm = functools.partial(grouped_matmul, group_sizes=group_sizes)
-        mid = jax.nn.silu(gm(xs, lp["we_gate"].astype(dtype))) * gm(
-            xs, lp["we_up"].astype(dtype)
+        # cast once, outside the conditional: the parts hand their
+        # gradients on in ``dtype``, as the grouped product leaves them;
+        # in f32 they would be written out before the conditional (8 ms
+        # a step of the cell, PERF.md PR 30)
+        weights = tuple(
+            lp[name].astype(dtype) for name in ("we_gate", "we_up", "we_down")
         )
-        ys = gm(mid.astype(dtype), lp["we_down"].astype(dtype))
-    with jax.named_scope("lm_moe_route"):
-        # rows past the last assignment hold whatever the product left
-        ys = jnp.where(row_valid[:, None], ys * row_w[:, None], 0.0)
-        y = _combine(ys.astype(dtype), row_token, slot_row)
+    group_sizes = index[-1]
+    n_head = head_rows(x.shape[0], cfg)
+    if n_head == x.shape[0] * cfg.top_k:  # the whole buffer: no conditional
+        need_tail = jnp.zeros((), bool)
+        y = _buffer_part(x, top_w, weights, index)
+    else:
+        need_tail = jnp.sum(group_sizes) > n_head
+        y = _head_and_tail(
+            x, top_w, weights, *split_buffer(index, n_head), need_tail
+        )
     if cfg.n_shared:
         with jax.named_scope("lm_moe_shared"):
             y = y + swiglu(
@@ -356,6 +505,9 @@ def topk_moe_ffn(lp, h2, cfg: TopKMoEConfig, dtype):
     stride = max(1, x.shape[0] // PROBE_TOKENS)
     stats = {
         "expert_rows": group_sizes, "top_e": top_e,
+        "buffer_passes": jnp.stack(
+            [jnp.ones((), jnp.int32), need_tail.astype(jnp.int32)]
+        ),
         "probe_x": jax.lax.stop_gradient(x[::stride]),
         "probe_e": top_e[::stride],
         "probe_w": jax.lax.stop_gradient(top_w[::stride]),

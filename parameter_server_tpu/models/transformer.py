@@ -29,7 +29,7 @@ from . import latent_attention as latent
 from .attention import ring_attention, ulysses_attention
 from .latent_attention import MLAConfig, rms_norm
 from .moe import (
-    TOP_E, TopKMoEConfig, init_moe, init_topk_moe, moe_ffn, swiglu,
+    MOE_COUNTS, TOP_E, TopKMoEConfig, init_moe, init_topk_moe, moe_ffn, swiglu,
     topk_moe_ffn,
 )
 
@@ -434,9 +434,11 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
     """THE training forward: ``(logits, stats)``. Each layer is what
     ``cfg.layer_kinds`` says it is. ``stats`` holds what the dropless
     expert layers returned (``models/moe.topk_moe_ffn``): summed over
-    them ``expert_rows`` [held] int32 (rows each held expert computed),
-    stacked by layer ``top_e`` [layers, T, k] (the experts each token
-    chose) and the router probes ``probe_x``, ``probe_e``, ``probe_w``;
+    them ``expert_rows`` [held] int32 (rows each held expert computed)
+    and ``buffer_passes`` [2] int32 (passes over the sorted buffer's
+    head, and how many of them needed its tail), stacked by layer
+    ``top_e`` [layers, T, k] (the experts each token chose) and the
+    router probes ``probe_x``, ``probe_e``, ``probe_w``;
     empty for a model with no such layer.
 
     Named scopes, for the device trace: ``lm_attn`` (projections and
@@ -593,7 +595,7 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
     total = {}
     if per_layer:
         total = {
-            k: sum(s[k] for s in per_layer) if k == "expert_rows"
+            k: sum(s[k] for s in per_layer) if k in MOE_COUNTS
             else jnp.stack([s[k] for s in per_layer])
             for k in per_layer[0]
         }

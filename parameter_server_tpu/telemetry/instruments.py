@@ -985,10 +985,18 @@ def lm_instruments(reg: MetricsRegistry) -> Dict[str, object]:
         ),
         "expert_rows": reg.ensure_counter(
             "ps_lm_expert_rows_total",
-            "rows of the sorted-token buffer each held expert of the "
+            "rows of the sorted-token buffers each held expert of the "
             "dropless top-k layers computed (forward count: one per "
             "token routed to the expert, summed over the layers)",
             labelnames=("expert",),
+        ),
+        "buffer_passes": reg.ensure_counter(
+            "ps_lm_moe_buffer_passes_total",
+            "passes of the dropless top-k layers over a part of their "
+            "sorted-token buffer (forward count): head = every layer of "
+            "every step, tail = those whose held assignments passed the "
+            "head, which then cost more than the whole buffer did",
+            labelnames=("part",),
         ),
     }
 

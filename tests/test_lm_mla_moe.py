@@ -7,6 +7,7 @@ beside 1 shared, vocabulary 512, 2 layers.
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import math
 import os
@@ -307,6 +308,146 @@ def test_topk_config_refuses_a_share_outside_the_experts():
         )
     with pytest.raises(ValueError, match="top_k"):
         moelib.TopKMoEConfig(n_experts=4, top_k=5, d_expert=4)
+
+
+# -- the head and the tail of the sorted buffer ------------------------------
+
+# 1 of 16 experts held, top-2, 1,024 tokens: 2,048 rows, 128 expected, a
+# head of 512 and a tail of 1,536
+TAIL_TOKENS = 1024
+
+
+def _tail_layer(routing: str = "alone"):
+    """``(share, lp, x, m)``: a layer that holds expert 5 of 16. Left
+    ``alone`` the router sends it about 128 rows; ``forced`` every token
+    picks it first; at the ``boundary`` exactly the first 512 do, and no
+    other: the head is full and the tail empty."""
+    full = moelib.TopKMoEConfig(n_experts=16, top_k=2, d_expert=32, n_shared=1)
+    lp = moelib.init_topk_moe(jax.random.PRNGKey(3), 64, full, 0.3)
+    lp = {k: v[5:6] if k.startswith("we_") else v for k, v in lp.items()}
+    lp["ln2"] = jnp.ones((64,))
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, TAIL_TOKENS // 2, 64))
+    if routing != "alone":
+        # the held expert reads the first coordinate alone; the others'
+        # logits stay small, so its weight is neither 0 nor 1
+        lp["router"] = (0.1 * lp["router"]).at[:, 5].set(0.0).at[0, 5].set(4.0)
+        first = jnp.arange(TAIL_TOKENS).reshape(2, -1) < 512
+        sign = 1.0 if routing == "forced" else jnp.where(first, 1.0, -1.0)
+        x = x.at[..., 0].set(sign * (jnp.abs(x[..., 0]) + 1.0))
+    share = dataclasses.replace(full, experts_held=1, expert_offset=5)
+    m = {**ref.model(small_desc()), "experts": 16, "held": 1, "offset": 5}
+    return share, lp, x, m
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("routing,rows,tail", [
+    ("alone", None, 0), ("forced", TAIL_TOKENS, 1), ("boundary", 512, 0),
+])
+def test_head_and_tail_are_the_reference_layer(routing, rows, tail, remat):
+    """Output and every gradient, whether the tail runs or not, also
+    when the layer is rematerialised around the saved choices."""
+    share, lp, x, m = _tail_layer(routing)
+    assert moelib.head_rows(TAIL_TOKENS, share) == 512
+    weigh = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+
+    def mine(lp, x):
+        h2 = latent.rms_norm(x, lp["ln2"], m["eps"])
+        y, stats = moelib.topk_moe_ffn(lp, h2, share, jnp.float32)
+        return jnp.sum(y * weigh), (y, stats)
+
+    def theirs(lp, x):
+        y, _ = ref.experts(lp, x, m, blocked=False)
+        return jnp.sum(y * weigh), y
+
+    if remat:
+        mine = jax.checkpoint(
+            mine,
+            policy=jax.checkpoint_policies.save_only_these_names(moelib.TOP_E),
+        )
+    with jax.default_matmul_precision("highest"):
+        (_, (y, stats)), (g_lp, g_x) = jax.value_and_grad(
+            mine, argnums=(0, 1), has_aux=True
+        )(lp, x)
+        (_, want), (w_lp, w_x) = jax.value_and_grad(
+            theirs, argnums=(0, 1), has_aux=True
+        )(lp, x)
+    held = int(stats["expert_rows"].sum())
+    assert held == rows if rows is not None else 0 < held < 512
+    assert stats["buffer_passes"].tolist() == [1, tail]
+    assert rel(y, want) < 1e-5
+    assert rel(g_x, w_x) < 1e-5
+    for leaf in ("router", "we_gate", "we_up", "we_down"):
+        assert float(jnp.abs(w_lp[leaf]).max()) > 1e-3, leaf
+        assert rel(g_lp[leaf], w_lp[leaf]) < 1e-5, leaf
+
+
+@pytest.mark.parametrize("tokens,held,experts,top_k,want", [
+    (16384, 8, 128, 4, 16384),  # the cell: a quarter of its 65,536 rows
+    (TAIL_TOKENS, 1, 16, 2, 512),
+    (4096, 1, 128, 4, 512),  # 4 x 128 expected: one tile
+    (4000, 3, 128, 4, 1536),  # 4 x 375 expected, rounded up to the tile
+    (80, 2, 8, 2, 160),  # a quarter held: the whole buffer
+    (80, 1, 16, 2, 160),  # fewer rows than a tile: the whole buffer
+    (16384, None, 128, 4, 65536),  # all held
+])
+def test_the_head_follows_the_share_of_the_experts_held(
+        tokens, held, experts, top_k, want):
+    cfg = moelib.TopKMoEConfig(
+        n_experts=experts, top_k=top_k, d_expert=4, experts_held=held
+    )
+    assert moelib.head_rows(tokens, cfg) == want
+
+
+@pytest.mark.parametrize("held,experts,conds", [
+    (1, 16, 1), (4, 16, 0), (8, 16, 0), (None, 16, 0),
+])
+def test_a_quarter_of_the_experts_held_means_no_conditional(
+        held, experts, conds):
+    """With no tail the traced layer has no ``cond``, forward or
+    backward; with one, one each way (the recomputed forward's is the
+    forward's again)."""
+    cfg = moelib.TopKMoEConfig(
+        n_experts=experts, top_k=2, d_expert=32, experts_held=held
+    )
+    lp = moelib.init_topk_moe(jax.random.PRNGKey(3), 64, cfg, 0.3)
+    x = jnp.ones((TAIL_TOKENS, 64))
+
+    def out(lp, x):
+        return jnp.sum(moelib.topk_moe_ffn(lp, x, cfg, jnp.float32)[0])
+
+    count = lambda f: sum(  # noqa: E731
+        e.primitive.name == "cond" for e in _eqns(jax.make_jaxpr(f)(lp, x).jaxpr)
+    )
+    assert count(out) == conds
+    assert count(jax.grad(out, argnums=(0, 1))) == 2 * conds
+
+
+def test_the_split_adds_no_scatter_add_as_wide_as_the_model():
+    """Dispatch and combine stay gathers both ways, head and tail: the
+    gradient's only scatter-adds are the router's own, onto the
+    probabilities [T, experts], and those of the rows' weights onto
+    ``top_w`` [T, k], one for each part."""
+    share, lp, x, _ = _tail_layer()
+
+    def out(lp, x):
+        return jnp.sum(moelib.topk_moe_ffn(lp, x, share, jnp.float32)[0])
+
+    jaxpr = jax.make_jaxpr(jax.grad(out, argnums=(0, 1)))(
+        lp, x.reshape(-1, 64)
+    ).jaxpr
+    onto = [
+        e.outvars[0].aval.shape for e in _eqns(jaxpr)
+        if e.primitive.name in ("scatter-add", "scatter_add")
+    ]
+    assert sorted(onto) == [(TAIL_TOKENS, 2)] * 2 + [(TAIL_TOKENS, 16)], onto
 
 
 # -- rope --------------------------------------------------------------------
@@ -640,32 +781,63 @@ def test_a_fused_launch_is_the_same_two_steps():
         two.place(batches[:1])
 
 
-def test_a_collect_counts_tokens_and_rows():
+def _one_of_sixteen_trainer():
+    """A trainer whose layers hold expert 0 of 16 (1,024 tokens: a head
+    of 512 rows) under routers of zeros: every token's top-2 are experts
+    0 and 1, so each layer's pass needs the tail."""
+    desc = ref.description(CONFIG, rehearsal=True)
+    desc["n_routed_experts"] = 1
+    desc["published"] = {**desc["published"], "n_routed_experts": 16}
+    cfg = lm_trainer.model_from_description(desc, remat=True)
+    trainer = lm_trainer.build_trainer(cfg, mesh_of(1), optimizer="adafactor")
+    made = ref.weights(3, ref.model(desc))
+    trainer.load({
+        k: jnp.zeros_like(v) if k.endswith("/router") else v
+        for k, v in made.items()
+    })
+    return trainer
+
+
+@pytest.mark.parametrize("make,shape,tail", [
+    (lambda: _toy_trainer(1), (2, 64), 0),  # 4 of 8 held: no tail at all
+    (_one_of_sixteen_trainer, (2, 512), 2),
+], ids=["half_held", "tail_taken"])
+def test_a_collect_counts_tokens_and_rows(make, shape, tail):
     from parameter_server_tpu.telemetry import registry as telreg
 
-    trainer = _toy_trainer(1)
+    trainer = make()
     batch = np.asarray(
-        jax.random.randint(jax.random.PRNGKey(9), (2, 64), 0, 512)
+        jax.random.randint(jax.random.PRNGKey(9), shape, 0, 512)
     )
+    tokens = shape[0] * shape[1]
 
     def totals():
         state = telreg.default_registry().export_state()
-        return {
+        out = {
             name: sum(s["value"] for s in state[name]["series"])
             for name in ("ps_lm_tokens_total", "ps_lm_expert_rows_total")
             if name in state
         }
+        for s in state.get("ps_lm_moe_buffer_passes_total", {}).get(
+                "series", ()):
+            out[s["labels"]["part"]] = s["value"]
+        return out
 
     before = totals()
     _, stats = trainer.collect(trainer.submit(trainer.place([batch])))
     after = totals()
-    assert after["ps_lm_tokens_total"] - before.get(
-        "ps_lm_tokens_total", 0) == 128
-    assert after["ps_lm_expert_rows_total"] - before.get(
-        "ps_lm_expert_rows_total", 0) == int(stats["expert_rows"].sum())
-    assert set(stats) == {"expert_rows"}  # choices, probes: on the device
-    # 2 layers x 128 tokens x top-2, of which the 4 held of 8 get a part
-    assert 0 < int(stats["expert_rows"].sum()) <= 2 * 128 * 2
+    grew = {k: v - before.get(k, 0) for k, v in after.items()}
+    assert grew["ps_lm_tokens_total"] == tokens
+    assert grew["ps_lm_expert_rows_total"] == int(stats["expert_rows"].sum())
+    # choices and probes stay on the device
+    assert set(stats) == {"expert_rows", "buffer_passes"}
+    # 2 layers x top-2, of which the held experts get a part
+    assert 0 < int(stats["expert_rows"].sum()) <= 2 * tokens * 2
+    # a pass a layer a step over the head, and over the tail where needed
+    assert stats["buffer_passes"].tolist() == [2, tail]
+    assert (grew["head"], grew["tail"]) == (2, tail)
+    if tail:
+        assert stats["expert_rows"].tolist() == [2 * tokens]
 
 
 @pytest.fixture(scope="module")

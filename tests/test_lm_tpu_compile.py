@@ -10,6 +10,7 @@ TPU's library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +36,7 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-@pytest.fixture()
+@pytest.fixture(scope="module")
 def no_compile_cache():
     """A compile for a described chip is written to the persistent cache
     and cannot be read back without one: keep it out."""
@@ -49,16 +50,14 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def test_the_cells_step_compiles_for_one_v5e_chip(
-    topo, no_compile_cache, monkeypatch
-):
+@pytest.fixture(scope="module")
+def step(topo, no_compile_cache):
+    """``(cfg, parameters, compiled)``: the cell's step as its trainer
+    builds it, compiled once for both tests."""
     from parameter_server_tpu.apps.lm import trainer as lm_trainer
     from parameter_server_tpu.models.transformer import init_lm
     from parameter_server_tpu.ops import flash_attention as fa
 
-    # the code asks the backend which attention to take, and the backend
-    # here is the CPU: steer it to the kernels the chip runs
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     desc = lm_trainer.load_description(CONFIG)
     t = desc["train"]
     cfg = lm_trainer.model_from_description(
@@ -75,15 +74,23 @@ def test_the_cells_step_compiles_for_one_v5e_chip(
     params = jax.tree.map(
         spec, jax.eval_shape(lambda k: init_lm(k, cfg), jax.random.PRNGKey(0))
     )
-    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params)) == (
-        1_154_524_160
-    )
     opt = jax.tree.map(spec, jax.eval_shape(trainer.tx.init, params))
     tokens = jax.ShapeDtypeStruct(
         (t["batch"], t["seq_len"]), jnp.int32,
         sharding=NamedSharding(mesh, P(None, "data")),
     )
-    compiled = trainer.step.lower(params, opt, tokens).compile()
+    # the code asks the backend which attention to take, and the backend
+    # here is the CPU: steer it to the kernels the chip runs
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, "_on_tpu", lambda: True)
+        compiled = trainer.step.lower(params, opt, tokens).compile()
+    n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+    return cfg, n_params, compiled
+
+
+def test_the_cells_step_compiles_for_one_v5e_chip(step):
+    _, n_params, compiled = step
+    assert n_params == 1_154_524_160
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3  # the flash kernels are in
     memory = compiled.memory_analysis()
@@ -95,3 +102,26 @@ def test_the_cells_step_compiles_for_one_v5e_chip(
     # buffers of every assignment among them: 11.2 GB when written. A plan
     # over 80% of the chip leaves the allocator no room
     assert 0.25 * CHIP_BYTES < planned < 0.80 * CHIP_BYTES, planned
+
+
+def test_the_buffers_tail_not_taken_moves_nothing(step):
+    """Every expert layer's tail (``models/moe.py``) is a conditional
+    forward and one backward (the recomputed forward's is dead code),
+    and the branch taken when no held assignment passed the head hands
+    its operands back: no copy, no zeros, no instruction at all."""
+    cfg, _, compiled = step
+    text = compiled.as_text()
+    bodies = dict(re.findall(
+        r"^%(\S+) \([^\n]*\{\n(.*?)^\}", text, flags=re.M | re.S
+    ))
+    branches = re.findall(
+        r" conditional\(.*?branch_computations=\{([^}]*)\}", text
+    )
+    n_moe = sum(ffn == "moe" for _, ffn in cfg.layer_kinds)
+    assert len(branches) == 2 * n_moe
+    for names in branches:
+        not_taken = bodies[names.split(",")[0].strip().lstrip("%")]
+        # an opcode is the lower-case word before a bracket (the
+        # layouts' ``T(8,128)`` and ``S(1)`` are upper-case)
+        ops = set(re.findall(r" ([a-z][a-z-]*)\(", not_taken))
+        assert ops and ops <= {"parameter", "get-tuple-element", "tuple"}, ops
