@@ -29,7 +29,7 @@ WORKDIR /home/parameter_server_tpu
 COPY parameter_server_tpu parameter_server_tpu
 COPY configs configs
 COPY script script
-COPY bench.py chip_smoke.py setup.py Makefile ./
+COPY chip_smoke.py setup.py Makefile ./
 RUN make native
 
 ENV PYTHONPATH=/home/parameter_server_tpu

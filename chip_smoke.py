@@ -219,7 +219,10 @@ class Run:
 def start(args) -> Run:
     import jax
 
-    from parameter_server_tpu.benchmarks import device_identity, device_peaks
+    from parameter_server_tpu.telemetry.device import (
+        device_identity,
+        device_peaks,
+    )
 
     device = device_identity()
     if not args.rehearsal and device["platform"] != "tpu":

@@ -15,8 +15,6 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-SKIP = {"parameter_server_tpu.benchmarks.__main__"}
-
 
 def first_line(doc: str | None) -> str:
     line = (doc or "").strip().splitlines()[0] if doc else ""
@@ -78,7 +76,6 @@ def main() -> int:
     names = [pkg.__name__] + sorted(
         m.name
         for m in pkgutil.walk_packages(pkg.__path__, prefix=pkg.__name__ + ".")
-        if m.name not in SKIP
     )
     for name in names:
         describe_module(name, lines)

@@ -804,11 +804,9 @@ def make_pull_lookup(updater, pull_quant: int, noise=None,
     and dequantizes AFTER the gather, instead of materializing and
     gathering a dense f32 shard — the byte-economy instinct behind
     the reference's production 1-byte fixing_float pull
-    (example/linear/ctr/online_l1lr.conf). MEASURED NEGATIVE on TPU
-    (BENCH_ONCHIP 08-02: u8+mask gather 23.6 ms vs f32 18.0 ms at
-    640k indices; bench `_q1` 585k vs 632k ex/s): v5e gathers are
-    row-granularity-bound, not byte-bound, so two narrow gathers lose
-    to one wide one. ``narrow=None`` therefore resolves to the WIDE
+    (example/linear/ctr/online_l1lr.conf). On a v5e a gather is priced
+    by the row and not by the byte (ROADMAP "Speed" 4: PR 27's
+    isolation table), so two narrow gathers lose to one wide one. ``narrow=None`` therefore resolves to the WIDE
     path for every width; narrow stays selectable
     (``pull_gather: "narrow"``) for parts where bytes do bind.
     Exactness-preserving either way: dequantize is elementwise with
@@ -942,8 +940,8 @@ def _donation_variants(step_impl, name: str = "train_step"):
 
     Each jitted variant is wrapped into the device inventory
     (telemetry/device.py) under ``<name>.<variant>``: per-step-builder
-    cost/memory analysis lands in the bench record's ``device``
-    section, new-aval recompiles are counted (zero post-warmup on a
+    cost/memory analysis lands in ``device.snapshot()``, new-aval
+    recompiles are counted (zero post-warmup on a
     healthy run), and the donated variants' input→output aliasing is
     runtime-verified (a fallback means the step silently paid a
     whole-table copy).
@@ -1463,21 +1461,16 @@ _WEIGHTS_WINDOW = 1 << 26
 def sparse_update_min_slots() -> int:
     """``SGDConfig.update="auto"`` flip point, in PER-SERVER shard
     slots: below it the dense sweep wins (the whole-shard Pallas pass
-    is cheap — 2^28 trains at 446k ex/s); at and above it the row
+    is cheap); at and above it the row
     formulation wins — and 2^31 REQUIRES it (the dense gradient temp
-    alone is 8.6 GB). The current 2^30 default was derived from the
-    XLA rows path (~130 ms sweep at 2^30 vs ~80 ms for four 640k-row
-    gathers/scatters, BENCH_ONCHIP component medians). The fused
-    sparse kernel (ops/ftrl_sparse.py) moves the row side of that
-    comparison: once an on-chip ``ftrl_sparse`` A/B capture lands
-    (``make ftrl-bench`` / every bench record), re-derive as the
-    smallest shard where ``ftrl_sparse.fused_ms`` (at the training
-    uniq width) beats the dense sweep's per-ministep cost
-    (``step_phase_ftrl_update_ms`` at that shard) — the kernel only
-    LOWERS this threshold, it never raises it, so 2^30 stays a safe
-    default until the capture re-judges it (doc/PERFORMANCE.md, "FTRL
-    roofline"). Env ``PS_SPARSE_UPDATE_MIN_SLOTS`` overrides while
-    on-chip captures refine the default."""
+    alone is 8.6 GB). The 2^30 default predates the ledger; the cells
+    either side of it are ``criteo_dense.text`` (2^29, the sweep) and
+    ``criteo_bigtable.text`` (2^30, the rows). The fused sparse kernel
+    (ops/ftrl_sparse.py) would move the row side of that comparison
+    and has never been timed on the chip (ROADMAP "Speed" 4, "Design"
+    3; doc/PERFORMANCE.md, "FTRL roofline"): it can only LOWER this
+    threshold, so 2^30 stays a safe default until a chip run judges
+    it. Env ``PS_SPARSE_UPDATE_MIN_SLOTS`` overrides meanwhile."""
     try:
         return int(os.environ.get("PS_SPARSE_UPDATE_MIN_SLOTS", 1 << 30))
     except ValueError:
@@ -3088,8 +3081,7 @@ class AsyncSGDWorker(ISGDCompNode):
         ``pipelined`` (default: on when T > 1) moves prep + stack +
         device staging onto a daemon thread behind a bounded queue, so
         localization CPU time and the host→device wire overlap the
-        device steps this thread is collecting — the same three-stage
-        split bench.py's timed loops use, and the TPU twin of the
+        device steps this thread is collecting — the TPU twin of the
         reference's MinibatchReader producer/consumer overlap
         (src/learner/sgd.h:60-143). Submission still happens HERE, in
         order, so seeds, snapshot scheduling (max_delay), and therefore

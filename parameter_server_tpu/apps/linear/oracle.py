@@ -1,5 +1,5 @@
 """NumPy FTRL oracle — the reference the device trainer's logloss is
-held to (``bench.py --real`` and ``chip_smoke.py``)."""
+held to (``chip_smoke.py``)."""
 
 from __future__ import annotations
 

@@ -13,17 +13,15 @@ a trained-looking FTRL weight table (KVVector, hashed directory),
 wraps it in a :class:`~parameter_server_tpu.serving.ServeFrontend`
 (admission control → worker pool → read replica → request coalescing),
 and reports p50/p99/p99.9 + goodput per offered-load point as JSON
-lines — the same record shape ``make serve-bench`` and ``bench.py``'s
-``serve`` section emit (doc/SERVING.md has the knob guide).
+lines (doc/SERVING.md has the knob guide).
 
 ``--train-while-serving`` streams concurrent donated pushes into the
 live table from a background thread while the load runs — the
 demonstration that replica-served reads never contend with (or get
 invalidated by) the training push path. ``--decode`` adds a
 speculative-decoding LM lane; ``--draft trained`` trains the
-(target, draft) byte-model pair on the structured corpus the
-``spec_big`` on-chip bench uses (script/onchip.py: 2.33x at gamma=8,
-accepted 0.978 on the 860M target), so the reported acceptance rate
+(target, draft) byte-model pair on a structured corpus
+(:func:`_spec_corpus`), so the reported acceptance rate
 reflects a draft that actually tracks its target instead of the
 random-init wiring models. ``--batch-slots N`` serves the decode lane
 through the continuous batcher (serving/batcher.py) instead of one
@@ -42,9 +40,8 @@ import numpy as np
 
 
 def _spec_corpus(rng):
-    """The structured byte corpus every speculative bench shares
-    (script/onchip.py _spec_corpus): a 16-byte cycle with 10% uniform
-    noise — regular enough that a tiny draft tracks the target, noisy
+    """The structured byte corpus the trained draft learns: a 16-byte
+    cycle with 10% uniform noise — regular enough that a tiny draft tracks the target, noisy
     enough that losses stay informative."""
     pat = np.tile(np.arange(97, 113, dtype=np.int32), 1 << 12)
     noise = rng.integers(0, 256, pat.size, np.int32)

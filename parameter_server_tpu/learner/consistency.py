@@ -85,7 +85,7 @@ SNAPSHOT_EVERY = 16
 #: LR multiplier the divergence reaction applies
 BACKOFF_FACTOR = 0.5
 
-#: episode records kept for the bench/debug snapshot
+#: episode records kept for the debug snapshot
 EPISODE_CAP = 64
 
 
@@ -384,8 +384,8 @@ class SignificanceTracker:
             return len(self._dropped)
 
     def summary(self) -> Dict[str, Any]:
-        """Record-embeddable accounting, with the reconciliation
-        identity stated in-place (bench records assert it)."""
+        """The accounting, with the reconciliation identity stated
+        in-place (tests/test_consistency.py asserts it)."""
         return {
             "candidates": self.candidates,
             "suppressed": self.suppressed,
@@ -471,8 +471,7 @@ class ConsistencyRuntime:
         return self.controller.react(reason)
 
     def snapshot(self) -> Dict[str, Any]:
-        """The record-embeddable consistency view (bench `consistency`
-        section + /debug/snapshot)."""
+        """The consistency view of /debug/snapshot."""
         out: Dict[str, Any] = {"worker": self.worker.name}
         if self.controller is not None:
             c = self.controller

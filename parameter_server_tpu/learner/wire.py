@@ -1,9 +1,8 @@
 """Compact host→device wire: encoded batch buffers for the upload path.
 
-The on-chip bench records put the device-only rate at 0.6-1.3M
-examples/sec while e2e through the host→device link collapses to
-68-342k — the link-bound ceiling (bytes/example × link MB/s) IS the
-throughput knob. This module is the host half of the compact wire: the
+Where the host→device link binds, bytes/example × link MB/s is the
+throughput ceiling (``wire_bytes_per_example`` in the benchmark's
+per-layer metrics). This module is the host half of the compact wire: the
 ingest pipeline's prep stage emits *encoded* batch buffers, the jitted
 train step decodes them on device (ops/wire_codec.py), and decoded
 batches never cross the link.
@@ -289,8 +288,7 @@ def encode_exact(
     stream, so every mode is exact for them).
 
     With a span sink installed, the encode emits one ``wire.encode``
-    timeline span carrying the active flow id — the ``encode`` category
-    of the critical-path attribution (telemetry/attribution.py)."""
+    timeline span carrying the active flow id."""
     from ..telemetry import spans as telemetry_spans
 
     if telemetry_spans.get_sink() is None:

@@ -37,14 +37,12 @@ def xla_min_slots() -> int:
     """Dense-update formulation flip point, in slots — DISABLED by
     default (2^62 ≈ never): no measurement on the current code decides
     between the Pallas sweep and the XLA reference at big shards. The
-    comparison that can is ``benchmarks/components.ftrl_chain`` (``make
-    ftrl-bench``), which chains 8 donated updates per dispatch so the
-    kernel runs with its production aliasing; the flip is the smallest
-    sweep size whose ``ftrl_dense_xla_2e{K}_chain_per_update_ms`` beats
-    ``ftrl_dense_pallas_2e{K}_chain_per_update_ms`` in a chip run (no
-    crossover → stays 2^62). Env ``PS_FTRL_XLA_MIN_SLOTS`` remains as
-    the sweep override; the value is baked at trace time per shape (jit
-    static caching)."""
+    comparison that can is a chip run of the dense cell
+    (``criteo_dense.text``) with the flip forced either way, both
+    updates donated so the kernel runs with its production aliasing
+    (ROADMAP "Design" 3; no crossover → stays 2^62). Env
+    ``PS_FTRL_XLA_MIN_SLOTS`` remains as the sweep override; the value
+    is baked at trace time per shape (jit static caching)."""
     try:
         return int(os.environ.get("PS_FTRL_XLA_MIN_SLOTS", 1 << 62))
     except ValueError:
@@ -276,9 +274,8 @@ def ftrl_update(
     jit DONATES the state (the fused production step, max_delay=0)
     get the update copy-free; at a non-donating call site XLA inserts
     defensive whole-table copies of z/sqrt_n to preserve the caller's
-    buffers — correct, but one extra table read+write. Benchmarks
-    must therefore time the donated form (benchmarks/components.py
-    ftrl phase).
+    buffers — correct, but one extra table read+write. A timing
+    must therefore be of the donated form.
 
     ``block_rows`` tiles the slot dimension (default 2048 = 1 MB/ref;
     env ``PS_FTRL_BLOCK_ROWS`` overrides so a cross-process on-chip

@@ -65,7 +65,7 @@ from .ftrl import (
 from .rows import write_index, write_rows
 
 #: update-path names reported by :func:`resolve_update_path` and the
-#: ``ps_ftrl_update_path_total`` telemetry counter / bench records
+#: ``ps_ftrl_update_path_total`` telemetry counter
 PATH_PALLAS_SPARSE = "pallas_sparse"
 PATH_PALLAS_DENSE = "pallas_dense"
 PATH_XLA_ROWS = "xla_rows"
@@ -102,7 +102,7 @@ def resolve_update_path(update_mode: str, *, on_tpu: bool, shard: int,
     """Which FTRL update path a train step with these statics will
     trace — the host-side twin of the in-jit dispatch (the decision is
     static, so the host can name it without touching the device).
-    Feeds the ``ps_ftrl_update_path_total`` counter and bench records:
+    Feeds the ``ps_ftrl_update_path_total`` counter:
 
     - ``pallas_sparse`` — update='sparse' through the fused kernel;
     - ``xla_rows``      — update='sparse' through the XLA
@@ -140,7 +140,7 @@ def ftrl_sparse_rows_ref(z, sqrt_n, rel, ok, g_u, *, alpha, beta, l1,
                          l2, seed=None, rows_ascend=False):
     """XLA rows reference: the exact gather→apply→scatter formulation
     ``updaters.apply_state_rows`` runs for the FTRL/decay case, inlined
-    here so kernel tests and the A/B bench can call it without an
+    here so kernel tests can call it without an
     updater object. Gathers the ``rel`` rows, applies the JITTED
     :func:`ops.ftrl.ftrl_update` exactly as ``FTRLUpdater.apply`` does
     (same ``_ftrl_math``, same position-hash bf16 narrow; calling the
@@ -384,8 +384,8 @@ def _fused_rows_call(z2d, n2d, urows, nrows, g_rows, *, br, alpha, beta,
     )(urows, nrows, z2d, n2d, g_rows)
 
 
-# no-donate: the public z/n entry point is used by parity tests and the
-# A/B bench, which keep their inputs; the fused train step donates at
+# no-donate: the public z/n entry point is used by parity tests,
+# which keep their inputs; the fused train step donates at
 # ITS boundary and the kernel aliases in-block via input_output_aliases
 # (same rule as ops/ftrl.ftrl_update).
 @functools.partial(

@@ -123,7 +123,7 @@ def _pull_impl(table, idx, *, mesh: Mesh, batch_sharded: bool = True):
 # no-donate: pull reads the table; the store keeps serving it afterwards.
 # Every public entry point below is wrapped into the device inventory
 # (telemetry/device.py): each lower().compile() lands its cost/memory
-# analysis in the ``device`` bench section, recompiles are counted per
+# analysis in ``device.snapshot()``, recompiles are counted per
 # name, and the donated paths' aliasing is runtime-verified.
 pull = _device.instrument(
     "kv_pull",

@@ -33,8 +33,7 @@ latency SLO needs —
 
 :mod:`.frontend` composes them into :class:`ServeFrontend`;
 :mod:`.loadgen` is the open-loop Poisson load generator + latency
-recorder behind ``make serve-bench`` and the ``serve`` section of every
-``bench.py`` record (p50/p99/p99.9 + goodput-vs-offered-load).
+recorder behind ``apps/serve`` (p50/p99/p99.9 + goodput-vs-offered-load).
 """
 
 from .admission import AdmissionController, RejectedError, TokenBucket

@@ -21,8 +21,8 @@ Two existing mechanisms make the merged pull cheap:
 Under load the coalescer gets MORE effective, not less: while the
 flusher is executing window t, new arrivals accumulate into window t+1,
 so the merge factor grows exactly when the executor needs relief. The
-bench's acceptance number (``submits_per_request < 1`` at overlapping-
-key load) is the stats pair this class counts.
+acceptance number (``submits_per_request < 1`` at overlapping-key
+load) is the stats pair this class counts.
 
 Threading: clients call :meth:`pull` from any thread; ONE flusher
 thread owns store submission order (the stateful stage of the PR-3
@@ -125,7 +125,7 @@ class PullCoalescer:
         self._open: Optional[_Window] = None  # guarded-by: _cv
         self._open_keys = 0  # guarded-by: _cv — total keys staged in _open
         self._closed = False  # guarded-by: _cv
-        # stats (monotonic; the serve bench reads them): requests in,
+        # stats (monotonic; apps/serve reads them): requests in,
         # submits out, keys requested vs keys actually pulled
         self.requests_total = 0  # guarded-by: _cv
         self.submits_total = 0  # guarded-by: _cv
@@ -261,7 +261,7 @@ class PullCoalescer:
             self._cv.notify_all()
         self._thread.join(timeout=60)
 
-    # -- introspection (the serve bench's coalescing-win numbers) --
+    # -- introspection (the coalescing-win numbers) --
 
     def stats(self) -> dict:
         with self._cv:

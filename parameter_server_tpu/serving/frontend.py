@@ -22,7 +22,7 @@ Requests are typed (:class:`PullRequest` — raw rows;
 ``submit`` is non-blocking: it either raises :class:`RejectedError`
 at the door or returns a :class:`Ticket` whose ``result()`` waits for
 a worker to complete the request. Latency is measured submit→complete
-— the number the open-loop bench quotes as p50/p99.
+— the number the open-loop load generator quotes as p50/p99.
 
 Elasticity: :meth:`pause` gates the workers (admitted requests keep
 queueing; the admission depth gate sheds past the bound — never an
@@ -117,7 +117,7 @@ class ServeConfig:
     # "fallback" — a full snapshot that is NOT consulted on the happy
     # path (reads stay live/fresh through the coalescer) and serves
     # only as the degraded path when the live store fails or misses
-    # its deadline (doc/ROBUSTNESS.md "Degraded-mode serving")
+    # its deadline (doc/SERVING.md "Degraded-mode serving")
     replica: str = "full"
     hot_keys: Optional[np.ndarray] = None
     replica_refresh_s: Optional[float] = None  # None = manual refresh()
@@ -784,7 +784,7 @@ class ServeFrontend:
                     self._executing -= 1
                     self._cv.notify_all()
 
-    # -- introspection (the serve bench's record fields) --
+    # -- introspection (apps/serve's record fields) --
 
     def stats(self) -> dict:
         with self._cv:

@@ -9,9 +9,8 @@ so the bench schedules arrivals from pre-drawn exponential gaps and
 fires them on time whether or not earlier requests completed
 (coordinated-omission-free: a stalled server faces the full backlog).
 
-``open_loop_bench`` returns the dict the ``serve`` section of every
-``bench.py`` record embeds per offered-load point: offered vs accepted
-vs completed rates (goodput), shed counts by reason, and
+``open_loop_bench`` returns the dict ``apps/serve`` prints per
+offered-load point: offered vs accepted vs completed rates (goodput), shed counts by reason, and
 p50/p90/p99/p99.9/max completion latency. Determinism: arrivals come
 from ``np.random.default_rng(seed)``; wall-clock scheduling is the only
 nondeterminism left (disclosed via ``achieved_offered_rate``).

@@ -195,8 +195,7 @@ class Executor:
         self._pending: Dict[int, Tuple[Callable[[], Any], List[int]]] = {}  # guarded-by: _cv
         # dependency-counted readiness (round 5): the original picker
         # re-sorted and re-scanned every pending step per dispatch —
-        # O(n² log n) across an n-step burst, measured at 2.7k steps/s
-        # for a 5000-step burst vs 33k for 500 (benchmarks executor).
+        # O(n² log n) across an n-step burst.
         # Now: unmet-dep counts + a dep→dependents map maintained at
         # submit/finish, and a min-heap of ready timestamps — each
         # step is pushed and popped once.

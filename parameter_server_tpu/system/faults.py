@@ -15,9 +15,7 @@ Design rules:
 
 - **Zero overhead disarmed.** A disarmed point costs one function call,
   one module-int truth test and a return — no lock, no dict lookup, no
-  allocation. The recovery drill's paired-rep A/B
-  (``benchmarks/components.recovery_drill`` → ``disarmed_overhead``)
-  keeps this honest.
+  allocation.
 - **Deterministic under a fixed seed.** Triggers are evaluated against
   a per-point call counter and a per-point ``random.Random`` seeded
   from ``(registry seed, point name)`` — the n-th *call* of a point

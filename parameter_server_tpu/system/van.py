@@ -105,8 +105,7 @@ class Van:
         right here, making a multi-node timeline unstitchable. The
         receiving side re-activates it (``spans.activate_trace``) so
         one batch/request is ONE flow across processes, and the leg
-        itself is a ``van.transfer`` span (the ``network`` resource in
-        telemetry/attribution.py). An explicitly pre-set trace is
+        itself is a ``van.transfer`` span. An explicitly pre-set trace is
         respected (re-sends keep their origin)."""
         if getattr(msg.task, "trace", None) is None:
             msg.task.trace = telemetry_spans.trace_context()

@@ -12,9 +12,6 @@ One low-overhead spine for every layer's observability (see
   threads;
 - :mod:`timeline` — merged cross-thread timeline reader + Chrome
   trace-event / Perfetto export with flow arrows;
-- :mod:`attribution` — critical-path analyzer over a timeline: per-step
-  / per-request attribution to {host-prep, encode, upload, queue-wait,
-  device-compute, decode, reply} and the binding resource;
 - :mod:`instruments` — the canonical catalog of metric names each layer
   records (executor phases, van bytes, parameter push/pull, app volume,
   heartbeat traffic);
@@ -30,9 +27,8 @@ One low-overhead spine for every layer's observability (see
 - :mod:`history` — the time plane: a bounded multi-resolution ring
   cascade (1 s × 10 m → 10 s × 2 h → 60 s × 12 h) over the registry
   with typed downsampling (counters→rate deltas, gauges→last/min/max,
-  histograms→bucket-delta merges), range queries, robust trend
-  estimation and steady-state drift checks
-  (``doc/OBSERVABILITY.md`` "History plane");
+  histograms→bucket-delta merges), range queries and robust trend
+  estimation (``doc/OBSERVABILITY.md`` "History plane");
 - :mod:`device` — the device truth plane: a compiled-function
   inventory over the jit entry points (per-name cost/memory analysis,
   recompile detection, runtime donation-aliasing verification), live
@@ -42,12 +38,11 @@ One low-overhead spine for every layer's observability (see
 
 from .aggregate import CLUSTER_NODE, ClusterAggregator
 from .alerts import AlertManager, AlertRule, default_rules, load_rules
-from .device import DeviceInventory, HbmMonitor, aot_analyze, instrument
+from .device import DeviceInventory, HbmMonitor, instrument
 from .exposition import ExpositionServer, close_cluster, expose_cluster
 from .history import (
     HistoryStore,
     default_store,
-    drift_check,
     installed_store,
     reset_default_store,
     set_default_store,
@@ -93,11 +88,9 @@ __all__ = [
     "Histogram",
     "JsonlSink",
     "MetricsRegistry",
-    "aot_analyze",
     "close_cluster",
     "default_rules",
     "default_store",
-    "drift_check",
     "expose_cluster",
     "installed_store",
     "reset_default_store",

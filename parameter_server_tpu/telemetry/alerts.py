@@ -1,8 +1,8 @@
 """Live SLO alerting: declarative rules evaluated against the registry.
 
 PRs 6 and 9 made overload and failure *survivable* (admission 429s,
-degraded 503s, recovery drills) but only *observable after the fact*,
-by reading a bench record. This module closes the loop online: a small
+degraded 503s, recovery drills) but only *observable after the fact*.
+This module closes the loop online: a small
 Prometheus-alerting-style engine evaluates declarative threshold and
 burn-rate rules on a sliding window of registry samples, walks each
 rule through ``inactive → pending → firing → resolved``, exports the

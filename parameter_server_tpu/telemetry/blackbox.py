@@ -7,15 +7,14 @@ shard wedges, the question is always "what happened in the last few
 seconds", and by the time a human attaches, that evidence is gone.
 This module keeps it: a bounded in-memory ring of recent span events
 plus periodic metrics-delta samples per node (the aircraft flight
-recorder, :class:`FlightRecorder` — zero file IO, overhead measured
-below the host noise floor by the in-record paired A/B), and a
+recorder, :class:`FlightRecorder` — zero file IO), and a
 *trigger plane* that snapshots everything into one self-contained
 **diagnostic bundle** at the moment of an incident:
 
 - alert ``pending→firing`` transitions (``AuxRuntime.set_alerts``),
 - ``DegradedError`` raises on the serving path,
-- a node declared dead by the RecoveryCoordinator (the drill's shard
-  kill — the record attaches the bundle under ``blackbox``),
+- a node declared dead by the RecoveryCoordinator (the recovery
+  drill's shard kill, tests/test_faults.py),
 - a wedged executor ``wait`` timeout.
 
 A bundle carries ring dumps from every node — fetched over the Van
@@ -26,7 +25,7 @@ clock offsets, the down-sampled **history hour** before the trigger
 (telemetry/history.py — the installed ring exported at the coarsest
 resolution covering 3600 s), and a Perfetto-ready ``trace`` (open
 ``bundle["trace"]`` at https://ui.perfetto.dev). It is served live at ``/debug/bundle``
-(telemetry/exposition.py) and on demand via ``make bundle``.
+(telemetry/exposition.py).
 
 Threading: the recorder is **lock-annotated** shared state (spans are
 emitted from every pipeline thread — the stateless-or-feeder rule's
@@ -431,10 +430,9 @@ def capture_bundle(
 
 
 def summarize_bundle(bundle: Dict[str, Any]) -> Dict[str, Any]:
-    """A record-embeddable digest of a bundle (the drill's ``blackbox``
-    section): per-node ring event counts / staleness, alert states,
-    trigger — everything an assertion needs without megabytes of
-    events in a bench record."""
+    """A digest of a bundle: per-node ring event counts / staleness,
+    alert states, trigger — everything an assertion needs without
+    megabytes of events."""
     rings = bundle.get("rings", {})
     nodes = {}
     for nid, d in sorted(rings.items()):
@@ -537,80 +535,3 @@ def last_bundle() -> Optional[Dict[str, Any]]:
 def bundles() -> List[Dict[str, Any]]:
     with _trigger_lock:
         return list(_bundles)
-
-
-# -- in-record overhead A/B (the PR 9 disarmed-overhead pattern) -----------
-
-
-def overhead_ab(reps: int = 5, n: int = 400) -> Dict[str, Any]:
-    """Steady-state recorder overhead, measured the PR 9 disarmed-
-    overhead way: the SAME span-instrumented work stream (spans wrap
-    real work, as they do in production — span density per unit work is
-    what matters, not a bare span loop) with the ring armed (tee, no
-    inner sink — the always-on black-box mode) vs no sink at all, both
-    orders inside one rep so a monotone capacity drift on this flapping
-    host cancels out of the paired ratio. The honest claim is the
-    median ratio straddling the host's noise floor; because the stream
-    ratio is hostage to seconds-scale capacity flaps, the absolute cost
-    is ALSO priced as a tight-loop ``armed_ns_per_event`` a flap cannot
-    fake. Zero file IO in both arms — asserted by the tee having no
-    path."""
-    rec = FlightRecorder(capacity=1024, node_id="ovh")
-    tee = TeeSink(rec, inner=None)
-    assert tee.path is None  # armed-but-idle: no file IO by construction
-    sink_of = {"armed": tee, "off": None}
-
-    def stream() -> float:
-        # ~50-100µs of real work per span — the production span density
-        # (a span wraps a prep stage or an executor step, never nothing)
-        acc = 0.0
-        for i in range(n):
-            with _spans.span("bb.ovh"):
-                for j in range(1500):
-                    acc += j * 1e-9
-        return acc
-
-    def timed(arm: str) -> float:
-        _spans.install_sink(sink_of[arm])
-        t0 = time.perf_counter()
-        stream()
-        return time.perf_counter() - t0
-
-    prev = _spans.install_sink(None)
-    try:
-        timed("armed")  # warm both shapes
-        timed("off")
-        ratios = []
-        for _ in range(reps):
-            # both orders inside one rep: armed, off, off, armed
-            a1 = timed("armed")
-            o = (timed("off") + timed("off")) / 2
-            a2 = timed("armed")
-            ratios.append(((a1 + a2) / 2) / max(o, 1e-9))
-        # tight-loop absolute: empty spans, armed — the pure per-event
-        # recorder cost (dict build + tee emit + ring append)
-        _spans.install_sink(tee)
-        m = 20_000
-        t0 = time.perf_counter()
-        for _ in range(m):
-            with _spans.span("bb.tight"):
-                pass
-        armed_ns = (time.perf_counter() - t0) / m * 1e9
-        _spans.install_sink(None)
-        t0 = time.perf_counter()
-        for _ in range(m):
-            with _spans.span("bb.tight"):
-                pass
-        off_ns = (time.perf_counter() - t0) / m * 1e9
-    finally:
-        _spans.install_sink(prev)
-    ratios.sort()
-    return {
-        "reps": reps,
-        "spans_per_rep": n,
-        "ratio_median": round(ratios[len(ratios) // 2], 3),
-        "armed_ns_per_event": round(armed_ns, 1),
-        "disarmed_ns_per_event": round(off_ns, 1),
-        "added_ns_per_event": round(armed_ns - off_ns, 1),
-        "file_io": False,
-    }

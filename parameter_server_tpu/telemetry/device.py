@@ -16,8 +16,8 @@ first-class:
   with new avals/statics → ``ps_device_recompiles_total{fn}``), and
   verifies that declared donation actually aliased
   (``memory_analysis().alias_size_in_bytes`` against the donated
-  argument bytes + the XLA "donated buffers were not usable" warning
-  → ``ps_device_donation_fallbacks_total{fn}``) — the runtime twin of
+  argument bytes → ``ps_device_donation_fallbacks_total{fn}``) — the
+  runtime twin of
   the static donation lint (doc/PERFORMANCE.md "Donation rules").
 - **Roofline gauges**: with sampling enabled
   (:func:`set_sampling`), every N-th instrumented dispatch is timed to
@@ -25,7 +25,7 @@ first-class:
   cost-analysis bytes/FLOPs and land as
   ``ps_device_kernel_gb_s{fn}`` / ``ps_device_kernel_tflops{fn}``,
   with ``ps_device_roofline_frac{fn,resource}`` against the
-  ``benchmarks.HBM_PEAK_GB_S`` / ``FLOPS_PEAK_TFLOPS`` peak tables
+  ``HBM_PEAK_GB_S`` / ``FLOPS_PEAK_TFLOPS`` peak tables below
   (unknown device kinds report no frac, never a faked one).
 - **HBM accounting** (:class:`HbmMonitor`): a registry collector
   sampling ``jax.local_devices()[*].memory_stats()`` (bytes in use /
@@ -47,9 +47,8 @@ Statics must be passed as
 keyword arguments at instrumented call sites (true for every wrap
 point: ops/kv_ops, ops jit entry points, the async_sgd step builders).
 
-``bench.py`` embeds :func:`snapshot` as the ``device`` section of
-every record; ``doc/OBSERVABILITY.md`` ("Device truth plane")
-documents how to read it.
+``doc/OBSERVABILITY.md`` ("Device truth plane") documents how to
+read :func:`snapshot`.
 """
 
 from __future__ import annotations
@@ -57,20 +56,75 @@ from __future__ import annotations
 import math
 import threading
 import time
-import warnings
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 from . import registry as telemetry_registry
 
-#: substring of the jax warning emitted when a declared donation could
-#: not alias (shape/dtype mismatch, or a backend without donation)
-_DONATION_WARNING = "donated buffers were not usable"
+
+#: The package's one peaks table, keyed by
+#: ``jax.devices()[0].device_kind`` (a TPU v5e reports "TPU v5 lite").
+#: Sources: Google Cloud TPU documentation, "System architecture" pages
+#: per generation ("TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 819
+#: GB/s HBM). On a chip a kind missing here is an error
+#: (:func:`device_peaks`); a CPU host resolves to None and every
+#: frac-of-peak field is then null, never faked.
+#:
+#: chip HBM peak bandwidth (GB/s): the denominator of
+#: ``ps_device_roofline_frac{resource="hbm"}``
+HBM_PEAK_GB_S = {
+    "TPU v4": 1228.0,
+    "TPU v5 lite": 819.0,
+    "TPU v5e": 819.0,
+    "TPU v5": 2765.0,
+    "TPU v5p": 2765.0,
+    "TPU v6 lite": 1640.0,
+    "TPU v6e": 1640.0,
+}
+
+#: chip bf16 matmul peak (TFLOP/s): the denominator of
+#: ``ps_device_roofline_frac{resource="flops"}``
+FLOPS_PEAK_TFLOPS = {
+    "TPU v4": 275.0,
+    "TPU v5 lite": 197.0,
+    "TPU v5e": 197.0,
+    "TPU v5": 459.0,
+    "TPU v5p": 459.0,
+    "TPU v6 lite": 918.0,
+    "TPU v6e": 918.0,
+}
+
+
+def device_identity() -> dict:
+    """The device every result names, as jax reports it (initializes
+    the backend)."""
+    import jax
+
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "count": len(devs),
+    }
+
+
+def device_peaks(device_kind: str) -> dict:
+    """``{"hbm_gb_s", "bf16_tflops"}`` of a chip; raises for a kind the
+    table does not list (a chip measured against no peak would report
+    no roofline share and nobody would notice)."""
+    if device_kind not in HBM_PEAK_GB_S or device_kind not in FLOPS_PEAK_TFLOPS:
+        raise KeyError(
+            f"device_kind {device_kind!r} is not in the peaks table "
+            f"(parameter_server_tpu/telemetry/device.py); add it with "
+            "its source before measuring on it"
+        )
+    return {
+        "hbm_gb_s": HBM_PEAK_GB_S[device_kind],
+        "bf16_tflops": FLOPS_PEAK_TFLOPS[device_kind],
+    }
 
 
 def _peaks(device_kind: str) -> Tuple[Optional[float], Optional[float]]:
     """(HBM peak GB/s, bf16 peak TFLOP/s) for a device kind, or Nones."""
-    from ..benchmarks import FLOPS_PEAK_TFLOPS, HBM_PEAK_GB_S
-
     return HBM_PEAK_GB_S.get(device_kind), FLOPS_PEAK_TFLOPS.get(device_kind)
 
 
@@ -195,33 +249,6 @@ def _custom_calls(compiled) -> Tuple[str, ...]:
     )))
 
 
-def aot_analyze(jit_fn, *args, **kwargs) -> Optional[Dict[str, Any]]:
-    """One-shot AOT analysis of a jitted callable at concrete args:
-    ``{"flops", "bytes_accessed", "argument_bytes", ..., "donation_
-    warned"}`` via ``lower().compile()``, or None when the backend
-    cannot lower/analyze. Pays one compile; bench cross-checks
-    (components.ftrl_sparse_ab, the flash probe) use this to put the
-    XLA-derived bytes/FLOPs next to their hand models."""
-    try:
-        with warnings.catch_warnings(record=True) as wlist:
-            warnings.simplefilter("always")
-            compiled = jit_fn.lower(*args, **kwargs).compile()
-        out: Dict[str, Any] = {
-            "donation_warned": any(
-                _DONATION_WARNING in str(w.message) for w in wlist
-            ),
-        }
-        cost = _cost_dict(compiled)
-        if cost:
-            out.update(cost)
-        mem = _memory_dict(compiled)
-        if mem:
-            out.update(mem)
-        return out
-    except Exception:
-        return None
-
-
 class _FnRecord:
     """Inventory state of one named function (all fields guarded by
     the owning inventory's lock)."""
@@ -321,7 +348,7 @@ class DeviceInventory:
         for the roofline gauges (0 disables — the production default:
         a timed call blocks on its result, which an async pipeline
         should only pay when someone is measuring). Returns the
-        previous value so benches can restore it."""
+        previous value so a caller can restore it."""
         with self._lock:
             prev, self._sample_every = self._sample_every, max(0, int(every))
         return prev
@@ -713,7 +740,7 @@ class HbmMonitor:
                 )
 
     def snapshot(self) -> Dict[str, Any]:
-        """Freshly collected HBM view for the bench record."""
+        """Freshly collected HBM view."""
         self.collect()
         with self._lock:
             return dict(self._last)
@@ -790,8 +817,8 @@ def install_hbm_monitor(reg=None) -> Optional[HbmMonitor]:
 
 
 def snapshot() -> Dict[str, Any]:
-    """The bench record's ``device`` section body: inventory counters +
-    cost analyses + the HBM view, stamped with the backend identity."""
+    """Inventory counters + cost analyses + the HBM view, stamped with
+    the backend identity."""
     out = _default_inventory.snapshot()
     try:
         import jax

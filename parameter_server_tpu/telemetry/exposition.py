@@ -22,8 +22,8 @@ port 0 test-friendly, clean join on shutdown) serving
 
 Wiring is one call: :func:`expose_cluster` stands the endpoint up over
 a started Postoffice (aux runtime + metric-report timer + default
-alert rules), which is exactly what ``bench.py --expose-port``,
-``apps/serve --expose-port`` and ``make metrics-serve`` do.
+alert rules), which is exactly what ``apps/serve --expose-port`` and
+``make metrics-serve`` do.
 :class:`ExpositionServer` itself only needs three callables, so tests
 (and single-registry processes) can serve anything.
 """
@@ -398,7 +398,7 @@ def expose_cluster(
 
 def close_cluster(srv: Optional[ExpositionServer]) -> None:
     """Tear down an :func:`expose_cluster` server + its aux runtime
-    (idempotent, None-safe — bench teardown paths call it from finally
+    (idempotent, None-safe: teardown paths call it from finally
     blocks)."""
     if srv is None:
         return

@@ -172,50 +172,6 @@ def monotonic_fractions(values: Sequence[float]) -> Tuple[float, float]:
     return ups / steps, downs / steps
 
 
-def drift_check(
-    samples: Sequence[Tuple[float, float]],
-    baseline_frac: float = 0.3,
-    tail_frac: float = 0.3,
-    tol: float = 0.15,
-    min_points: int = 6,
-) -> dict:
-    """Live steady-state drift verdict over a run's own (t, throughput)
-    windows — bench_diff's idea reborn online: the tail of the run is
-    judged against its post-warmup baseline, same-host same-run, so no
-    cross-run capacity drift can alibi or fake the verdict. Median of
-    each segment (robust to one throttled window) + the Theil-Sen
-    slope as supporting evidence. ``drifting`` only flags DOWNWARD
-    drift beyond ``tol`` — a run that speeds up is not a defect."""
-    pts = [(float(t), float(v)) for t, v in samples]
-    out: dict = {"n": len(pts), "tol": tol}
-    if len(pts) < min_points:
-        out["verdict"] = "insufficient-data"
-        out["drifting"] = False
-        return out
-    pts.sort(key=lambda p: p[0])
-    k_base = max(2, int(len(pts) * baseline_frac))
-    k_tail = max(2, int(len(pts) * tail_frac))
-
-    def median(vals: List[float]) -> float:
-        s = sorted(vals)
-        m = len(s) // 2
-        return s[m] if len(s) % 2 else 0.5 * (s[m - 1] + s[m])
-
-    base = median([v for _, v in pts[:k_base]])
-    tail = median([v for _, v in pts[-k_tail:]])
-    ratio = tail / base if base > 0 else None
-    out.update({
-        "baseline_median": base,
-        "tail_median": tail,
-        "ratio": ratio,
-        "slope_per_s": theil_sen(pts),
-    })
-    drifting = ratio is not None and ratio < 1.0 - tol
-    out["drifting"] = drifting
-    out["verdict"] = "drift-down" if drifting else "ok"
-    return out
-
-
 class HistoryStore:
     """The bounded multi-resolution store over one MetricsRegistry.
 

@@ -285,7 +285,7 @@ def serve_instruments(reg: MetricsRegistry) -> Dict[str, object]:
         "latency": reg.ensure_histogram(
             "ps_serve_latency_seconds",
             "request latency submit to completion, by kind — the "
-            "serving SLO number (open-loop p50/p99 in bench records)",
+            "serving SLO number (open-loop p50/p99 in apps/serve records)",
             labelnames=("kind",),
             buckets=PHASE_BUCKETS,
         ),
@@ -382,7 +382,7 @@ def ftrl_instruments(reg: MetricsRegistry) -> Dict[str, object]:
     counters are incremented on the HOST at submit time (jit-purity:
     an in-kernel counter would fire once at trace and never again);
     they say which update formulation the training traffic actually
-    rode, next to the ``ftrl_sparse`` A/B in bench records."""
+    rode."""
     return {
         "rows": reg.ensure_counter(
             "ps_ftrl_rows_total",
@@ -403,7 +403,8 @@ def device_instruments(reg: MetricsRegistry) -> Dict[str, object]:
     """Device truth plane (telemetry/device.py): per-jit compile and
     recompile counts from the compiled-function inventory, the runtime
     donation-aliasing verifier, live roofline gauges (achieved GB/s /
-    TFLOP/s and frac-of-peak against the benchmarks peak tables), and
+    TFLOP/s and frac-of-peak against telemetry/device.py's peak
+    tables), and
     HBM/live-buffer accounting sampled by a registry collector. The
     ``fn`` label is the inventory name the wrap point declared
     (kv_push, step_encoded_scan.snap_donate, ...); ``resource`` is
@@ -764,8 +765,7 @@ def learning_instruments(reg: MetricsRegistry) -> Dict[str, object]:
     host-side from the step builders' in-jit side outputs. Five planes
     watch the system (seconds, bytes, FLOPs, incidents); this family
     watches the learning — a NaN'd table or a τ breach becomes a
-    metric, an alert rule, and a bench-record section instead of a
-    silent 200."""
+    metric and an alert rule instead of a silent 200."""
     return {
         "staleness": reg.ensure_histogram(
             "ps_learning_staleness_ministeps",
@@ -891,7 +891,8 @@ def consistency_instruments(reg: MetricsRegistry) -> Dict[str, object]:
     in-record against ``ps_push_keys_total``:
     pushed + suppressed == candidates (the in-jit mask), and
     candidates + dropped == the unfiltered baseline (the host-side
-    persistent-drop set) — bench records assert both identities."""
+    persistent-drop set); tests/test_consistency.py asserts both
+    identities."""
     return {
         "tau": reg.ensure_gauge(
             "ps_consistency_tau",

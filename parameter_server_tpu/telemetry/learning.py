@@ -21,8 +21,8 @@ chip:
   measured invariant — it meters the same counter the snapshot refresh
   enforces, so it is a regression detector for the ENFORCEMENT (a
   skipped or mis-scheduled refresh reads > τ and fires), not an
-  independent oracle of it (bench records assert ``observed <= τ``
-  in-record; the ``staleness_breach`` rule fires live on
+  independent oracle of it (tests/test_learning.py asserts
+  ``observed <= τ``; the ``staleness_breach`` rule fires live on
   ``ps_learning_staleness_over_tau > 0``). Since PR 20 the bound is
   the LIVE τ: each submission is judged against the effective τ in
   force when it was stamped (:meth:`LearningPlane.set_tau`; the
@@ -71,8 +71,8 @@ import numpy as np
 
 from . import registry as telemetry_registry
 
-#: trajectory points kept per plane (loss/grad-norm tail for the bench
-#: record's ``learning`` section; the full stream rides the metrics)
+#: trajectory points kept per plane (loss/grad-norm tail of
+#: ``snapshot()``; the full stream rides the metrics)
 TRAJECTORY_CAP = 512
 
 #: grad-norm spike factor: a collected step whose grad norm exceeds
@@ -529,8 +529,8 @@ def _safe_sqrt(v: float) -> float:
 
 
 def _json_float(v: Optional[float]) -> Optional[float]:
-    """JSON-able scalar: non-finite floats become strings (a bench
-    record with a literal NaN would be unparseable JSON)."""
+    """JSON-able scalar: non-finite floats become strings (a snapshot
+    with a literal NaN would be unparseable JSON)."""
     if v is None:
         return None
     if not math.isfinite(v):

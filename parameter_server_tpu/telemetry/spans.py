@@ -1,8 +1,8 @@
 """Logical-clock span tracing: host wall time correlated to executor time.
 
 The executor's logical clocks (``Task.time``) order every step but carry
-no timing; ``bench.py`` can summarize an XLA device trace but sees
-nothing host-side. A *span* bridges the two: a host wall-time interval
+no timing, and an XLA device trace sees nothing host-side. A *span*
+bridges the two: a host wall-time interval
 stamped with the logical timestamp it serves, emitted as one JSONL line
 through the process sink. The executor emits one ``executor.step`` event
 per finished step carrying all three phases (queue-wait from submit to
@@ -95,7 +95,7 @@ def sink_state() -> str:
     empty timeline tail (/debug/snapshot) can tell "no trace captured
     because nothing is listening" apart from "nothing happened":
     ``parked`` means a sink exists but is temporarily uninstalled
-    (:func:`parked_sink`, the embedded-A/B idiom), ``absent`` means no
+    (:func:`parked_sink`), ``absent`` means no
     sink was ever installed (or it was closed)."""
     with _sink_lock:
         if _sink is not None:
@@ -146,10 +146,9 @@ def maybe_new_flow() -> Optional[int]:
 
 @contextlib.contextmanager
 def parked_sink():
-    """Temporarily uninstall the span sink for a block — used around
-    embedded A/B benches whose instrumented arms would otherwise pay a
-    one-sided tracing tax and flood the run's trace with off-window
-    events. Restores the previous sink on exit. While parked,
+    """Temporarily uninstall the span sink for a block, so that work
+    outside the traced window neither pays for tracing nor floods the
+    run's trace with off-window events. Restores the previous sink on exit. While parked,
     :func:`sink_state` reports ``parked`` (only if a sink actually
     existed — parking nothing is still ``absent``)."""
     global _parked_depth

@@ -8,8 +8,9 @@ run; serve submit → admission → coalescer flush → executor → reply)
 whose bottleneck shifts per run. This module turns the flat stream into
 a *timeline*: per-thread tracks, flow arrows stitching each batch or
 request across threads (the ``flow`` ids :func:`spans.new_flow`
-allocates), and the input of the critical-path analyzer
-(:mod:`telemetry.attribution`).
+allocates). The flight recorder's bundle (``telemetry/blackbox.py``,
+the ``trace`` section) and the exposition's debug tail
+(``telemetry/exposition.py``) read it.
 
 Export format is the Chrome trace-event JSON array form — open it at
 https://ui.perfetto.dev (or chrome://tracing): each span becomes one
@@ -145,52 +146,6 @@ def merge_node_events(
             merged.append(ev)
     merged.sort(key=lambda e: _start_end(e)[0])
     return merged
-
-
-def merge_node_sinks(
-    node_paths: Dict[str, str],
-    offsets: Optional[Dict[str, float]] = None,
-) -> List[Dict[str, Any]]:
-    """:func:`merge_node_events` over per-node JSONL sink files."""
-    return merge_node_events(
-        {node: load_events(path) for node, path in node_paths.items()},
-        offsets,
-    )
-
-
-def merge_device_track(
-    host_events: Sequence[Dict[str, Any]],
-    device_events: Sequence[Dict[str, Any]],
-) -> List[Dict[str, Any]]:
-    """Merge a profiler-derived device track
-    (utils/profiling.device_track_events) into a host span timeline.
-
-    Each device span inherits the flow id of the ``executor.step`` span
-    whose submit→finish interval contains its midpoint — the submitting
-    step — so the Chrome export draws flow arrows from the host step
-    phases onto the device ops they launched, and the attribution
-    reader can group device time per unit of work. Device spans whose
-    midpoint lands in no step (profiler warmup, gaps) merge without a
-    flow: they still render on the ``device:<pid>`` track, they just
-    draw no arrows. Returns a new time-sorted list; inputs unmodified.
-    """
-    steps: List[Tuple[float, float, int]] = []
-    for ev in host_events:
-        if ev.get("name") == "executor.step" and ev.get("flow") is not None:
-            s, e = _start_end(ev)
-            steps.append((s, e, int(ev["flow"])))
-    steps.sort()
-    out = list(host_events)
-    for ev in device_events:
-        ev = dict(ev)
-        mid = float(ev.get("t_wall", 0.0)) + float(ev.get("dur_s", 0.0)) / 2.0
-        for s, e, fid in steps:
-            if s <= mid <= e:
-                ev["flow"] = fid
-                break
-        out.append(ev)
-    out.sort(key=lambda e: _start_end(e)[0])
-    return out
 
 
 def to_chrome_trace(
@@ -373,19 +328,8 @@ def export_chrome_trace(
     jsonl_path: str, out_path: str, **kwargs
 ) -> Dict[str, Any]:
     """Load a JSONL span trace and write the Chrome trace JSON next to
-    it; returns the trace object (callers embed summary stats). A
-    merged device track in the JSONL (``device.*`` events from a
-    --profile run) is re-stitched to its submitting steps so the export
-    carries the host→device flow arrows."""
-    from .attribution import is_device_event
-
-    events = load_events(jsonl_path)
-    dev = [e for e in events if is_device_event(e)]
-    if dev:
-        events = merge_device_track(
-            [e for e in events if not is_device_event(e)], dev
-        )
-    trace = to_chrome_trace(events, **kwargs)
+    it; returns the trace object."""
+    trace = to_chrome_trace(load_events(jsonl_path), **kwargs)
     with open(out_path, "w", encoding="utf-8") as f:
         json.dump(trace, f)
     return trace

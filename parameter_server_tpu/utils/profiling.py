@@ -56,8 +56,7 @@ def annotate(name: str, **keys):
 # events carry the HLO op name (and, through jax.named_scope, our
 # phase prefix) either in the event name or in args.name/args.tf_op.
 # Summarizing it here turns a --profile capture into a self-contained
-# breakdown table in the bench record — no TensorBoard needed on the
-# capture host (the r3 verdict's "where does the step time go").
+# breakdown table: no TensorBoard needed on the capture host.
 
 _PHASE_PREFIXES = (
     "ps_decode", "ps_pull", "ps_compute", "ps_push", "ps_update",
@@ -128,9 +127,9 @@ def _iter_trace_events(log_dir: str):
 
 
 def _device_op_keys(pnames: dict, tnames: dict):
-    """(device_pids, keep(pid, tid)) — the device-op track filter shared
-    by :func:`summarize_trace` and :func:`device_track_events`: pids
-    whose process name looks like a device, and within them only
+    """(device_pids, keep(pid, tid)): the device-op track filter of
+    :func:`summarize_trace`: pids whose process name looks like a
+    device, and within them only
     op-level tids (prefer threads named "XLA Ops"; a device pid without
     one keeps its tids minus Module/Step aggregates, which cover the
     sum of their ops and would double everything)."""
@@ -162,63 +161,6 @@ def _device_op_keys(pnames: dict, tnames: dict):
         return key not in excluded
 
     return device_pids, keep
-
-
-def device_track_events(
-    log_dir: str,
-    host_anchor: "float | None" = None,
-    max_events: int = 4000,
-) -> "list[dict]":
-    """The newest capture's device-op complete events as span-sink-shaped
-    dicts — the device track of a merged timeline.
-
-    Each op becomes ``{"kind": "span", "name": "device.<op>", "thread":
-    "device:<pid>", "t_wall": ..., "dur_s": ...}``, consumable by the
-    same readers as host spans (telemetry/timeline.py export,
-    telemetry/attribution.device_breakdown). Durations are exact trace
-    truth; ABSOLUTE placement is best-effort — the profiler clock has
-    no wall reference, so the track is shifted as a block to start at
-    ``host_anchor`` (the host wall time of the profiled launch,
-    bench.py phase_breakdown); nothing is clipped at the far end. Ops
-    beyond ``max_events`` are dropped longest-kept (sorted by
-    duration) and the truncation is visible as ``len() ==
-    max_events``; never raises (result-path code)."""
-    try:
-        collected: "list[tuple[int, float, float, str]]" = []
-        for pnames, tnames, events in _iter_trace_events(log_dir):
-            _, keep = _device_op_keys(pnames, tnames)
-            for ev in events:
-                if not isinstance(ev, dict) or ev.get("ph") != "X":
-                    continue
-                if not keep(ev.get("pid"), ev.get("tid")):
-                    continue
-                dur = ev.get("dur")
-                if not dur:
-                    continue
-                name = str(ev.get("name") or "?")[:80]
-                collected.append(
-                    (ev.get("pid"), float(ev.get("ts", 0.0)), float(dur), name)
-                )
-        if not collected:
-            return []
-        if len(collected) > max_events:
-            collected = sorted(collected, key=lambda c: -c[2])[:max_events]
-        t0_us = min(c[1] for c in collected)
-        base = host_anchor if host_anchor is not None else 0.0
-        out = [
-            {
-                "kind": "span",
-                "name": f"device.{name}",
-                "thread": f"device:{pid}",
-                "t_wall": base + (ts - t0_us) / 1e6,
-                "dur_s": dur / 1e6,
-            }
-            for pid, ts, dur, name in collected
-        ]
-        out.sort(key=lambda e: e["t_wall"])
-        return out
-    except Exception:  # pragma: no cover - defensive: result-path code
-        return []
 
 
 def _self_times(track_events: "list[dict]"):

@@ -12,7 +12,7 @@ and fails on:
   ``_seconds`` or ``_bytes`` unit suffix (naming-convention drift);
 - a render_text() exposition that does not parse as Prometheus text;
 - **orphan registrations**: any ``ps_*`` instrument registered by name
-  anywhere in the package (or bench.py) outside the canonical catalog.
+  anywhere in the package outside the canonical catalog.
   The exposition endpoint serves whatever the registry holds, so a
   call-site-invented name would ship undocumented, un-linted series —
   every ``ps_*`` name must exist in ``instruments.py`` (satellite of
@@ -50,12 +50,12 @@ _CATALOG_REL = os.path.join("telemetry", "instruments.py")
 
 def orphan_problems(root: str, catalog_names: "set[str]") -> list:
     """Static AST sweep: every ``reg.counter("ps_...")``-shaped call in
-    the package (+ bench.py) must name a metric the canonical catalog
+    the package must name a metric the canonical catalog
     declares. Catches runtime-registered orphans that would be served
     by the exposition endpoint but documented and linted nowhere."""
     problems = []
     pkg = os.path.join(root, "parameter_server_tpu")
-    paths = [os.path.join(root, "bench.py")]
+    paths = []
     for dirpath, dirnames, filenames in os.walk(pkg):
         dirnames[:] = [d for d in dirnames if d != "__pycache__"]
         paths.extend(
@@ -63,7 +63,7 @@ def orphan_problems(root: str, catalog_names: "set[str]") -> list:
         )
     for path in sorted(paths):
         rel = os.path.relpath(path, root)
-        if rel.endswith(_CATALOG_REL) or not os.path.exists(path):
+        if rel.endswith(_CATALOG_REL):
             continue
         try:
             with open(path, encoding="utf-8") as f:

@@ -1,39 +1,27 @@
 """Cross-artifact consistency pass (rule ``cross-artifact``).
 
-Names that cross an artifact boundary — a fault-point string in code, a
-metric name in an alert JSON, a benchmark key in the Makefile — have no
-compiler: when one side drifts, the other becomes a silent no-op (an
-alert that never fires, a drill that never injects). This pass pins
-each reference side to its truth side and fails the lint on drift.
-Finding sub-rules (suppression keys):
+Names that cross an artifact boundary (a fault-point string in code, a
+metric name in an alert JSON) have no compiler: when one side drifts,
+the other becomes a silent no-op (an alert that never fires, a drill
+that never injects). This pass pins each reference side to its truth
+side and fails the lint on drift. Finding sub-rules (suppression keys):
 
-- ``fault-point`` — the point name at every ``faults.inject`` /
+- ``fault-point``: the point name at every ``faults.inject`` /
   ``faults.arm`` / ``faults.scoped`` call site must be a member of
   ``faults.POINTS`` (the runtime rejects unknown names too, but only
-  when that code path actually runs — a drill nobody exercises drifts
+  when that code path actually runs: a drill nobody exercises drifts
   silently);
-- ``alert-metric`` — every ``"metric"`` / ``"den"`` name in
+- ``alert-metric``: every ``"metric"`` / ``"den"`` name in
   ``configs/alerts/*.json`` must exist in the instruments catalog
   (``telemetry/instruments.py`` string constants): a rule over a
-  renamed metric evaluates forever against an absent series;
-- ``bench-wiring`` — every benchmark key the Makefile invokes
-  (``python -m parameter_server_tpu.benchmarks <key>``) must exist in
-  the ``@benchmark("<key>")`` REGISTRY; every REGISTRY key must be
-  referenced somewhere (Makefile or ``tests/test_benchmarks.py``) so
-  registered benchmarks cannot become unreachable dead code;
-- ``metadata-section`` — every name in ``script/bench_diff.py``'s
-  ``METADATA_SECTIONS`` must appear as a string constant in the bench
-  record producers (``bench.py`` / ``benchmarks/components.py``): a
-  section nobody writes is stale exclusion config.
+  renamed metric evaluates forever against an absent series.
 
 Direction matters: each check points from the REFERENCE (call site,
-config, Makefile) at its TRUTH (POINTS, catalog, REGISTRY). The
-reverse direction — e.g. a POINTS entry no drill arms — is reported
-only for REGISTRY keys, where an unreferenced entry is definitionally
-dead; POINTS / catalog entries may be armed by tests or operators at
-runtime.
+config) at its TRUTH (POINTS, catalog). The reverse direction (a POINTS
+entry no drill arms) is not reported: POINTS and catalog entries may be
+armed by tests or operators at runtime.
 
-Findings in non-Python artifacts (JSON, Makefile) cannot carry inline
+Findings in non-Python artifacts (JSON) cannot carry inline
 suppressions; fix the drift or adjust the truth side instead.
 """
 
@@ -43,21 +31,14 @@ import ast
 import glob
 import json
 import os
-import re
 from typing import Dict, List, Sequence, Set
 
 from .engine import Finding, Rule, SourceFile, callee_chain, walk_package
 
 _FAULT_FNS = {"inject", "arm", "scoped"}
-_BENCH_INVOKE_RE = re.compile(
-    r"-m\s+parameter_server_tpu\.benchmarks\s+([A-Za-z_][A-Za-z0-9_]*)"
-)
 
 _FAULTS_MOD = "parameter_server_tpu/system/faults.py"
 _INSTRUMENTS_MOD = "parameter_server_tpu/telemetry/instruments.py"
-_COMPONENTS_MOD = "parameter_server_tpu/benchmarks/components.py"
-_BENCH_MOD = "bench.py"  # the record assembler lives at the repo root
-_BENCH_DIFF = "script/bench_diff.py"
 
 
 def _string_constants(tree: ast.AST) -> Set[str]:
@@ -82,14 +63,12 @@ class CrossArtifactRule(Rule):
     version = "1"
 
     def paths(self, root: str) -> Sequence[str]:
-        return tuple(walk_package(root)) + (_BENCH_MOD, _BENCH_DIFF)
+        return tuple(walk_package(root))
 
     def check(self, files: Dict[str, SourceFile], root: str) -> List[Finding]:
         findings: List[Finding] = []
         findings.extend(self._check_fault_points(files))
         findings.extend(self._check_alert_metrics(files, root))
-        findings.extend(self._check_bench_wiring(files, root))
-        findings.extend(self._check_metadata_sections(files))
         return findings
 
     # -- fault points --------------------------------------------------
@@ -206,119 +185,4 @@ class CrossArtifactRule(Rule):
                             "see a sample",
                         )
                     )
-        return findings
-
-    # -- benchmark wiring ----------------------------------------------
-
-    def _registry(self, files) -> Dict[str, int]:
-        """@benchmark("key") -> decorator line."""
-        sf = files.get(_COMPONENTS_MOD)
-        out: Dict[str, int] = {}
-        if sf is None:
-            return out
-        for node in ast.walk(sf.tree):
-            if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                continue
-            for dec in node.decorator_list:
-                if (
-                    isinstance(dec, ast.Call)
-                    and callee_chain(dec)[-1] == "benchmark"
-                    and dec.args
-                    and isinstance(dec.args[0], ast.Constant)
-                    and isinstance(dec.args[0].value, str)
-                ):
-                    out[dec.args[0].value] = dec.lineno
-        return out
-
-    def _check_bench_wiring(self, files, root: str) -> List[Finding]:
-        registry = self._registry(files)
-        if not registry:
-            return []
-        findings: List[Finding] = []
-        mk_path = os.path.join(root, "Makefile")
-        try:
-            with open(mk_path, "r", encoding="utf-8") as f:
-                mk_text = f.read()
-        except OSError:
-            mk_text = ""
-        for i, line in enumerate(mk_text.splitlines(), start=1):
-            for m in _BENCH_INVOKE_RE.finditer(line):
-                key = m.group(1)
-                if key not in registry:
-                    findings.append(
-                        Finding(
-                            "Makefile",
-                            i,
-                            "bench-wiring",
-                            f"Makefile invokes benchmark '{key}' which is "
-                            "not a registered @benchmark key in "
-                            f"{_COMPONENTS_MOD}",
-                        )
-                    )
-        # reverse direction: a REGISTRY key nothing references is dead
-        ref_texts = [mk_text]
-        for rel in ("tests/test_benchmarks.py",):
-            try:
-                with open(
-                    os.path.join(root, rel), "r", encoding="utf-8"
-                ) as f:
-                    ref_texts.append(f.read())
-            except OSError:
-                pass
-        for key, line in sorted(registry.items()):
-            if not any(f'"{key}"' in t or f"'{key}'" in t or
-                       re.search(rf"\b{re.escape(key)}\b", t)
-                       for t in ref_texts):
-                findings.append(
-                    Finding(
-                        _COMPONENTS_MOD,
-                        line,
-                        "bench-wiring",
-                        f"benchmark '{key}' is registered but referenced "
-                        "by no Makefile target or "
-                        "tests/test_benchmarks.py — unreachable "
-                        "registration",
-                    )
-                )
-        return findings
-
-    # -- metadata sections ---------------------------------------------
-
-    def _check_metadata_sections(self, files) -> List[Finding]:
-        diff_sf = files.get(_BENCH_DIFF)
-        if diff_sf is None:
-            return []
-        sections: Dict[str, int] = {}
-        for node in ast.walk(diff_sf.tree):
-            if isinstance(node, ast.Assign) and any(
-                isinstance(t, ast.Name) and t.id == "METADATA_SECTIONS"
-                for t in node.targets
-            ):
-                for c in ast.walk(node.value):
-                    if isinstance(c, ast.Constant) and isinstance(
-                        c.value, str
-                    ):
-                        sections[c.value] = c.lineno
-        if not sections:
-            return []
-        producers: Set[str] = set()
-        for rel in (_BENCH_MOD, _COMPONENTS_MOD):
-            sf = files.get(rel)
-            if sf is not None:
-                producers |= _string_constants(sf.tree)
-        if not producers:
-            return []
-        findings: List[Finding] = []
-        for name, line in sorted(sections.items()):
-            if name not in producers:
-                findings.append(
-                    Finding(
-                        _BENCH_DIFF,
-                        line,
-                        "metadata-section",
-                        f"METADATA_SECTIONS entry '{name}' is written by "
-                        f"no bench record producer ({_BENCH_MOD} / "
-                        f"{_COMPONENTS_MOD}) — stale exclusion config",
-                    )
-                )
         return findings

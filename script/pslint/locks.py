@@ -57,7 +57,7 @@ from .engine import (
 )
 
 # the concurrency surface: every module with threads or locks on the
-# training/system path (doc/STATIC_ANALYSIS.md "Scope")
+# training/system path (doc/STATIC_ANALYSIS.md "Rule catalog")
 SCOPE = (
     "parameter_server_tpu/system/executor.py",
     "parameter_server_tpu/system/postoffice.py",

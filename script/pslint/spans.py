@@ -55,9 +55,7 @@ class SpanDisciplineRule(Rule):
     def paths(self, root: str) -> Sequence[str]:
         if self.scope is not None:
             return self.scope
-        # bench.py lives at the repo root but is a first-class span
-        # call site (the attribution section's stage spans)
-        return list(walk_package(root)) + ["bench.py"]
+        return list(walk_package(root))
 
     def check(self, files: Dict[str, SourceFile], root: str) -> List[Finding]:
         findings: List[Finding] = []

@@ -43,3 +43,22 @@ def require_native(symbol: str = None):
     if symbol is not None and getattr(lib, symbol, None) is None:
         pytest.fail(f"libpsnative has no symbol {symbol}")
     return lib
+
+
+def repo_texts(tops, suffixes):
+    """``(path relative to the repo, text)`` of every file under the
+    repo's ``tops`` (files or directories) whose name ends with one of
+    ``suffixes``: what the tests that police names across files read."""
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for top in tops:
+        path = os.path.join(repo, top)
+        walk = os.walk(path) if os.path.isdir(path) else [(repo, [], [top])]
+        for root, dirs, names in walk:
+            dirs[:] = [d for d in dirs if d != "__pycache__"]
+            for name in sorted(names):
+                if name.endswith(suffixes):
+                    full = os.path.join(root, name)
+                    with open(full, encoding="utf-8", errors="replace") as f:
+                        yield os.path.relpath(full, repo), f.read()

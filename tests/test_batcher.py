@@ -92,6 +92,22 @@ def _drain(b, handles_done, max_rounds=500):
     raise AssertionError("batch failed to drain")
 
 
+def _drain_waves(b, reqs, max_rounds=500):
+    """The throughput path under join/leave churn: sessions join in
+    waves as slots free (``admit_many``), rounds fuse per dispatch
+    (``step_block``). Returns the retired handles."""
+    done, pending = [], list(reqs)
+    for _ in range(max_rounds):
+        wave = []
+        while pending and len(wave) < b.free_slots():
+            wave.append((pending.pop(0), None))
+        assert len(b.admit_many(wave)) == len(wave)
+        done.extend(b.step_block())
+        if not pending and b.active_sessions() == 0:
+            return done
+    raise AssertionError("batch failed to drain")
+
+
 class TestTokenParity:
     def test_identity_under_join_leave_churn(self, models):
         """Six sessions with DIFFERENT lengths and budgets through a
@@ -134,17 +150,36 @@ class TestTokenParity:
         ]
         b = _batcher(models)
         b.warmup()
-        done, pending = [], list(reqs)
-        for _ in range(500):
-            wave = []
-            while pending and len(wave) < b.free_slots():
-                wave.append((pending.pop(0), None))
-            handles = b.admit_many(wave)
-            assert len(handles) == len(wave)
-            done.extend(b.step_block())
-            if not pending and b.active_sessions() == 0:
-                break
+        done = _drain_waves(b, reqs)
         assert len(done) == len(reqs)
+        for h in done:
+            np.testing.assert_array_equal(
+                h.out, _sequential(models, h.req)
+            )
+
+    @pytest.mark.parametrize("slots", [1, 4, 8, 16])
+    def test_no_compile_after_warmup_at_each_slot_count(self, models, slots):
+        """``warmup()`` claims to be every compilation traffic can
+        trigger (the round, the block, one join per power-of-two wave):
+        under join/leave churn through the wave path, at each slot
+        count, the three jitted entry points gain no cache entry, and
+        every session still equals its solo run."""
+        from parameter_server_tpu.models import speculative
+
+        jits = (speculative._spec_round_jit,
+                speculative._spec_round_block_jit,
+                speculative._spec_join_many_jit)
+        b = _batcher(models, slots=slots)
+        b.warmup()
+        compiled = [f._cache_size() for f in jits]
+        reqs = [
+            DecodeRequest(prompt=_prompt(70 + i, 1, 3 + (i % 4)),
+                          steps=5 + 2 * (i % 3))
+            for i in range(2 * slots + 1)
+        ]
+        done = _drain_waves(b, reqs)
+        assert len(done) == len(reqs)
+        assert [f._cache_size() for f in jits] == compiled
         for h in done:
             np.testing.assert_array_equal(
                 h.out, _sequential(models, h.req)

@@ -320,7 +320,9 @@ class TestKKTFilter:
             summary = worker._consistency.tracker.summary()
         finally:
             worker.executor.stop()
-        # the in-jit identity, metered host-side...
+        # the shipped margin suppresses some keys and ships the rest...
+        assert 0 < summary["suppressed"] < summary["candidates"]
+        # ...the in-jit identity, metered host-side...
         assert summary["reconciled"]
         assert summary["pushed"] + summary["suppressed"] == (
             summary["candidates"]

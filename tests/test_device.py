@@ -1,6 +1,5 @@
-"""Device truth plane (telemetry/device.py + the merged device
-timeline): the contracts doc/OBSERVABILITY.md "Device truth plane"
-sells.
+"""Device truth plane (telemetry/device.py): the contracts
+doc/OBSERVABILITY.md "Device truth plane" sells.
 
 - the compiled-function inventory is a DROP-IN wrapper: identical
   outputs, donation semantics preserved, tracer-stage calls pass
@@ -21,10 +20,11 @@ sells.
 - the recompile-storm alert rule (configs/alerts/default.json) walks
   inactive→pending→firing on a shape-churning jit and resolves when
   shapes steady;
-- synthetic device tracks merge into the host timeline (flows
-  inherited from the submitting executor.step), attribute correctly
-  (kernel-dominated vs gap-dominated), and records without a device
-  trace are byte-for-byte unchanged.
+- the peaks table: a listed chip has both peaks, an unlisted one is
+  an error, a CPU resolves to None, and the benchmark's own copy
+  (``chipbench/peaks.json``) agrees;
+- one live /metrics scrape shows the ``ps_device_*`` families
+  node-labeled with the recompile-storm rule evaluating.
 """
 
 import json
@@ -282,13 +282,13 @@ class TestHbmMonitor:
         assert export["ps_device_live_buffer_bytes"]["series"]
         assert export["ps_device_live_buffer_high_water_bytes"]["series"]
 
-    def test_bench_snapshot_shape(self, fresh_plane):
+    def test_snapshot_shape(self, fresh_plane):
         device_mod.install_hbm_monitor()
         snap = device_mod.snapshot()
         assert "functions" in snap
         assert "hbm" in snap and "live_buffer_bytes" in snap["hbm"]
         assert snap["backend"] == "cpu"
-        # the no-faked-peak rule rides into the record
+        # the no-faked-peak rule rides into the snapshot
         assert snap["hbm_peak_gb_s"] is None
         assert snap["flops_peak_tflops"] is None
 
@@ -383,159 +383,52 @@ class TestRecompileStormAlert:
         assert rule.metric == "ps_device_hbm_frac_used"
 
 
-# -- merged device timeline + attribution ---------------------------------
+# -- the peaks table ---------------------------------------------------------
 
 
-def _host_step(flow, t0, total, run_s, name="executor.step"):
-    """An executor.step event as the executor emits it (t_wall stamped
-    at FINISH, total_s spanning submit→finish)."""
-    return {
-        "kind": "span", "name": name, "t_wall": t0 + total,
-        "total_s": total, "queue_wait_s": total - run_s, "run_s": run_s,
-        "materialize_s": 0.0, "flow": flow, "thread": "executor",
-    }
+class TestPeaksTable:
+    """The package's one peaks table (telemetry/device.py), which the
+    roofline gauges and ``chip_smoke.py``'s device line read."""
 
-
-def _dev_span(name, t0, dur, thread="device:1"):
-    return {
-        "kind": "span", "name": f"device.{name}", "thread": thread,
-        "t_wall": t0, "dur_s": dur,
-    }
-
-
-class TestDeviceTimelineMerge:
-    def test_device_track_events_parse_and_anchor(self, tmp_path):
-        run = tmp_path / "plugins" / "profile" / "run1"
-        run.mkdir(parents=True)
-        trace = {
-            "traceEvents": [
-                {"ph": "M", "pid": 7, "tid": 0, "name": "process_name",
-                 "args": {"name": "/device:TPU:0"}},
-                {"ph": "M", "pid": 7, "tid": 2, "name": "thread_name",
-                 "args": {"name": "XLA Ops"}},
-                {"ph": "M", "pid": 7, "tid": 3, "name": "thread_name",
-                 "args": {"name": "XLA Modules"}},
-                {"ph": "M", "pid": 1, "tid": 0, "name": "process_name",
-                 "args": {"name": "host threads"}},
-                # op track events (kept), module aggregate (dropped),
-                # host event (dropped)
-                {"ph": "X", "pid": 7, "tid": 2, "name": "fusion.3",
-                 "ts": 1000.0, "dur": 500.0},
-                {"ph": "X", "pid": 7, "tid": 2, "name": "copy.1",
-                 "ts": 1600.0, "dur": 100.0},
-                {"ph": "X", "pid": 7, "tid": 3, "name": "jit_step",
-                 "ts": 1000.0, "dur": 700.0},
-                {"ph": "X", "pid": 1, "tid": 5, "name": "hostwork",
-                 "ts": 0.0, "dur": 99.0},
-            ]
+    @pytest.mark.parametrize("kind,hbm,flops", [
+        ("TPU v5 lite", 819.0, 197.0),  # what a v5e reports
+        ("TPU v5e", 819.0, 197.0),
+        ("TPU v4", 1228.0, 275.0),
+    ])
+    def test_a_listed_chip_has_both_peaks(self, kind, hbm, flops):
+        assert device_mod.device_peaks(kind) == {
+            "hbm_gb_s": hbm, "bf16_tflops": flops,
         }
-        (run / "host.trace.json").write_text(json.dumps(trace))
-        from parameter_server_tpu.utils.profiling import device_track_events
+        assert device_mod._peaks(kind) == (hbm, flops)
 
-        evs = device_track_events(str(tmp_path), host_anchor=100.0)
-        assert [e["name"] for e in evs] == ["device.fusion.3", "device.copy.1"]
-        assert all(e["thread"] == "device:7" for e in evs)
-        # anchored: first op starts at the host window start; the
-        # 600us relative offset and durations survive exactly
-        assert evs[0]["t_wall"] == pytest.approx(100.0)
-        assert evs[1]["t_wall"] == pytest.approx(100.0006)
-        assert evs[0]["dur_s"] == pytest.approx(500e-6)
+    def test_an_unlisted_chip_is_an_error_and_a_cpu_has_no_peak(self):
+        with pytest.raises(KeyError, match="peaks table"):
+            device_mod.device_peaks("TPU v9 ultra")
+        ident = device_mod.device_identity()
+        assert ident["platform"] == "cpu" and ident["count"] == 8
+        # no faked peak: the gauges' lookup resolves a CPU to None
+        assert device_mod._peaks(ident["kind"]) == (None, None)
 
-    def test_merge_attaches_submitting_step_flow(self):
-        from parameter_server_tpu.telemetry.timeline import merge_device_track
-
-        host = [_host_step(flow=7, t0=100.0, total=1.0, run_s=0.8)]
-        dev_in = _dev_span("fusion.3", 100.5, 0.2)
-        dev_out = _dev_span("fusion.9", 200.0, 0.1)
-        merged = merge_device_track(host, [dev_in, dev_out])
-        by_name = {e["name"]: e for e in merged}
-        assert by_name["device.fusion.3"]["flow"] == 7
-        assert "flow" not in by_name["device.fusion.9"]
-        # inputs were not mutated
-        assert "flow" not in dev_in
-
-    def test_chrome_export_renders_device_track_with_arrows(self, tmp_path):
-        from parameter_server_tpu.telemetry import timeline as tl
-
-        events = [
-            _host_step(flow=7, t0=100.0, total=1.0, run_s=0.8),
-            _dev_span("fusion.3", 100.5, 0.2),
-        ]
-        jsonl = tmp_path / "t.jsonl"
-        with open(jsonl, "w") as f:
-            for ev in events:
-                f.write(json.dumps(ev) + "\n")
-        out = tmp_path / "t.json"
-        trace = tl.export_chrome_trace(str(jsonl), str(out))
-        evs = trace["traceEvents"]
-        threads = {
-            (e.get("args") or {}).get("name")
-            for e in evs if e.get("name") == "thread_name"
-        }
-        assert "device:1" in threads
-        arrows = [e for e in evs if e.get("ph") in ("s", "f")]
-        assert any(a.get("id") == 7 for a in arrows)
-        assert os.path.exists(out)
-
-
-class TestDeviceAttribution:
-    def _summarize(self, events):
-        from parameter_server_tpu.telemetry.attribution import summarize
-
-        return summarize(events)
-
-    def test_kernel_dominated_track(self):
-        host = [_host_step(flow=1, t0=0.0, total=1.0, run_s=0.9)]
-        dev = [
-            _dev_span("matmul.1", 0.10, 0.50),
-            _dev_span("matmul.1", 0.62, 0.30),
-            _dev_span("copy.2", 0.93, 0.05),
-        ]
-        out = self._summarize(host + dev)
-        db = out["device_compute_breakdown"]
-        assert db["busy_frac"] > 0.9
-        assert db["gap_s"] < 0.1
-        kernels = {k["name"]: k for k in db["kernels"]}
-        assert kernels["matmul.1"]["share"] > 0.9
-        assert kernels["matmul.1"]["calls"] == 2
-
-    def test_gap_dominated_track(self):
-        host = [_host_step(flow=1, t0=0.0, total=1.0, run_s=0.9)]
-        dev = [
-            _dev_span("matmul.1", 0.0, 0.02),
-            _dev_span("matmul.1", 0.98, 0.02),
-        ]
-        db = self._summarize(host + dev)["device_compute_breakdown"]
-        assert db["busy_frac"] < 0.1
-        assert db["gap_s"] > 0.9
-        # the resource view is untouched: device events are not
-        # double-billed into device_compute busy time
-        assert self._summarize(host + dev)["busy_s"].get(
-            "device_compute", 0.0
-        ) == pytest.approx(0.9)
-
-    def test_nested_device_spans_credit_self_time(self):
-        dev = [
-            _dev_span("while.body", 0.0, 1.0),
-            _dev_span("mul.1", 0.1, 0.8),
-        ]
-        from parameter_server_tpu.telemetry.attribution import (
-            device_breakdown,
+    def test_the_benchmarks_own_copy_agrees(self):
+        """``chipbench/peaks.json`` is the benchmark's copy (it imports
+        nothing of the program): a chip in both has the same peaks."""
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "chipbench", "peaks.json",
         )
+        with open(path) as f:
+            copy = json.load(f)["by_device_kind"]
+        assert copy
+        for kind, peaks in copy.items():
+            mine = device_mod.device_peaks(kind)
+            assert peaks["hbm_bytes_per_s"] == mine["hbm_gb_s"] * 1e9
+            assert peaks["bf16_flops_per_s"] == mine["bf16_tflops"] * 1e12
 
-        db = device_breakdown(dev)
-        kernels = {k["name"]: k for k in db["kernels"]}
-        assert kernels["mul.1"]["ms"] == pytest.approx(800.0)
-        # the wrapper is credited only what its body leaves
-        assert kernels["while.body"]["ms"] == pytest.approx(200.0)
-        # and union coverage counts the interval once
-        assert db["gap_s"] == pytest.approx(0.0)
 
-    def test_no_device_trace_record_unchanged(self):
-        host = [_host_step(flow=1, t0=0.0, total=1.0, run_s=0.9)]
-        out = self._summarize(host)
-        assert "device_compute_breakdown" not in out
+# -- one live scrape ---------------------------------------------------------
 
+
+class TestClusterScrape:
     def test_scrape_shows_device_families_node_labeled_and_storm_rule(
         self, fresh_plane, mesh8
     ):
@@ -588,17 +481,3 @@ class TestDeviceAttribution:
         finally:
             close_cluster(srv)
             Postoffice.reset()
-
-    def test_flash_crosscheck_reconciles_hand_model(self):
-        """The flash half of the record's roofline cross-check: XLA's
-        counted FLOPs must be within 2x of the hand 4·bh·s²·d
-        convention (it was 0.96x on this container) — and a CPU host
-        must report no MFU rather than a faked one."""
-        from parameter_server_tpu.benchmarks.components import (
-            flash_cost_crosscheck,
-        )
-
-        out = flash_cost_crosscheck(smoke=True)
-        assert out["hand_flops"] > 0
-        assert 0.5 < out["hand_over_xla_ratio"] < 2.0
-        assert out["mfu_hand"] is None

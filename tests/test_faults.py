@@ -761,26 +761,467 @@ class TestDegradedServing:
 
 
 # ---------------------------------------------------------------------------
-# the drill itself (smoke shape; the full run is `make chaos-bench`)
+# the drill itself
+
+
+def _recovery_drill() -> dict:
+    """Kill-one-shard recovery drill under concurrent train + serve load
+    (doc/ROBUSTNESS.md "The drill").
+
+    The script, all under live load (a paced training push stream and a
+    closed-loop serving client against the SAME store):
+
+    1. **healthy**: periodic consistent replica backups
+       (``ReplicaManager.start_periodic`` -> snapshot steps THROUGH the
+       store executor, so donated pushes can't tear them) while the
+       trainer acks pushes and serving reads live.
+    2. **kill**: the backup stream stops, then ``S0`` dies the way real
+       shards die: its heartbeats stop arriving (injected
+       ``heartbeat.report`` silence), its table is wiped (the
+       replacement starts empty), and the serving store path starts
+       failing (``serve.pull`` / ``serve.refresh`` faults). Serving
+       DEGRADES to the stale read replica (503-distinct accounting)
+       instead of erroring; training keeps acking into the void:
+       exactly the updates the replay contract must not lose.
+    3. **detect + recover**: the RecoveryCoordinator's poll declares
+       S0 dead after the heartbeat timeout; the server-death handler
+       parks the trainer (bounded-delay semantics: survivors stop
+       pushing while the shard recovers), installs the last consistent
+       snapshot through the executor, REPLAYS every acked push past the
+       snapshot's barrier timestamp in original order, then re-arms the
+       store path and resumes.
+    4. **verify**: after the stream completes, the drilled table must
+       be BIT-identical to an undisturbed run of the same batch
+       sequence: zero lost *acknowledged* updates, to the bit.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from parameter_server_tpu.parallel import mesh as meshlib
+    from parameter_server_tpu.parameter.kv_vector import KVVector
+    from parameter_server_tpu.parameter.replica import ReplicaManager
+    from parameter_server_tpu.serving import (
+        PullRequest,
+        RejectedError,
+        ServeConfig,
+        ServeFrontend,
+    )
+    from parameter_server_tpu.system.heartbeat import (
+        HeartbeatCollector,
+        HeartbeatReport,
+    )
+    from parameter_server_tpu.system.postoffice import Postoffice
+    from parameter_server_tpu.system.recovery import RecoveryCoordinator
+
+    Postoffice.reset()
+    mesh = Postoffice.instance().start().mesh
+    seed = 7
+    k = 4
+    num_slots = 1 << 10
+    key_space = 1 << 16
+    n_per_batch = 64
+    # the stream must OUTLIVE detection in every mode: the drill's
+    # whole point is recovery under live load, so the trainer has to
+    # still be pushing when the handler parks it. Post-kill batches x
+    # (>=4ms pacing) must exceed hb_timeout + poll + margin: with
+    # 100 post-kill batches at >=4ms the park is guaranteed (the
+    # test's trainer_parked assertion pins it).
+    n_batches = 120
+    kill_at = n_batches // 6
+    hb_timeout = 0.3
+
+    def batch(i: int):
+        # regenerable by index, which is what lets the recovery handler
+        # REPLAY acked-but-unbacked updates instead of journaling arrays
+        rng = np.random.default_rng((seed << 20) + i)
+        keys = rng.integers(0, key_space, n_per_batch).astype(np.int64)
+        vals = rng.normal(size=(n_per_batch, k)).astype(np.float32)
+        return keys, vals
+
+    def push_and_ack(kv, i: int) -> int:
+        keys, vals = batch(i)
+        ts = kv.push(kv.request(channel=0), keys=keys, values=vals)
+        kv.executor.wait(ts, timeout=60)
+        return ts
+
+    # -- the undisturbed reference trajectory (also warms every jit:
+    # push scatter-add, gather, snapshot copy — so compile stalls can't
+    # eat the drill's heartbeat margin) --
+    kv_ref = KVVector(
+        mesh=mesh, k=k, num_slots=num_slots, hashed=True, name="drill_ref"
+    )
+    for i in range(n_batches):
+        push_and_ack(kv_ref, i)
+    t_ref = np.array(kv_ref.table(0, copy=True))
+    kv_ref.executor.stop()
+
+    # -- the drilled store + chaos-plane wiring --
+    faults.reset()
+    # flight recorder (telemetry/blackbox.py): armed for the whole
+    # drill so the shard death auto-captures a diagnostic bundle with
+    # the pre-death evidence still in the rings. Per-node recorders for
+    # the drill's logical nodes; min capture interval dropped so the
+    # death trigger is never rate-limit-suppressed by an earlier
+    # capture. Cleanup is TARGETED, not a global reset: the drill
+    # restores exactly the state it touched (its recorders, the
+    # interval, its tee), so bundles another test of this process
+    # captured survive the drill.
+    from parameter_server_tpu.telemetry import alerts as alerts_mod
+    from parameter_server_tpu.telemetry import blackbox
+    from parameter_server_tpu.telemetry import (
+        registry as telemetry_registry,
+    )
+
+    prev_min_interval = blackbox.set_min_interval(0.0)
+    was_armed = blackbox.installed_recorder() is not None
+    blackbox.arm()
+    blackbox.recorder("W0").clear()  # a prior drill in this process
+    blackbox.recorder("S0").clear()  # must not leak into this bundle
+    node_alerts = None
+    if telemetry_registry.enabled():
+        node_alerts = alerts_mod.AlertManager(
+            [r for r in alerts_mod.default_rules()
+             if r.name == "node_deaths"]
+        )
+        node_alerts.evaluate()  # baseline sample: rate needs a window
+    # independently-metered update accounting (the learning truth
+    # plane's progress side): baseline the parameter plane's push-key
+    # counter for the drilled store BEFORE it exists, so the post-drill
+    # delta is exactly this drill's pushed keys
+    push_tel = None
+    push_keys0 = 0.0
+    if telemetry_registry.enabled():
+        from parameter_server_tpu.telemetry.instruments import (
+            parameter_instruments,
+        )
+
+        push_tel = parameter_instruments(
+            telemetry_registry.default_registry()
+        )["push_keys"]
+        push_keys0 = push_tel.value(store="drill_live", channel=0)
+    kv = KVVector(
+        mesh=mesh, k=k, num_slots=num_slots, hashed=True, name="drill_live"
+    )
+    rm = ReplicaManager()
+    rm.backup_consistent(kv)  # a snapshot exists before any fault can
+    rm.start_periodic(kv, interval_s=0.04)
+
+    collector = HeartbeatCollector(timeout=hb_timeout)
+    rc = RecoveryCoordinator(collector, handler_retry=None)  # replay is
+    # not idempotent: a partial replay retried would double-apply, so
+    # the drill's handler runs exactly once and fails loudly instead
+
+    fe = ServeFrontend(
+        kv,
+        ServeConfig(
+            replica="fallback",  # live-first reads; replica = degraded path
+            replica_refresh_s=0.15,
+            live_pull_deadline_s=2.0,
+            degraded_max_staleness_s=60.0,
+            workers=2,
+            max_queue_depth=256,
+        ),
+    ).start()
+    rng = np.random.default_rng(seed + 1)
+    u = rng.random((128, 16))
+    pool = (u * u * u * key_space).astype(np.int64)  # hot-headed draws
+    fe.submit(PullRequest(keys=pool[0])).result(30)  # warm the pull lane
+
+    counts = {"ok": 0, "shed": 0, "failed": 0}  # serve-thread-only writes
+    stop_serve = threading.Event()
+
+    def serve_loop() -> None:
+        i = 0
+        while not stop_serve.is_set():
+            try:
+                fe.submit(PullRequest(keys=pool[i % len(pool)])).result(10)
+                counts["ok"] += 1
+            except RejectedError:
+                counts["shed"] += 1
+            except Exception:  # DegradedError and organic failures both
+                counts["failed"] += 1  # count here; degraded SUCCESSES
+                # are counted by the frontend (degraded_served)
+            i += 1
+            time.sleep(0.002)
+
+    acked: list = []  # (push ts, batch index); guarded-by: ack_lock
+    ack_lock = threading.Lock()
+    pause_req = threading.Event()
+    parked = threading.Event()
+    train_err: list = []
+
+    def trainer() -> None:
+        try:
+            for i in range(n_batches):
+                if pause_req.is_set():
+                    parked.set()
+                    while pause_req.is_set():
+                        time.sleep(0.002)
+                    parked.clear()
+                ts = push_and_ack(kv, i)
+                with ack_lock:
+                    acked.append((ts, i))
+                time.sleep(0.004)  # paced: a continuous live stream,
+                # not a burst that outruns the detection window
+        except BaseException as e:  # surfaced after join
+            train_err.append(e)
+
+    stop_beat = threading.Event()
+
+    def beater() -> None:
+        beats = 0
+        while not stop_beat.wait(0.04):
+            collector.report("S0", HeartbeatReport(hostname="S0"))
+            collector.report("W0", HeartbeatReport(hostname="W0"))
+            beats += 1
+            if beats % 3 == 0:
+                # periodic metrics-delta samples into the survivors'
+                # flight-recorder rings (the report-timer cadence —
+                # what a bundle's per-node metrics history is made of)
+                for nid in ("W0", "S0"):
+                    rec = blackbox.recorder(nid, create=False)
+                    if rec is not None:
+                        rec.sample_metrics()
+
+    killed = threading.Event()
+    recovered = threading.Event()
+    replayed = [0]
+    barrier_used = [-1]
+    trainer_parked = [False]
+
+    trainer_t = threading.Thread(target=trainer, name="drill-trainer")
+
+    def on_server_dead(nid: str) -> None:
+        if not killed.is_set():
+            # a loaded host can stall the beater past the heartbeat
+            # timeout BEFORE the drill killed anything — that is a
+            # false positive, and consuming the exactly-once handler
+            # on it would mask the real kill. Revive and keep watching.
+            rc.revive(nid)
+            return
+        # bounded-delay semantics: survivors stop pushing while the
+        # shard recovers (park the trainer between batches)
+        pause_req.set()
+        while not parked.is_set() and trainer_t.is_alive():
+            time.sleep(0.002)
+        # the under-live-load property CI pins: the trainer was ALIVE
+        # and parked (not already finished) when recovery began
+        trainer_parked[0] = parked.is_set()
+        rec_ok = rm.recover(kv, through_executor=True)
+        assert rec_ok, "no replica snapshot to recover from"
+        barrier = rm.barrier(kv.name).get(0, -1)
+        barrier_used[0] = barrier
+        with ack_lock:
+            replay = [(ts, i) for ts, i in acked if ts > barrier]
+        for _, i in replay:  # original order — FP addition must re-run
+            push_and_ack(kv, i)  # in the exact sequence it first ran
+        replayed[0] = len(replay)
+        # the replacement shard is up: store path + heartbeats return
+        faults.disarm("serve.pull")
+        faults.disarm("serve.refresh")
+        faults.disarm("heartbeat.report")
+        recovered.set()
+        pause_req.clear()
+
+    rc.on_server_dead(on_server_dead)
+    collector.report("S0", HeartbeatReport(hostname="S0"))
+    collector.report("W0", HeartbeatReport(hostname="W0"))
+
+    serve_t = threading.Thread(target=serve_loop, name="drill-serve")
+    beat_t = threading.Thread(target=beater, name="drill-beater")
+    degraded_probes = 0
+    try:
+        beat_t.start()
+        rc.start(interval=0.03)
+        trainer_t.start()
+        serve_t.start()
+
+        # phase 1 (healthy): run until the kill point has been ACKED
+        while True:
+            with ack_lock:
+                n_acked = len(acked)
+            if n_acked >= kill_at or train_err:
+                break
+            time.sleep(0.005)
+        if train_err:
+            raise train_err[0]
+
+        # phase 2 (kill): the dead shard's backup stream stops FIRST —
+        # a crashed node cannot keep snapshotting — then make sure at
+        # least one acked update postdates the final barrier (the
+        # replay set must be provably non-empty)
+        rm.stop_periodic()
+        barrier_before = rm.barrier(kv.name).get(0, -1)
+        replay_deadline = time.perf_counter() + 30
+        while True:
+            with ack_lock:
+                if any(ts > barrier_before for ts, _ in acked):
+                    break
+            assert trainer_t.is_alive() and (
+                time.perf_counter() < replay_deadline
+            ), "no acked update ever postdated the final backup barrier"
+            time.sleep(0.002)
+        faults.arm("heartbeat.report", kind="silence", match="S0")
+        faults.arm("serve.pull", kind="raise")
+        faults.arm("serve.refresh", kind="raise")
+        killed.set()
+        # wipe the shard through the executor (the replacement starts
+        # empty; the submitted step serializes with in-flight pushes)
+        zeros = jax.device_put(
+            jnp.zeros((kv.num_slots, kv.k), kv.dtype),
+            meshlib.table_sharding(kv.mesh),
+        )
+        kv.executor.wait(
+            kv.submit(lambda: kv.set_table(0, zeros), kv.request(channel=0)),
+            timeout=60,
+        )
+        # deterministic degraded evidence: requests in the dead window
+        # must be ANSWERED (stale) — the 503-vs-429 story, measured
+        for j in range(3):
+            try:
+                fe.submit(PullRequest(keys=pool[j])).result(10)
+                degraded_probes += 1
+            except Exception:
+                pass
+
+        # phase 3: detection + recovery run on the coordinator thread;
+        # phase 4: the trainer finishes the stream
+        deadline = time.perf_counter() + 90
+        while not recovered.is_set() and time.perf_counter() < deadline:
+            if node_alerts is not None:
+                node_alerts.evaluate()
+            time.sleep(0.005)
+        assert recovered.is_set(), "recovery never completed"
+        # the node_deaths rule sees the coordinator's deaths counter
+        # tick and walks pending->firing (for_s=0: one evaluation)
+        if node_alerts is not None:
+            alert_deadline = time.perf_counter() + 10
+            while (
+                "node_deaths" not in node_alerts.firing()
+                and time.perf_counter() < alert_deadline
+            ):
+                node_alerts.evaluate()
+                time.sleep(0.01)
+        trainer_t.join(timeout=120)
+        assert not trainer_t.is_alive(), "trainer wedged"
+        if train_err:
+            raise train_err[0]
+    finally:
+        try:
+            faults.reset()
+            rm.stop_periodic()
+            stop_serve.set()
+            stop_beat.set()
+            rc.stop()
+            for t in (serve_t, beat_t, trainer_t):
+                if t.ident is not None:
+                    t.join(timeout=60)
+            fe.close()
+        finally:
+            # grab the death's bundle BY TRIGGER KIND — last_bundle()
+            # could be a later capture (a straggling DegradedError from
+            # the dead window fires the degraded trigger with the
+            # interval still 0) whose rings carry no staleness override
+            # for S0
+            death_bundle = next(
+                (b for b in reversed(blackbox.bundles())
+                 if b["trigger"]["kind"] == "node_death"),
+                None,
+            )
+            # targeted cleanup (never a global reset — see the arm
+            # comment): the rate-limit override, the drill's per-node
+            # recorders, and the drill's tee (only if the drill armed
+            # it) must not leak past the drill even when it raises —
+            # its OWN nested finally, so a failing teardown step above
+            # (a wedged join, a close error) cannot skip it
+            blackbox.set_min_interval(prev_min_interval)
+            blackbox.drop_recorder("W0")
+            blackbox.drop_recorder("S0")
+            if not was_armed:
+                blackbox.disarm()
+
+    kv.executor.wait_all(pop=False, timeout=60)
+    t_drill = np.array(kv.table(0, copy=True))
+    fe_stats = fe.stats()
+    kv.executor.stop()
+    # the shard death's auto-captured diagnostic bundle (the
+    # RecoveryCoordinator's node_death trigger), summarized
+    blackbox_section: dict = {"captured": death_bundle is not None}
+    if death_bundle is not None:
+        blackbox_section = blackbox.summarize_bundle(death_bundle)
+    if node_alerts is not None:
+        st = node_alerts.states().get("node_deaths")
+        blackbox_section["node_deaths_alert"] = (
+            st.state_name if st is not None else "absent"
+        )
+    bit_identical = (
+        t_ref.dtype == t_drill.dtype
+        and t_ref.shape == t_drill.shape
+        and t_ref.tobytes() == t_drill.tobytes()
+    )
+    # the bit-identity claim, independently METERED (PR 15): every key
+    # the trainer acked plus every key the handler replayed must show
+    # in the parameter plane's own push-key counter for this store —
+    # a replay that silently lost (or double-ran) updates would still
+    # reconcile bit-identically on idempotent data, but it cannot fool
+    # a counter the push path ticks per request
+    update_accounting = None
+    if push_tel is not None:
+        pushed = int(
+            push_tel.value(store="drill_live", channel=0) - push_keys0
+        )
+        expected = (n_batches + replayed[0]) * n_per_batch
+        update_accounting = {
+            "pushed_keys_metered": pushed,
+            "expected_keys": expected,
+            "acked_updates": n_batches,
+            "replayed_updates": replayed[0],
+            "keys_per_batch": n_per_batch,
+            "metered_matches": pushed == expected,
+        }
+        assert update_accounting["metered_matches"], update_accounting
+
+    return {
+        "replayed_updates": replayed[0],
+        "acked_updates": n_batches,
+        "barrier_ts": barrier_used[0],
+        "backup_version_used": (rm.meta(kv.name) or {}).get("version"),
+        "trainer_parked": trainer_parked[0],
+        "trajectory_bit_identical": bool(bit_identical),
+        "update_accounting": update_accounting,
+        "blackbox": blackbox_section,
+        "serve": {
+            "requests": counts["ok"] + counts["shed"] + counts["failed"],
+            "completed_ok": counts["ok"],
+            "degraded_served": fe_stats["degraded_served"],
+            "degraded_probes_in_dead_window": degraded_probes,
+            "shed": counts["shed"],
+            "failed": counts["failed"],
+        },
+    }
 
 
 def test_recovery_drill_smoke():
     """Tier-1 acceptance: injected shard death under live train+serve
     load is detected and recovered with ZERO lost acknowledged updates
     — post-drill trajectory bit-identical to the undisturbed run."""
-    from parameter_server_tpu.benchmarks.components import recovery_drill
     from parameter_server_tpu.system.postoffice import Postoffice
 
     try:
-        out = recovery_drill(smoke=True)
+        out = _recovery_drill()
     finally:
         Postoffice.reset()
     assert out["trajectory_bit_identical"] is True
     assert out["trainer_parked"] is True  # recovery ran AGAINST live
     # load (the trainer was parked mid-stream, not already finished)
     assert out["replayed_updates"] >= 1
-    assert out["detection_ms"] > 0 and out["mttr_ms"] >= out["detection_ms"]
     assert out["serve"]["degraded_served"] >= 1
     assert out["serve"]["requests"] > 0
     assert out["backup_version_used"] >= 1
-    assert out["disarmed_overhead"]["ratio_median"] > 0
+    # the shard death's auto-captured bundle: the dead shard stale, the
+    # survivor's ring dumped, the shipped node_deaths rule firing
+    bb = out["blackbox"]
+    assert bb.get("captured")
+    assert bb["nodes"].get("S0", {}).get("stale")
+    assert not bb["nodes"].get("W0", {}).get("stale", True)
+    assert bb.get("node_deaths_alert", "firing") == "firing"

@@ -56,7 +56,6 @@ from parameter_server_tpu.telemetry.exposition import (
 )
 from parameter_server_tpu.telemetry.history import (
     HistoryStore,
-    drift_check,
     monotonic_fractions,
     percentile_from_buckets,
     theil_sen,
@@ -96,7 +95,7 @@ def _store(reg, t, resolutions=((1.0, 600), (10.0, 720), (60.0, 720))):
 
 
 # ---------------------------------------------------------------------------
-# estimators: Theil-Sen, concordance, bucket percentiles, drift verdicts
+# estimators: Theil-Sen, concordance, bucket percentiles
 # ---------------------------------------------------------------------------
 
 
@@ -137,18 +136,6 @@ class TestEstimators:
         )
         # rank past every bucket clamps to the top bound, never raises
         assert percentile_from_buckets(bounds, [0, 0, 0], 0, 0.5) is None
-
-    def test_drift_check_verdicts(self):
-        ramp_down = [(float(i), 100.0 - 0.5 * i) for i in range(60)]
-        d = drift_check(ramp_down)
-        assert d["verdict"] == "drift-down" and d["drifting"]
-        assert d["ratio"] < 0.85
-        flat = [(float(i), 100.0) for i in range(60)]
-        d = drift_check(flat)
-        assert d["verdict"] == "ok" and not d["drifting"]
-        assert d["ratio"] == pytest.approx(1.0)
-        d = drift_check([(0.0, 1.0), (1.0, 1.0)])
-        assert d["verdict"] == "insufficient-data" and not d["drifting"]
 
 
 # ---------------------------------------------------------------------------
@@ -282,6 +269,38 @@ class TestHistoryStore:
         assert st.snapshot()["series_dropped"] == 2
         ex = reg.export_state()["ps_history_dropped_series_total"]
         assert [s["value"] for s in ex["series"]] == [2.0]
+
+    def test_the_shipped_catalog_folds_whole(self):
+        """One live series of every instrument the package ships
+        (``instruments.install_all``) fits the store's caps: a forced
+        fold tracks one series an instrument and drops none, and every
+        forced fold is counted."""
+        from parameter_server_tpu.telemetry.instruments import install_all
+
+        reg = MetricsRegistry()
+        catalog = install_all(reg)
+        for inst in catalog.values():
+            target = (
+                inst.labels(**{ln: "probe" for ln in inst.labelnames})
+                if inst.labelnames else inst
+            )
+            if inst.kind == "histogram":
+                target.observe(0.001)
+            elif inst.kind == "gauge":
+                target.set(1.0)
+            else:
+                target.inc()
+        st = HistoryStore(reg)
+        for _ in range(3):
+            assert st.fold(force=True)
+        snap = st.snapshot()
+        assert snap["folds"] == 3
+        assert snap["series_dropped"] == 0
+        live = {
+            name for name, decl in reg.export_state().items()
+            if decl["series"]
+        }
+        assert snap["series"] == len(live) >= len(catalog)
 
     def test_ring_laps_forget_beyond_span(self):
         reg = MetricsRegistry()

@@ -527,16 +527,3 @@ class TestDeviceUploader:
         with pytest.raises(RuntimeError, match="prep died"):
             next(it)
         up.close()
-
-
-class TestHostIngestBench:
-    def test_smoke_ab_runs_and_reports(self):
-        """The components A/B returns the record bench.py embeds; a
-        smoke run stays in tier-1 budget (seconds)."""
-        from parameter_server_tpu.benchmarks.components import host_ingest_ab
-
-        out = host_ingest_ab(smoke=True)
-        assert out["examples"] > 0
-        assert out["serial_examples_per_sec"] > 0
-        assert out["pipelined_examples_per_sec"] > 0
-        assert out["pipelined_speedup"] > 0

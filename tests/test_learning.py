@@ -53,6 +53,102 @@ def _batches(n, minibatch=64, key_space=1 << 14, lanes=6, seed0=0):
     return out
 
 
+def _divergence_drill(mesh) -> dict:
+    """Seeded divergence drill: an LR blow-up (square loss, alpha 1e10)
+    NaNs the trajectory within a few steps; the learning plane judges
+    the collected steps divergent (``ps_learning_divergence_total``),
+    the SHIPPED ``loss_divergence`` rule walks inactive -> pending ->
+    firing, and the firing transition captures a flight-recorder
+    diagnostic bundle through the alert trigger plane: the same
+    listener wiring ``AuxRuntime.set_alerts`` installs. Deterministic
+    under a fake clock."""
+    from parameter_server_tpu.apps.linear.async_sgd import AsyncSGDWorker
+    from parameter_server_tpu.apps.linear.config import (
+        Config,
+        LearningRateConfig,
+        LossConfig,
+        PenaltyConfig,
+        SGDConfig,
+    )
+    from parameter_server_tpu.telemetry import alerts as alerts_mod
+    from parameter_server_tpu.telemetry import blackbox
+    from parameter_server_tpu.utils.sparse import random_sparse
+
+    rule = next(
+        r for r in alerts_mod.default_rules() if r.name == "loss_divergence"
+    )
+    clock = [0.0]
+    mgr = alerts_mod.AlertManager([rule], clock=lambda: clock[0])
+    prev_interval = blackbox.set_min_interval(0.0)
+    was_armed = blackbox.installed_recorder() is not None
+    blackbox.arm()
+    bundles: list = []
+
+    def on_transition(ev) -> None:
+        # the AuxRuntime._maybe_bundle_on_alert wiring, drill-local:
+        # a firing alert captures the evidence while it is in the ring
+        if ev.to == "firing" and ev.rule == "loss_divergence":
+            b = blackbox.trigger_bundle("alert", detail=ev.rule)
+            if b is not None:
+                bundles.append(b)
+
+    mgr.add_listener(on_transition)
+    conf = Config()
+    conf.loss = LossConfig(type="square")
+    conf.penalty = PenaltyConfig(type="l2", lambda_=[0.0])
+    # the blow-up: plain SGD at a constant learning rate orders of
+    # magnitude past stability turns the square loss's w-proportional
+    # gradient into an exponential: float32 overflows to Inf/NaN
+    # within a handful of steps on any data (FTRL would self-damp via
+    # its adaptive per-coordinate rate, which is exactly why the drill
+    # picks the updater the reference's SGDEntry models)
+    conf.learning_rate = LearningRateConfig(
+        type="constant", alpha=1e10, beta=1.0
+    )
+    conf.async_sgd = SGDConfig(
+        algo="standard", minibatch=64, num_slots=1 << 9, max_delay=0,
+    )
+    worker = AsyncSGDWorker(conf, mesh=mesh, name="learning_diverge")
+    states = []
+    try:
+        mgr.evaluate()  # t=0 baseline sample: a rate needs a window
+        states.append(mgr.states()[rule.name].state_name)
+        for i in range(8):
+            b = random_sparse(64, 1 << 12, 6, seed=100 + i, binary=True)
+            b.y = np.where(np.arange(64) % 2 == 0, 1.0, -1.0).astype(
+                np.float32
+            )
+            ts = worker._submit_prepped(
+                worker.prep(b, device_put=False), with_aux=False
+            )
+            worker.collect(ts)
+        plane = learning_mod.get_plane("learning_diverge")
+        divergences = dict(plane.snapshot()["divergence"]) if plane else {}
+        clock[0] = 5.0
+        mgr.evaluate()  # pending -> firing in one tick (for_s=0)
+        states.append(mgr.states()[rule.name].state_name)
+        fired = rule.name in mgr.firing()
+        # traffic stops; the window slides past the burst -> resolved
+        clock[0] = 5.0 + rule.window_s + 10.0
+        mgr.evaluate()
+        states.append(mgr.states()[rule.name].state_name)
+    finally:
+        worker.executor.stop()
+        blackbox.set_min_interval(prev_interval)
+        if not was_armed:
+            blackbox.disarm()
+    return {
+        "divergence_counts": divergences,
+        "states_seen": states,
+        "fired": bool(fired),
+        "resolved": states[-1] in ("resolved", "inactive"),
+        "bundle_captured": bool(bundles),
+        "bundle_trigger": (
+            dict(bundles[0]["trigger"]) if bundles else None
+        ),
+    }
+
+
 @pytest.fixture()
 def po(mesh8):
     Postoffice.reset()
@@ -307,11 +403,7 @@ class TestShippedLearningRules:
         """Acceptance: a seeded LR blow-up drives the SHIPPED
         loss_divergence rule to firing, with a diagnostic bundle
         captured through the PR 13 alert trigger plane."""
-        from parameter_server_tpu.benchmarks.components import (
-            _divergence_drill,
-        )
-
-        out = _divergence_drill(po.mesh, smoke=True)
+        out = _divergence_drill(po.mesh)
         assert out["divergence_counts"].get("nonfinite", 0) >= 1
         assert out["fired"]
         assert "firing" in out["states_seen"]

@@ -165,38 +165,72 @@ class TestMigrate:
         assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
 
 
+def _migrate_under_pushes(kv, keys, batches):
+    """Push ``batches[0]``, then stall a migration between its snapshot
+    and install (rebalance.migrate fault) while the rest keep landing.
+    Returns ``migrate``'s record."""
+    _push_all(kv, [(keys, batches[0][1])])
+    faults.arm("rebalance.migrate", kind="delay", delay_s=0.5, once=True)
+    result = {}
+    t = threading.Thread(
+        target=lambda: result.update(kv.migrate(_perm(kv.num_slots, seed=4)))
+    )
+    t.start()
+    time.sleep(0.1)  # let the migration reach its stalled window
+    for _, vals in batches[1:]:
+        kv.push(kv.request(channel=0), keys=keys, values=vals)
+    t.join(timeout=30)
+    assert not t.is_alive()
+    kv.executor.wait_all(pop=False)
+    return result
+
+
 class TestJournalReplay:
     def test_pushes_landing_mid_migration_replay_bit_identically(self):
-        """Stall the migration between its snapshot and install
-        (rebalance.migrate fault) while pushes keep landing: they are
-        journaled, replayed past the barrier with translated slots, and
-        the result is bit-identical to an undisturbed run."""
+        """Pushes that land while a migration is stalled are journaled,
+        replayed past the barrier with translated slots, and the result
+        is bit-identical to an undisturbed run."""
         keys = np.arange(40, dtype=np.int64)
         batches = _batches(4, n_keys=40)
         kv = _store(hashed=False, name="journal", keys=keys)
-        _push_all(kv, [(keys, batches[0][1])])
-
-        faults.arm("rebalance.migrate", kind="delay", delay_s=0.5,
-                   once=True)
-        result = {}
-        t = threading.Thread(
-            target=lambda: result.update(
-                kv.migrate(_perm(kv.num_slots, seed=4))
-            )
-        )
-        t.start()
-        time.sleep(0.1)  # let the migration reach its stalled window
-        for _, vals in batches[1:]:
-            kv.push(kv.request(channel=0), keys=keys, values=vals)
-        t.join(timeout=30)
-        assert not t.is_alive()
-        kv.executor.wait_all(pop=False)
+        result = _migrate_under_pushes(kv, keys, batches)
         assert result["journaled"] >= 1
         assert result["replayed"] == result["journaled"]
 
         ref = _store(hashed=False, name="journal_ref", keys=keys)
         _push_all(ref, [(keys, b) for _, b in batches])
         assert kv.get_replica()[0].tobytes() == ref.get_replica()[0].tobytes()
+
+
+    def test_a_live_migration_compiles_nothing_new(self):
+        """A move under live pushes and pulls is a latency event, not a
+        compile event: the table a migration installs keeps the shapes
+        and the sharding the data plane compiled for, so a store that
+        journals and replays pushes across its own move, and is pulled
+        from afterwards, re-specializes no program of ``ops/kv_ops``."""
+        from parameter_server_tpu.telemetry import device as device_mod
+
+        keys = np.arange(40, dtype=np.int64)
+        batches = _batches(4, n_keys=40)
+        device_mod.reset()
+        try:
+            scratch = _store(hashed=False, name="warm", keys=keys)
+            _push_all(scratch, [(keys, batches[0][1])])
+            np.asarray(scratch.wait_pull(
+                scratch.pull(scratch.request(channel=0), keys=keys)
+            ))
+            device_mod.mark_warmup()
+
+            kv = _store(hashed=False, name="live", keys=keys)
+            result = _migrate_under_pushes(kv, keys, batches)
+            np.asarray(
+                kv.wait_pull(kv.pull(kv.request(channel=0), keys=keys))
+            )
+            assert result["journaled"] >= 1
+            snap = device_mod.snapshot()
+            assert snap["recompiles_post_warmup"] == 0, snap["functions"]
+        finally:
+            device_mod.reset()
 
 
 class TestServeContinuity:

@@ -273,8 +273,7 @@ def test_executor_telemetry_overhead_bounded():
     protects; the per-step telemetry cost is a buffered record (one
     small lock + append, flushed outside the hot path).
 
-    Measurement discipline (the ROADMAP bench invariant, same as
-    benchmarks/components.host_ingest_ab): this host's effective CPU
+    Measurement discipline: this host's effective CPU
     capacity flaps on a seconds timescale, so the quoted number is the
     MEDIAN of BACK-TO-BACK PAIRED reps — each pair runs the on/off arms
     adjacent in time (alternating order so drift cancels), and the

@@ -1,20 +1,16 @@
-"""Timeline tracing + critical-path attribution (ISSUE 7 tentpole).
+"""Timeline tracing (ISSUE 7 tentpole).
 
 Covers: flow-id propagation across the real pipeline threads (feeder →
 prep pool → consumer → executor step), the serve path's flow spans
 (submit → execute → coalesced flush → reply), the abandoned-span
-terminator from the pool's exception-forwarding path, the Chrome
-trace-event export (schema invariants + a committed golden file), and
-the attribution math on synthetic multi-thread traces with KNOWN
-critical paths — upload-bound, compute-bound, and queue-bound runs must
-each be attributed correctly.
+terminator from the pool's exception-forwarding path, and the Chrome
+trace-event export (schema invariants + a committed golden file).
 """
 
 from __future__ import annotations
 
 import json
 import os
-import sys
 import threading
 
 import numpy as np
@@ -30,11 +26,8 @@ from parameter_server_tpu.telemetry import (
     install_sink,
     new_flow,
 )
-from parameter_server_tpu.telemetry import attribution, timeline
+from parameter_server_tpu.telemetry import timeline
 from parameter_server_tpu.telemetry import spans as telemetry_spans
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "data", "timeline_golden.json")
 
@@ -339,7 +332,7 @@ class TestServeFlows:
 
 
 # ---------------------------------------------------------------------------
-# attribution: synthetic traces with KNOWN critical paths
+# synthetic span events
 # ---------------------------------------------------------------------------
 
 
@@ -352,283 +345,6 @@ def _span(name, t, dur, thread, flow=None, **attrs):
         ev["flow"] = flow
     ev.update(attrs)
     return ev
-
-
-def _staged_run(prep_s, upload_s, device_s, launches=4):
-    """Serialized launches: prep → upload → device back to back (the
-    phase_breakdown shape), on three threads."""
-    events, t = [], 100.0
-    for i in range(launches):
-        fid = 1000 + i
-        events.append(_span("bench.prep", t, prep_s, "prep-thread", fid))
-        t += prep_s
-        events.append(_span("bench.upload", t, upload_s, "upload-thread", fid))
-        t += upload_s
-        events.append(_span("bench.device", t, device_s, "MainThread", fid))
-        t += device_s
-    return events
-
-
-class TestAttribution:
-    def test_upload_bound_run_is_attributed_to_upload(self):
-        out = attribution.summarize(_staged_run(0.01, 0.10, 0.02))
-        assert out["binding_resource"] == "upload"
-        assert out["shares"]["upload"] == pytest.approx(
-            0.10 / 0.13, abs=0.01
-        )
-        assert out["flows"]["dominant"] == "upload"
-        assert out["binding_utilization"] == pytest.approx(
-            0.10 / 0.13, abs=0.01
-        )
-
-    def test_compute_bound_run_is_attributed_to_device(self):
-        out = attribution.summarize(_staged_run(0.01, 0.02, 0.10))
-        assert out["binding_resource"] == "device_compute"
-        assert out["flows"]["dominant"] == "device_compute"
-
-    def test_host_bound_run_is_attributed_to_host_prep(self):
-        out = attribution.summarize(_staged_run(0.10, 0.01, 0.02))
-        assert out["binding_resource"] == "host_prep"
-
-    def test_queue_bound_requests_dominated_by_queue_wait(self):
-        # serve shape: submit marker, a long wait, a short execute, reply
-        events = []
-        for i in range(5):
-            t = 10.0 + i * 0.3
-            fid = 2000 + i
-            events.append(_span("serve.submit", t, 0.0, "client", fid))
-            events.append(
-                _span("serve.execute", t + 0.2, 0.01, "serve-worker-0", fid)
-            )
-            events.append(
-                _span("serve.reply", t + 0.211, 0.0, "serve-worker-0", fid)
-            )
-        out = attribution.summarize(events)
-        assert out["flows"]["dominant"] == "queue_wait"
-        shares = out["flows"]["critical_path_shares"]
-        assert shares["queue_wait"] == pytest.approx(0.2 / 0.211, abs=0.02)
-
-    def test_pull_execute_is_queue_wait_not_host_prep(self):
-        # a pull's serve.execute blocks on the coalescer window + store
-        # round trip inside PullTicket.result — billing it as host_prep
-        # busy time would name the wrong binding resource under serve
-        # load. predict execution is real host math and stays host_prep.
-        pull = _span("serve.execute", 10.0, 0.05, "serve-worker-0", 1)
-        pull["req"] = "pull"
-        predict = _span("serve.execute", 10.0, 0.05, "serve-worker-1", 2)
-        predict["req"] = "predict"
-        assert attribution.categorize_event(pull) == "queue_wait"
-        assert attribution.categorize_event(predict) == "host_prep"
-        busy = attribution.busy_by_category([pull, predict])
-        assert busy["queue_wait"] == pytest.approx(0.05)
-        assert busy["host_prep"] == pytest.approx(0.05)
-
-    def test_flush_flows_do_not_dilute_flow_view(self):
-        # a coalescer flush flow's only duration-bearing span is the
-        # uncategorized serve.coalesce.flush wrapper (executor phases
-        # nest inside it), so its path has zero attributable time — it
-        # must be excluded from the flow view instead of pushing every
-        # category's median share toward zero
-        events = []
-        for i in range(3):  # request flows: mostly queue-wait
-            t, fid = 10.0 + i, 100 + i
-            events.append(_span("serve.submit", t, 0.0, "client", fid))
-            ex = _span("serve.execute", t + 0.2, 0.01, "serve-worker-0", fid)
-            ex["req"] = "pull"
-            events.append(ex)
-            events.append(_span("serve.reply", t + 0.211, 0.0, "serve-worker-0", fid))
-        for i in range(3):  # flush flows: wrapper + nested executor step
-            t, fid = 10.05 + i, 200 + i
-            events.append(_span("serve.coalesce.flush", t, 0.1, "flusher", fid))
-            events.append({
-                "kind": "span", "name": "executor.step", "executor": "e",
-                "ts": i, "t_wall": t + 0.09, "thread": "MainThread",
-                "flow": fid, "queue_wait_s": 0.01, "run_s": 0.06,
-                "materialize_s": 0.01, "total_s": 0.08,
-            })
-        out = attribution.attribute_flows(events)
-        assert out["count"] == 3  # request flows only
-        assert out["dominant"] == "queue_wait"
-        assert out["critical_path_shares"]["queue_wait"] > 0.9
-
-    def test_coalesce_flush_not_double_billed(self):
-        # the flush span wraps the union merge + store pull whose work
-        # the SAME flow's executor.step expansion already attributes —
-        # the wrapper itself must stay uncategorized, not queue_wait
-        flush = _span("serve.coalesce.flush", 10.0, 0.05, "flusher", 7)
-        step = {
-            "kind": "span", "name": "executor.step", "executor": "e",
-            "ts": 1, "t_wall": 10.05, "thread": "MainThread", "flow": 7,
-            "queue_wait_s": 0.01, "run_s": 0.03, "materialize_s": 0.01,
-            "total_s": 0.05,
-        }
-        assert attribution.categorize_event(flush) is None
-        busy = attribution.busy_by_category([flush, step])
-        assert busy["queue_wait"] == pytest.approx(0.01)
-        assert busy["device_compute"] == pytest.approx(0.04)
-
-    def test_executor_step_expands_into_phases(self):
-        events = [
-            {
-                "kind": "span", "name": "executor.step", "executor": "e",
-                "ts": 3, "t_wall": 50.0, "thread": "MainThread", "flow": 9,
-                "queue_wait_s": 0.4, "run_s": 0.1, "materialize_s": 0.1,
-                "total_s": 0.6,
-            }
-        ]
-        expanded = attribution.expand_executor_steps(events)
-        names = [e["name"] for e in expanded]
-        assert names == [
-            "executor.queue_wait", "executor.run", "executor.materialize",
-        ]
-        assert all(e["flow"] == 9 for e in expanded)
-        # phases tile [t_end - total, t_end] in order
-        assert expanded[0]["t_wall"] == pytest.approx(49.4)
-        assert expanded[-1]["t_wall"] + expanded[-1]["dur_s"] == pytest.approx(50.0)
-        out = attribution.summarize(events)
-        assert out["busy_s"]["queue_wait"] == pytest.approx(0.4)
-        assert out["busy_s"]["device_compute"] == pytest.approx(0.2)
-
-    def test_pipelined_overlap_not_double_counted_on_critical_path(self):
-        # two flows whose device span overlaps the next flow's upload:
-        # per-flow paths only count time past the cursor
-        events = [
-            _span("bench.upload", 0.0, 1.0, "up", 1),
-            _span("bench.device", 0.5, 1.0, "main", 1),  # overlaps 0.5
-        ]
-        cp = attribution.flow_critical_path(events)
-        assert cp["total_s"] == pytest.approx(1.5)
-        assert cp["by_category"]["upload"] == pytest.approx(1.0)
-        assert cp["by_category"]["device_compute"] == pytest.approx(0.5)
-
-    def test_nested_encode_carved_out_of_host_prep(self):
-        # wire.encode runs INSIDE the prep call on the prep thread
-        # (worker.prep -> encode_exact), so its seconds bill to encode
-        # alone — never doubly to host_prep
-        events = [
-            _span("bench.prep", 0.0, 1.0, "prep-thread", 1),
-            _span("wire.encode", 0.3, 0.4, "prep-thread", 1, mode="exact"),
-            _span("bench.device", 1.0, 0.5, "MainThread", 1),
-        ]
-        busy = attribution.busy_by_category(events)
-        assert busy["host_prep"] == pytest.approx(0.6)
-        assert busy["encode"] == pytest.approx(0.4)
-        out = attribution.summarize(events)
-        assert out["shares"]["host_prep"] == pytest.approx(0.6 / 1.5, abs=1e-4)
-        assert out["shares"]["encode"] == pytest.approx(0.4 / 1.5, abs=1e-4)
-        # an OVERLAPPING encode on another thread is parallel work, not
-        # nesting — both resources really were busy; no carve-out
-        parallel = [
-            _span("bench.prep", 0.0, 1.0, "prep-thread", 1),
-            _span("wire.encode", 0.3, 0.4, "other-thread", 2),
-        ]
-        busy2 = attribution.busy_by_category(parallel)
-        assert busy2["host_prep"] == pytest.approx(1.0)
-        assert busy2["encode"] == pytest.approx(0.4)
-
-    def test_window_clips_busy_time(self):
-        events = [_span("bench.upload", 0.0, 10.0, "up", 1)]
-        out = attribution.summarize(events, window=(2.0, 4.0))
-        assert out["busy_s"]["upload"] == pytest.approx(2.0)
-        assert out["wall_s"] == pytest.approx(2.0)
-
-    def test_flows_view_respects_window(self):
-        # in-window flows are upload-bound; a later serialized
-        # device-bound phase outside the window must stay out of the
-        # per-flow median (bench.py's e2e section windows around the
-        # timed stream, but the trace also holds breakdown-phase flows)
-        timed = _staged_run(0.01, 0.10, 0.02)
-        off = [
-            dict(ev, t_wall=ev["t_wall"] + 500.0, flow=ev["flow"] + 100)
-            for ev in _staged_run(0.01, 0.02, 0.30, launches=8)
-        ]
-        lo, hi = timeline.events_window(timed)
-        out = attribution.summarize(timed + off, window=(lo, hi))
-        assert out["flows"]["count"] == 4
-        assert out["flows"]["dominant"] == "upload"
-        # unwindowed, the off-phase flows swamp the median
-        assert (
-            attribution.summarize(timed + off)["flows"]["dominant"]
-            == "device_compute"
-        )
-
-    def test_abandoned_spans_counted_not_attributed(self):
-        events = _staged_run(0.01, 0.05, 0.01, launches=2)
-        events.append(
-            {
-                "kind": "span", "name": "pool.worker", "t_wall": 101.0,
-                "dur_s": 0.0, "thread": "w0", "abandoned": True,
-                "reason": "RuntimeError",
-            }
-        )
-        out = attribution.summarize(events)
-        assert out["abandoned_spans"] == 1
-        assert out["binding_resource"] == "upload"
-
-
-# ---------------------------------------------------------------------------
-# bench wiring: the attribution record section
-# ---------------------------------------------------------------------------
-
-
-class TestBenchAttribution:
-    def test_attach_attribution_agrees_with_hand_breakdown(self, tmp_path):
-        import bench
-
-        path = str(tmp_path / "t.jsonl")
-        with open(path, "w") as f:
-            for ev in _staged_run(0.01, 0.10, 0.02):
-                f.write(json.dumps({**ev, "phase": "breakdown"}) + "\n")
-        rec = {
-            "breakdown_fracs": {
-                "host_prep": 0.077, "upload": 0.769, "device": 0.154,
-            }
-        }
-        bench.attach_attribution(rec, path)
-        att = rec["attribution"]
-        assert att["binding_resource"] == "upload"
-        assert att["shares"]["upload"] == pytest.approx(0.769, abs=0.1)
-        assert att["agrees_with_hand_breakdown"] is True
-        assert att["trace_jsonl"] == path
-
-    def test_attach_attribution_flags_disagreement(self, tmp_path):
-        import bench
-
-        path = str(tmp_path / "t.jsonl")
-        with open(path, "w") as f:
-            for ev in _staged_run(0.01, 0.10, 0.02):
-                f.write(json.dumps({**ev, "phase": "breakdown"}) + "\n")
-        rec = {
-            "breakdown_fracs": {
-                "host_prep": 0.60, "upload": 0.20, "device": 0.20,
-            }
-        }
-        bench.attach_attribution(rec, path)
-        assert rec["attribution"]["agrees_with_hand_breakdown"] is False
-
-    def test_attach_attribution_never_breaks_the_record(self):
-        import bench
-
-        rec = {}
-        bench.attach_attribution(rec, "/nonexistent/path.jsonl")
-        assert "attribution" not in rec
-        assert "attribution_error" in rec
-        bench.attach_attribution(rec, None)  # no sink: silent no-op
-
-    def test_e2e_window_section(self, tmp_path):
-        import bench
-
-        path = str(tmp_path / "t.jsonl")
-        events = _staged_run(0.01, 0.10, 0.02)
-        with open(path, "w") as f:
-            for ev in events:
-                f.write(json.dumps({**ev, "phase": "e2e"}) + "\n")
-        rec = {}
-        lo, hi = timeline.events_window(events)
-        bench.attach_attribution(rec, path, (lo, hi))
-        e2e = rec["attribution"]["e2e"]
-        assert e2e["binding_resource"] == "upload"
-        assert e2e["wall_s"] == pytest.approx(hi - lo)
 
 
 # ---------------------------------------------------------------------------

@@ -1004,3 +1004,60 @@ class TestStagingLegCodec:
         assert cb.wire_nbytes <= cb.raw_nbytes + len(cb.frames)
         got = wire.decompress_batch(cb)
         np.testing.assert_array_equal(got["x"], noise["x"])
+
+
+# ---------------------------------------------------------------------------
+# bytes per example, exact, per wire: what `wire_bytes_per_example` reads
+# ---------------------------------------------------------------------------
+
+
+def _fixture_bytes(valued, mode):
+    """Bytes the committed fixture's three minibatches put on the link
+    through the exact-wire prep (``mode`` None: the raw PreppedBatch)."""
+    t = TestEncodeExactParity()
+    total = 0
+    for b in fixture_batches(binary=not valued):
+        raw = t._prep(b, shared=True)
+        total += wire.tree_nbytes(
+            raw if mode is None
+            else wire.encode_exact(raw, t.NUM_SLOTS, mode=mode)
+        )
+    return total
+
+
+def _uniform_bytes(which):
+    """One 1,024-row uniform-lane batch through the bits wire or the
+    stream-once wire (the committed fixture is ragged: both refuse it)."""
+    from parameter_server_tpu.apps.linear.async_sgd import (
+        prep_batch_ell_bits,
+    )
+
+    t = TestStreamWireParity()
+    b = _criteo_like_batches(1, rows=1024)[0]
+    if which == "stream":
+        return wire.tree_nbytes(t._prep(b, t._statics(b), rows_pad=512))
+    d = KeyDirectory(t.NUM_SLOTS, hashed=True)
+    return wire.tree_nbytes(prep_batch_ell_bits(b, d, 2, 512, 8, t.NUM_SLOTS))
+
+
+class TestBytesPerExample:
+    """A wire's size is a property of its format: the same rows ship the
+    same bytes on every host. A change here moves
+    ``wire_bytes_per_example`` in every cell that runs the wire."""
+
+    @pytest.mark.parametrize("measure,args,want", [
+        # 1312 B an example: the padded PreppedBatch
+        pytest.param(_fixture_bytes, (False, None), 125952, id="raw"),
+        # 213.5: binary rows, the value stream elided
+        pytest.param(_fixture_bytes, (False, "exact"), 20496, id="exact"),
+        # 469.5: the f32 value stream rides along
+        pytest.param(_fixture_bytes, (True, "exact"), 45072,
+                     id="exact_valued"),
+        pytest.param(_fixture_bytes, (True, "int8"), 26688,
+                     id="int8_valued"),  # 278
+        pytest.param(_uniform_bytes, ("bits",), 18576, id="bits"),  # 18.14
+        # 14.95: the lane dictionary under bits
+        pytest.param(_uniform_bytes, ("stream",), 15304, id="stream"),
+    ])
+    def test_exact_byte_count(self, measure, args, want):
+        assert measure(*args) == want
