@@ -85,7 +85,9 @@ def run(argv=None) -> dict:
                     "relative positions; default is NoPE)")
     ap.add_argument("--rope-theta", type=float, default=10000.0)
     ap.add_argument("--remat", action="store_true",
-                    help="rematerialize layers (jax.checkpoint)")
+                    help="rematerialize layers (jax.checkpoint), keeping "
+                    "each layer's input, its expert choices and the flash "
+                    "kernel's output and log-sum-exp")
     ap.add_argument("--bf16", action="store_true",
                     help="bfloat16 decoder activations")
     ap.add_argument("--moe-every", type=int, default=0)

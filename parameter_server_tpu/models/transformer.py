@@ -70,7 +70,9 @@ class LMConfig:
     # training memory drops from O(layers * S) activations to O(S) +
     # per-layer recompute — THE long-context memory lever alongside
     # sequence parallelism. Gradients are numerically identical up to
-    # compiler reassociation of the recomputed ops.
+    # compiler reassociation of the recomputed ops. Kept besides each
+    # layer's input: the expert layer's choices and the flash kernel's
+    # output and log-sum-exp (lm_forward_with_stats says why).
     remat: bool = False
     # "bfloat16" runs decoder activations in bf16 (MXU-native): params
     # and the softmax/logits stay float32, attention accumulates f32
@@ -576,11 +578,21 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
         return x, stats
 
     if cfg.remat:
-        # everything is recomputed but the expert layer's choices: a
-        # near-tie decided again could fall the other way
+        # everything is recomputed but, beside the layer's input (which
+        # jax.checkpoint always keeps): the expert layer's choices,
+        # because a near-tie decided again could fall the other way; and
+        # the flash kernel's output and log-sum-exp, because they cost
+        # O(S^2 * d) to make and O(S * d) to keep, B*S*H*2 bytes a device
+        # a layer at bf16 (over a ring of n chips, n chunk outputs of S/n
+        # positions each: the same bytes whatever n), as much as one more
+        # layer input. The XLA attention modes bear no such name.
+        from ..ops.flash_attention import FLASH_LSE, FLASH_OUT
+
         layer = jax.checkpoint(
             layer, static_argnums=(2, 3),
-            policy=jax.checkpoint_policies.save_only_these_names(TOP_E),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                TOP_E, FLASH_OUT, FLASH_LSE
+            ),
         )
 
     x = params["emb"][tokens]

@@ -48,6 +48,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -499,12 +500,26 @@ def _flash(q, k, v, q_offset, k_offset, causal, block_q, block_k,
     )
 
 
+# the names of the forward rule's two residuals that are also its
+# outputs, for ``jax.checkpoint`` policies (``save_only_these_names``):
+# a rematerialised layer whose policy lists them hands the backward rule
+# the values its forward pass made, and does not run the forward kernel
+# again (O(Sq*Sk*D) work to make, O(Sq*D) bytes to keep). They sit on the
+# values the backward rule receives: a name on the caller's copy of the
+# result would keep that copy and still recompute these. No policy
+# listing them, a name is the identity.
+FLASH_OUT = "flash_attention_out"
+FLASH_LSE = "flash_attention_lse"
+
+
 def _flash_fwd(q, k, v, q_offset, k_offset, causal, block_q, block_k,
                use_pallas, interpret, window):
     out, lse = _flash(
         q, k, v, q_offset, k_offset, causal, block_q, block_k,
         use_pallas, interpret, window,
     )
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return (out, lse), (q, k, v, out, lse, q_offset, k_offset)
 
 

@@ -62,3 +62,33 @@ def repo_texts(tops, suffixes):
                     full = os.path.join(root, name)
                     with open(full, encoding="utf-8", errors="replace") as f:
                         yield os.path.relpath(full, repo), f.read()
+
+
+@pytest.fixture()
+def flash_as_on_the_chip(monkeypatch):
+    """The backend here is the CPU and the code asks it which attention
+    to take: steer it to the kernels the chip runs, for tests that count
+    a program's kernel calls and never lower it. Traces cached under
+    either answer are forgotten on the way in and on the way out."""
+    from parameter_server_tpu.ops import flash_attention as fa
+
+    jax.clear_caches()
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    yield
+    jax.clear_caches()
+
+
+def jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it: what the
+    tests that count a program's kernel calls walk."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from jaxpr_eqns(sub)
+
+
+def pallas_calls(fn, *args) -> int:
+    """How many ``pallas_call`` equations the program of ``fn(*args)``
+    holds. A jaxpr is never lowered, so the CPU counts what a chip runs."""
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    return sum(e.primitive.name == "pallas_call" for e in jaxpr_eqns(jaxpr))

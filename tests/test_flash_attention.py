@@ -18,6 +18,7 @@ from parameter_server_tpu.models.attention import (
     dense_mha,
     ring_attention,
 )
+from parameter_server_tpu.ops import flash_attention as fa
 from parameter_server_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_ref,
@@ -190,6 +191,86 @@ def test_flash_kernel_gradients_through_lse():
 
     for a, b in zip(make_loss(False)(q, k, v), make_loss(True)(q, k, v)):
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-4)
+
+
+def _kept_layer(policy):
+    """A layer around the interpret-mode kernel, rematerialised under
+    ``policy`` (``None``: not rematerialised), and its inputs."""
+    bh, s, d = 2, 136, 32
+    x, wq, wo = _rand((bh, s, d), 1), _rand((d, d), 2), _rand((d, d), 3)
+
+    def layer(x, wq, wo):
+        att = flash_attention(
+            x @ wq, x, x, causal=True, use_pallas=True, interpret=True
+        )
+        return x + jnp.tanh(att) @ wo
+
+    if policy is not None:
+        layer = jax.checkpoint(layer, policy=policy)
+    grad = jax.grad(
+        lambda x, wq, wo: jnp.sum(layer(x, wq, wo) ** 2), argnums=(0, 1, 2)
+    )
+    return grad, (x, wq, wo)
+
+
+def test_a_policy_that_lists_the_residuals_runs_the_forward_kernel_once():
+    """The forward rule names its output and log-sum-exp: a checkpoint
+    policy that lists the names keeps them, and the gradient program
+    holds forward, dq and dkv where one that lists none holds the
+    forward twice. The values do not know the difference."""
+    from conftest import pallas_calls
+
+    save = jax.checkpoint_policies.save_only_these_names
+    plain, args = _kept_layer(None)
+    kept, _ = _kept_layer(save(fa.FLASH_OUT, fa.FLASH_LSE))
+    again, _ = _kept_layer(save())
+    assert pallas_calls(plain, *args) == 3
+    assert pallas_calls(kept, *args) == 3
+    assert pallas_calls(again, *args) == 4
+    for a, b, c in zip(kept(*args), again(*args), plain(*args)):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a, c)
+
+
+@pytest.mark.parametrize("differentiated", [False, True])
+def test_the_names_change_no_program_without_a_checkpoint(
+    differentiated, monkeypatch
+):
+    """No policy, no effect: serving's program (the primal, which never
+    meets the forward rule) and plain training's are the ones they
+    were, apart from training's two identity ``name`` equations."""
+    from conftest import jaxpr_eqns
+
+    q, k, v = (_rand((2, 136, 32), seed) for seed in (1, 2, 3))
+
+    def f(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, use_pallas=True, interpret=True
+        ))
+
+    if differentiated:
+        f = jax.grad(f, argnums=(0, 1, 2))
+
+    def program():
+        eqns = list(jaxpr_eqns(jax.make_jaxpr(f)(q, k, v).jaxpr))
+        names = [e.params["name"] for e in eqns if e.primitive.name == "name"]
+        return names, [
+            (e.primitive.name, [str(o.aval) for o in e.outvars])
+            for e in eqns if e.primitive.name != "name"
+        ]
+
+    names, named = program()
+    # the forward rule's trace is cached by function: forget it before
+    # tracing without the names, and again before anyone else traces
+    try:
+        with monkeypatch.context() as patch:
+            patch.setattr(fa, "checkpoint_name", lambda x, name: x)
+            jax.clear_caches()
+            no_names, unnamed = program()
+    finally:
+        jax.clear_caches()
+    assert named == unnamed and no_names == []
+    assert names == ([fa.FLASH_OUT, fa.FLASH_LSE] if differentiated else [])
 
 
 def test_ring_flash_with_interpret_kernel_on_mesh():

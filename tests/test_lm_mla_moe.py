@@ -23,6 +23,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from chipbench import lm_reference as ref  # noqa: E402
+from conftest import jaxpr_eqns, pallas_calls  # noqa: E402
 from parameter_server_tpu.apps.lm import trainer as lm_trainer  # noqa: E402
 from parameter_server_tpu.models import latent_attention as latent  # noqa: E402
 from parameter_server_tpu.models import moe as moelib  # noqa: E402
@@ -119,6 +120,62 @@ def test_remat_changes_nothing(setup, both_grads):
     (want, want_grads), _ = both_grads
     assert abs(float(loss) - float(want)) < 1e-6
     assert max(rel(grads[k], want_grads[k]) for k in LEAVES) < 1e-5
+
+
+def _under_the_parents_policy(fn):
+    """``fn()`` with every checkpoint policy cut to ``TOP_E`` alone: what
+    a rematerialised layer kept before it kept the flash kernel's output
+    and log-sum-exp."""
+    save = jax.checkpoint_policies.save_only_these_names
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(
+            jax.checkpoint_policies, "save_only_these_names",
+            lambda *names: save(moelib.TOP_E),
+        )
+        return fn()
+
+
+@pytest.fixture(scope="module")
+def kept_and_recomputed(setup):
+    """Loss and gradients of the rematerialised model, as it is and under
+    the policy that makes the attention's residuals again."""
+    desc, _, params, tokens = setup
+    cfg = lm_trainer.model_from_description(desc, remat=True)
+    run = lambda: jax.value_and_grad(tfm.lm_loss)(  # noqa: E731
+        params, tokens, cfg, mesh_of(1)
+    )
+    return run(), _under_the_parents_policy(run)
+
+
+def test_a_kept_attention_output_gives_the_recomputed_loss(
+    kept_and_recomputed
+):
+    (kept, _), (again, _) = kept_and_recomputed
+    assert np.isfinite(float(kept)) and float(kept) == float(again)
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_a_kept_attention_output_gives_the_recomputed_gradient(
+    kept_and_recomputed, leaf
+):
+    """A kept value is the value the recomputation made: to the last bit."""
+    (_, kept), (_, again) = kept_and_recomputed
+    assert np.any(np.asarray(kept[leaf]))
+    np.testing.assert_array_equal(kept[leaf], again[leaf])
+
+
+def test_remat_runs_one_flash_forward_a_layer(setup, flash_as_on_the_chip):
+    """The gradient program holds forward, dq and dkv for every ``mla``
+    layer; under the parent's policy the forward twice."""
+    desc, _, params, tokens = setup
+    cfg = lm_trainer.model_from_description(desc, remat=True)
+    n_mla = sum(att == "mla" for att, _ in cfg.layer_kinds)
+    # a function made anew for each count: make_jaxpr caches by function
+    count = lambda: pallas_calls(  # noqa: E731
+        jax.grad(lambda p: tfm.lm_loss(p, tokens, cfg, mesh_of(1))), params
+    )
+    assert n_mla == 2 and count() == 3 * n_mla
+    assert _under_the_parents_policy(count) == 4 * n_mla
 
 
 def test_the_blocked_reference_is_the_plain_one(setup, both_grads):
@@ -339,14 +396,6 @@ def _tail_layer(routing: str = "alone"):
     return share, lp, x, m
 
 
-def _eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs inside it."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqns(sub)
-
-
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
 @pytest.mark.parametrize("routing,rows,tail", [
     ("alone", None, 0), ("forced", TAIL_TOKENS, 1), ("boundary", 512, 0),
@@ -424,7 +473,8 @@ def test_a_quarter_of_the_experts_held_means_no_conditional(
         return jnp.sum(moelib.topk_moe_ffn(lp, x, cfg, jnp.float32)[0])
 
     count = lambda f: sum(  # noqa: E731
-        e.primitive.name == "cond" for e in _eqns(jax.make_jaxpr(f)(lp, x).jaxpr)
+        e.primitive.name == "cond"
+        for e in jaxpr_eqns(jax.make_jaxpr(f)(lp, x).jaxpr)
     )
     assert count(out) == conds
     assert count(jax.grad(out, argnums=(0, 1))) == 2 * conds
@@ -444,7 +494,7 @@ def test_the_split_adds_no_scatter_add_as_wide_as_the_model():
         lp, x.reshape(-1, 64)
     ).jaxpr
     onto = [
-        e.outvars[0].aval.shape for e in _eqns(jaxpr)
+        e.outvars[0].aval.shape for e in jaxpr_eqns(jaxpr)
         if e.primitive.name in ("scatter-add", "scatter_add")
     ]
     assert sorted(onto) == [(TAIL_TOKENS, 2)] * 2 + [(TAIL_TOKENS, 16)], onto

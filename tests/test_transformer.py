@@ -278,6 +278,27 @@ class TestMemoryAndPrecision:
         losses, _ = run_copy_training(mesh8, params, cfg_all, steps=30)
         assert losses[-1] < 0.6 * losses[0], (losses[0], losses[-1])
 
+    @pytest.mark.parametrize("ring", [1, 4])
+    def test_remat_runs_each_flash_forward_once(
+        self, ring, flash_as_on_the_chip
+    ):
+        """One ``mha`` layer through ``ring_flash``, rematerialised: the
+        gradient program holds forward, dq and dkv for every hop of the
+        ring, each hop's output and log-sum-exp being kept, and no
+        forward again (which would make four a hop)."""
+        from conftest import pallas_calls
+        from parameter_server_tpu.parallel import mesh as meshlib
+
+        one = LMConfig(
+            vocab=32, d_model=32, n_heads=2, n_layers=1, d_ff=64,
+            attention="ring_flash", remat=True,
+        )
+        mesh = meshlib.make_mesh(num_data=ring, num_server=1)
+        tokens = shard_tokens(np.zeros((2, 64), np.int32), mesh)
+        grad = jax.grad(lambda p: lm_loss(p, tokens, one, mesh, "data"))
+        params = init_lm(jax.random.PRNGKey(0), one)
+        assert pallas_calls(grad, params) == 3 * ring
+
     def test_bad_compute_dtype_rejected(self):
         with pytest.raises(ValueError, match="compute_dtype"):
             LMConfig(compute_dtype="float16")
