@@ -17,11 +17,13 @@ Without --data it trains on a synthetic periodic-byte corpus so the demo
 runs anywhere.
 
 ``--model-config FILE`` takes the model from a description file instead
-of the width flags (``trainer.model_from_description``: latent attention,
-a dropless top-k expert layer beside a shared expert, RMSNorm, an untied
-head over the file's vocabulary); bytes are then ids below 256 of that
-vocabulary. Such a model trains here; the generation flags refuse it by
-name, because the serving forwards have no latent cache.
+of the width flags (``trainer.model_from_description``: a ``mistral4``
+description's latent attention or a ``solar_open2`` description's gated
+delta-rule and gated GQA layers, a dropless top-k expert layer beside a
+shared expert, RMSNorm, an untied head over the file's vocabulary); bytes
+are then ids below 256 of that vocabulary. Such a model trains here; the
+generation flags refuse it by name, because the serving forwards have no
+latent cache and carry no recurrent state.
 """
 
 from __future__ import annotations
@@ -248,6 +250,12 @@ def run(argv=None) -> dict:
                 "--model-config: the new layer kinds have no tensor-"
                 "parallel or FSDP placement yet (--num-servers 1, no "
                 "--fsdp)"
+            )
+        if n_data > 1 and any(a == "kda" for a, _ in cfg.layer_kinds):
+            ap.error(
+                "--model-config: a 'kda' layer's recurrence is not built "
+                f"over a sequence-sharded mesh ({n_data} devices share the "
+                "sequence here): run it on one device"
             )
         if args.prompt is not None:
             try:

@@ -38,21 +38,22 @@ def load_description(path: str) -> dict:
 
 def model_from_description(desc: dict, *, attention: str = "ring_flash",
                            remat: bool = False, bf16: bool = False):
-    """An ``LMConfig`` for a ``mistral4`` description: latent attention
-    and a top-k expert layer beside shared experts in every layer,
-    RMSNorm, an untied head."""
-    from ...models.latent_attention import MLAConfig, YarnRope
+    """An ``LMConfig`` for a description of one of ``MODEL_TYPES``, each
+    with a top-k expert layer beside shared experts in every layer,
+    RMSNorm and an untied head: ``mistral4`` (latent attention) or
+    ``solar_open2`` (gated NoPE GQA layers at ``gqa_layers``, gated
+    delta-rule layers, "kda", everywhere else)."""
     from ...models.moe import TopKMoEConfig
-    from ...models.transformer import LMConfig
 
-    if desc.get("model_type") != "mistral4":
+    kind = desc.get("model_type")
+    if kind not in MODEL_TYPES:
         raise ValueError(
-            f"model_type {desc.get('model_type')!r}: only mistral4 is "
-            "described here"
+            f"model_type {kind!r}: the model types described here are "
+            f"{', '.join(MODEL_TYPES)}"
         )
-    if desc["hidden_act"] != "silu" or desc.get("attention_bias") or desc.get(
-        "mlp_bias"
-    ):
+    if desc.get("hidden_act", "silu") != "silu" or desc.get(
+        "attention_bias"
+    ) or desc.get("mlp_bias"):
         raise ValueError("the layer is a gated SiLU FFN without biases")
     if desc.get("n_group", 1) != 1 or desc.get("topk_group", 1) != 1:
         raise ValueError("group-limited routing is not built")
@@ -61,34 +62,15 @@ def model_from_description(desc: dict, *, attention: str = "ring_flash",
             "first_k_dense_replace != 0: leading dense layers are not "
             "described here"
         )
-    rp = desc["rope_parameters"]
-    yarn = None
-    if rp.get("rope_type", rp.get("type")) == "yarn":
-        yarn = YarnRope(
-            factor=rp["factor"],
-            original_max_position=rp["original_max_position_embeddings"],
-            beta_fast=rp["beta_fast"], beta_slow=rp["beta_slow"],
-            mscale=rp.get("mscale", 1.0),
-            mscale_all_dim=rp.get("mscale_all_dim", 0.0),
-            position_scale_beta=rp.get("llama_4_scaling_beta", 0.0),
-        )
     published, share = desc.get("published", {}), desc.get("share", {})
-    n_layers = desc["num_hidden_layers"]
-    return LMConfig(
+    common = dict(
         vocab=desc["vocab_size"], d_model=desc["hidden_size"],
-        n_heads=desc["num_attention_heads"], n_layers=n_layers,
-        d_ff=desc["intermediate_size"], attention=attention, remat=remat,
+        n_heads=desc["num_attention_heads"],
+        n_layers=desc["num_hidden_layers"], d_ff=desc["intermediate_size"],
+        attention=attention, remat=remat,
         compute_dtype="bfloat16" if bf16 else "float32",
-        rope_theta=rp["rope_theta"], tie_head=desc["tie_word_embeddings"],
-        norm="rmsnorm", norm_eps=desc["rms_norm_eps"], ffn_act="swiglu",
-        scale_emb=False, layers=(("mla", "moe"),) * n_layers,
-        mla=MLAConfig(
-            q_lora_rank=desc["q_lora_rank"], kv_lora_rank=desc["kv_lora_rank"],
-            qk_nope_head_dim=desc["qk_nope_head_dim"],
-            qk_rope_head_dim=desc["qk_rope_head_dim"],
-            v_head_dim=desc["v_head_dim"],
-            rope_interleave=desc["rope_interleave"], yarn=yarn,
-        ),
+        tie_head=desc["tie_word_embeddings"], norm="rmsnorm",
+        norm_eps=desc["rms_norm_eps"], ffn_act="swiglu", scale_emb=False,
         moe=TopKMoEConfig(
             n_experts=published.get(
                 "n_routed_experts", desc["n_routed_experts"]
@@ -102,6 +84,84 @@ def model_from_description(desc: dict, *, attention: str = "ring_flash",
             routed_scaling_factor=float(desc["routed_scaling_factor"]),
         ),
     )
+    return _ATTENTION_OF[kind](desc, common)
+
+
+def _mistral4(desc: dict, common: dict):
+    from ...models.latent_attention import MLAConfig, YarnRope
+    from ...models.transformer import LMConfig
+
+    rp = desc["rope_parameters"]
+    yarn = None
+    if rp.get("rope_type", rp.get("type")) == "yarn":
+        yarn = YarnRope(
+            factor=rp["factor"],
+            original_max_position=rp["original_max_position_embeddings"],
+            beta_fast=rp["beta_fast"], beta_slow=rp["beta_slow"],
+            mscale=rp.get("mscale", 1.0),
+            mscale_all_dim=rp.get("mscale_all_dim", 0.0),
+            position_scale_beta=rp.get("llama_4_scaling_beta", 0.0),
+        )
+    return LMConfig(
+        **common, rope_theta=rp["rope_theta"],
+        layers=(("mla", "moe"),) * common["n_layers"],
+        mla=MLAConfig(
+            q_lora_rank=desc["q_lora_rank"], kv_lora_rank=desc["kv_lora_rank"],
+            qk_nope_head_dim=desc["qk_nope_head_dim"],
+            qk_rope_head_dim=desc["qk_rope_head_dim"],
+            v_head_dim=desc["v_head_dim"],
+            rope_interleave=desc["rope_interleave"], yarn=yarn,
+        ),
+    )
+
+
+def _solar_open2(desc: dict, common: dict):
+    """Layer ``i`` is a gated GQA layer without rope where ``i`` is in
+    ``gqa_layers`` and a gated delta-rule layer elsewhere, its widths
+    from ``linear_attn_config``."""
+    from ...models.kda import KDAConfig
+    from ...models.transformer import LMConfig
+
+    if desc.get("use_rope"):
+        raise ValueError(
+            "use_rope true: a solar_open2 description's GQA layers are "
+            "built without rope (NoPE) only"
+        )
+    if desc.get("kda_use_full_proj"):
+        raise ValueError(
+            "kda_use_full_proj true: the decay gate is built as its "
+            "low-rank pair only"
+        )
+    if not desc.get("kda_allow_neg_eigval", True):
+        raise ValueError(
+            "kda_allow_neg_eigval false: the gated delta-rule layer is "
+            "built with beta = 2 sigmoid(.) only"
+        )
+    lin = desc["linear_attn_config"]
+    if lin.get("num_kv_heads") not in (None, lin["num_heads"]):
+        raise ValueError(
+            "linear_attn_config.num_kv_heads: the gated delta-rule layer "
+            "is built with as many key/value heads as query heads"
+        )
+    gqa = set(desc["gqa_layers"])
+    return LMConfig(
+        **common, n_kv_heads=desc["num_key_value_heads"],
+        head_dim=desc["head_dim"], attn_gate=bool(desc.get("use_gqa_gate")),
+        rope=False,
+        layers=tuple(
+            ("mha" if i in gqa else "kda", "moe")
+            for i in range(common["n_layers"])
+        ),
+        kda=KDAConfig(
+            n_heads=lin["num_heads"], head_dim=lin["head_dim"],
+            conv_size=lin["short_conv_kernel_size"],
+            gate_rank=lin.get("gate_rank", lin["head_dim"]),
+        ),
+    )
+
+
+_ATTENTION_OF = {"mistral4": _mistral4, "solar_open2": _solar_open2}
+MODEL_TYPES = tuple(_ATTENTION_OF)
 
 
 def make_optimizer(name: str, lr, *, clip_norm: Optional[float] = None,
@@ -136,8 +196,9 @@ class Launch:
 
     loss: object  # device scalar: the launch's last step's loss
     # device arrays (``lm_forward_with_stats``): the counts
-    # (``moe.MOE_COUNTS``: ``expert_rows``, ``buffer_passes``) summed over
-    # the launch's steps; its last step's choices and router probes
+    # (``transformer.STEP_COUNTS``: ``expert_rows``, ``buffer_passes``,
+    # ``kda_scan_tokens``) summed over the launch's steps; its last
+    # step's choices and router probes
     stats: dict
     tokens: int
 
@@ -154,8 +215,8 @@ class Trainer:
         import jax
         import optax
 
-        from ...models.moe import MOE_COUNTS
         from ...models.transformer import (
+            STEP_COUNTS,
             lm_loss_and_stats,
             next_token_targets,
         )
@@ -211,7 +272,7 @@ class Trainer:
                 # counts add up over the launch's steps; the choices
                 # and probes kept are the last step's
                 return p, opt, losses[-1], {
-                    k: v.sum(0) if k in MOE_COUNTS else v[-1]
+                    k: v.sum(0) if k in STEP_COUNTS else v[-1]
                     for k, v in stats.items()
                 }
 
@@ -334,19 +395,24 @@ class Trainer:
     def collect(self, launch: Launch):
         """``(loss, counts)`` of a launch on the host, counted: its
         ``expert_rows`` and ``buffer_passes`` where the model has the
-        dropless layer. The launch's choices and probes stay on the
+        dropless layer, its ``kda_scan_tokens`` where it has the gated
+        delta-rule layer. The launch's choices and probes stay on the
         device (``launch.stats``)."""
-        from ...models.moe import MOE_COUNTS
+        from ...models.transformer import KDA_SCAN_TOKENS, STEP_COUNTS
 
         with self.loop_phase("collect_wait"):
             loss = float(launch.loss)
         with self.loop_phase("collect_host"):
             counts = {
                 k: np.asarray(v) for k, v in launch.stats.items()
-                if k in MOE_COUNTS
+                if k in STEP_COUNTS
             }
             if self._counters is not None:
                 self._counters["tokens"].inc(launch.tokens)
+                if KDA_SCAN_TOKENS in counts:
+                    self._counters["kda_scan_tokens"].inc(
+                        int(counts[KDA_SCAN_TOKENS])
+                    )
                 for j, n in enumerate(counts.get("expert_rows", ())):
                     self._counters["expert_rows"].labels(
                         expert=str(self.cfg.moe.expert_offset + j)

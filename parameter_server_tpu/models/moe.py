@@ -413,12 +413,60 @@ def _buffer_part(x, top_w, weights, part):
         return _combine(ys.astype(x.dtype), row_token, slot_row)
 
 
-def _add_tail(y, x, top_w, weights, tail, need_tail):
-    """``y`` plus the tail's ``_buffer_part`` where ``need_tail``; the
-    other branch hands ``y`` back."""
+# The tail runs in pieces of at most this many times the head's rows,
+# one after the other (``lax.fori_loop``): the compiler plans a
+# conditional's branch whether or not a step takes it, and a tail of 9
+# heads (8 of 320 experts held: 58,880 rows of 4,096) planned 4.9 GB
+# that no step but the rare one touches. A tail of up to 4 heads (8 of
+# 128 held: 3) is one piece, the program it was before there were pieces.
+TAIL_PIECE_HEADS = 4
+
+
+def _tail_pieces(n_head: int, n_tail: int):
+    """``(pieces, rows of each)`` of a tail of ``n_tail`` rows behind a
+    head of ``n_head``: equal pieces of whole tiles."""
+    pieces = -(-n_tail // (TAIL_PIECE_HEADS * n_head))
+    if pieces == 1:
+        return 1, n_tail
+    return pieces, -(-n_tail // (pieces * ROW_TILE)) * ROW_TILE
+
+
+def _over_the_tail(step, carry, head, tail):
+    """``carry = step(carry, part)`` for every piece of ``tail`` in turn,
+    each piece a part of its own as ``split_buffer`` cuts one (``head``
+    only says how large a piece may be)."""
+    row_token, row_slot, row_valid, slot_row, group_sizes = tail
+    n_tail = row_token.shape[0]
+    pieces, rows = _tail_pieces(head[0].shape[0], n_tail)
+    if pieces == 1:
+        return step(carry, tail)
+    pad = lambda t: jnp.pad(t, (0, pieces * rows - n_tail))  # noqa: E731
+    row_token, row_slot, row_valid = map(pad, (row_token, row_slot, row_valid))
+    ends = jnp.cumsum(group_sizes)
+
+    def piece(i, carry):
+        lo = i * rows
+        hi = jnp.minimum(lo + rows, n_tail)
+        cut = lambda t: jax.lax.dynamic_slice(t, (lo,), (rows,))  # noqa: E731
+        mine = (slot_row >= lo) & (slot_row < hi)
+        return step(carry, (
+            cut(row_token), cut(row_slot), cut(row_valid),
+            jnp.where(mine, slot_row - lo, rows),
+            jnp.clip(ends, lo, hi) - jnp.clip(ends - group_sizes, lo, hi),
+        ))
+
+    return jax.lax.fori_loop(0, pieces, piece, carry)
+
+
+def _add_tail(y, x, top_w, weights, head, tail, need_tail):
+    """``y`` plus the tail's ``_buffer_part``, piece by piece, where
+    ``need_tail``; the other branch hands ``y`` back."""
     return jax.lax.cond(
         need_tail,
-        lambda y: y + _buffer_part(x, top_w, weights, tail),
+        lambda y: _over_the_tail(
+            lambda y, part: y + _buffer_part(x, top_w, weights, part),
+            y, head, tail,
+        ),
         lambda y: y,
         y,
     )
@@ -429,7 +477,7 @@ def _head_and_tail(x, top_w, weights, head, tail, need_tail):
     """``_buffer_part`` of the head, plus that of the tail where
     ``need_tail``."""
     y = _buffer_part(x, top_w, weights, head)
-    return _add_tail(y, x, top_w, weights, tail, need_tail)
+    return _add_tail(y, x, top_w, weights, head, tail, need_tail)
 
 
 def _head_and_tail_fwd(x, top_w, weights, head, tail, need_tail):
@@ -437,19 +485,22 @@ def _head_and_tail_fwd(x, top_w, weights, head, tail, need_tail):
         lambda *a: _buffer_part(*a, head), x, top_w, weights
     )
     # of the tail only its inputs: the backward pass computes it again
-    return _add_tail(y, x, top_w, weights, tail, need_tail), (
-        head_vjp, x, top_w, weights, tail, need_tail
+    return _add_tail(y, x, top_w, weights, head, tail, need_tail), (
+        head_vjp, x, top_w, weights, head, tail, need_tail
     )
 
 
 def _head_and_tail_bwd(res, g):
-    head_vjp, x, top_w, weights, tail, need_tail = res
+    head_vjp, x, top_w, weights, head, tail, need_tail = res
+
+    def add_a_piece(grads, part):
+        part_vjp = jax.vjp(
+            lambda *a: _buffer_part(*a, part), x, top_w, weights
+        )[1]
+        return jax.tree.map(jnp.add, grads, part_vjp(g))
 
     def add_the_tails(grads):
-        tail_vjp = jax.vjp(
-            lambda *a: _buffer_part(*a, tail), x, top_w, weights
-        )[1]
-        return jax.tree.map(jnp.add, grads, tail_vjp(g))
+        return _over_the_tail(add_a_piece, grads, head, tail)
 
     grads = jax.lax.cond(
         need_tail, add_the_tails, lambda grads: grads, head_vjp(g)

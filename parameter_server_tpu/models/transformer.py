@@ -25,16 +25,24 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from . import kda as kdalib
 from . import latent_attention as latent
 from .attention import ring_attention, ulysses_attention
+from .kda import KDAConfig
 from .latent_attention import MLAConfig, rms_norm
 from .moe import (
     MOE_COUNTS, TOP_E, TopKMoEConfig, init_moe, init_topk_moe, moe_ffn, swiglu,
     topk_moe_ffn,
 )
 
-ATTENTION_KINDS = ("mha", "mla")
+ATTENTION_KINDS = ("mha", "mla", "kda")
 FFN_KINDS = ("dense", "switch", "moe")
+# the stats of ``lm_forward_with_stats`` that are counts: they add up
+# over layers and steps, where the others are kept by layer and of the
+# last step. ``kda_scan_tokens``: token-layers the recurrence of the
+# "kda" layers computed (forward count: tokens x such layers)
+KDA_SCAN_TOKENS = "kda_scan_tokens"
+STEP_COUNTS = MOE_COUNTS + (KDA_SCAN_TOKENS,)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,8 +93,19 @@ class LMConfig:
     # grouped-query attention: K/V carry only this many heads, each
     # serving n_heads/n_kv_heads query heads (1 = MQA). Shrinks wk/wv
     # params AND the decode KV cache by the group factor — the cache is
-    # the dominant serving HBM traffic. None = n_heads (standard MHA)
+    # the dominant serving HBM traffic. None = n_heads (standard MHA).
+    # The training forward broadcasts K/V over their groups BEFORE the
+    # attention kernel (it keeps the parameter saving; the kernels see
+    # n_heads heads); only the decode path stays grouped
     n_kv_heads: "int | None" = None
+    # width of an "mha" head where it is not d_model / n_heads (wq and
+    # the gate [d_model, n_heads * head_dim], wo its transpose's shape);
+    # None = d_model // n_heads. Training only: the serving forwards
+    # refuse an explicit width by name
+    head_dim: "int | None" = None
+    # an "mha" layer's output gate: out = (att * sigmoid(h W_g)) W_o,
+    # W_g [d_model, n_heads * head_dim], elementwise. Training only
+    attn_gate: bool = False
     # rotary position embedding (RoFormer, Su et al. 2021): q/k head
     # vectors are rotated by position-dependent angles before attention,
     # so scores depend only on RELATIVE offsets — parameter-free and
@@ -128,9 +147,12 @@ class LMConfig:
     # dropless top-k layer, ``moe`` below). None = n_layers of "mha"
     # with every ``moe_every``-th FFN "switch". The serving forwards run
     # "mha" with "dense" or "switch" and refuse the others by name.
+    # Attention "kda" is the gated delta-rule linear-attention layer
+    # (models/kda.py, ``kda`` below): one chip's sequence only
     layers: "tuple | None" = None
     mla: "MLAConfig | None" = None
     moe: "TopKMoEConfig | None" = None
+    kda: "KDAConfig | None" = None
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -153,7 +175,7 @@ class LMConfig:
                 if att not in ATTENTION_KINDS or ffn not in FFN_KINDS:
                     raise ValueError(
                         f"LMConfig.layers: ({att!r}, {ffn!r}) is not one of "
-                        f"{ATTENTION_KINDS} x {FFN_KINDS}"
+                        f"attention {ATTENTION_KINDS} x FFN {FFN_KINDS}"
                     )
         kinds = self.layer_kinds
         if any(a == "mla" for a, _ in kinds):
@@ -164,6 +186,8 @@ class LMConfig:
                     "an 'mla' layer runs the ring schedules without a "
                     "window: a2a and sliding windows are not built for it"
                 )
+        if any(a == "kda" for a, _ in kinds) and self.kda is None:
+            raise ValueError("a 'kda' layer needs LMConfig.kda")
         if any(f == "moe" for _, f in kinds) and self.moe is None:
             raise ValueError("a 'moe' layer needs LMConfig.moe")
         if self.kv_cache_dtype not in (None, "int8"):
@@ -205,15 +229,22 @@ class LMConfig:
                     f"n_kv_heads={self.n_kv_heads} (each K/V head serves "
                     "an equal group of query heads)"
                 )
-        if self.rope and (self.d_model // self.n_heads) % 2:
+        if self.rope and self.head_width % 2:
             raise ValueError(
                 f"LMConfig.rope pairs head dimensions: head_dim="
-                f"{self.d_model // self.n_heads} must be even"
+                f"{self.head_width} must be even"
             )
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
+
+    @property
+    def head_width(self) -> int:
+        """Width of an "mha" head."""
+        if self.head_dim is not None:
+            return self.head_dim
+        return self.d_model // self.n_heads
 
     @property
     def layer_kinds(self) -> tuple:
@@ -227,10 +258,19 @@ class LMConfig:
 
 
 def refuse_serving(cfg: LMConfig, where: str) -> None:
-    """The cached forwards compute "mha" attention over a K/V cache and
-    a dense or switch FFN. A layer of another kind is refused by name:
-    decoding it through those would be a different model."""
+    """The cached forwards compute "mha" attention (heads of d_model /
+    n_heads, no output gate) over a K/V cache and a dense or switch FFN.
+    A layer of another kind ("mla", "kda", "moe"), an explicit head
+    width or an output gate is refused by name: decoding it through
+    those would be a different model."""
     for att, ffn in cfg.layer_kinds:
+        if att == "kda":
+            raise NotImplementedError(
+                f"{where}: the gated delta-rule layer ('kda') has no "
+                "decode path yet (its recurrent state and convolution "
+                "tail are not carried beside a K/V cache); this model "
+                "trains through lm_forward only"
+            )
         if att == "mla":
             raise NotImplementedError(
                 f"{where}: latent attention ('mla') has no decode cache "
@@ -243,6 +283,12 @@ def refuse_serving(cfg: LMConfig, where: str) -> None:
                 "TopKMoEConfig) is not built into the serving forwards; "
                 "this model trains through lm_forward only"
             )
+    if cfg.attn_gate or cfg.head_width * cfg.n_heads != cfg.d_model:
+        raise NotImplementedError(
+            f"{where}: the serving forwards compute heads of d_model / "
+            "n_heads without an output gate; LMConfig.head_dim / "
+            "attn_gate train through lm_forward only"
+        )
     if cfg.norm != "layernorm" or cfg.ffn_act != "gelu" or not (
         cfg.tie_head and cfg.scale_emb
     ):
@@ -271,23 +317,30 @@ def init_lm(key: jax.Array, cfg: LMConfig) -> Dict[str, jax.Array]:
                 k1, cfg.d_model, cfg.n_heads, cfg.mla, s
             ).items():
                 p[f"l{i}/{name}"] = w
+        elif att_kind == "kda":
+            for name, w in kdalib.init_kda(
+                k1, cfg.d_model, cfg.kda, s
+            ).items():
+                p[f"l{i}/{name}"] = w
         else:
             # separate q/k/v projections (not a fused [d, 3d]): under
             # tensor parallelism each projection column-shards on its
             # own, so the qkv split boundaries stay shard-local (the
             # fused-QKV TP pitfall puts K across two shards and forces
-            # per-layer reshards)
-            wqkv = s * jax.random.normal(k1, (cfg.d_model, 3 * cfg.d_model))
-            p[f"l{i}/wq"], p[f"l{i}/wk"], p[f"l{i}/wv"] = jnp.split(
-                wqkv, 3, axis=1
-            )
-            if cfg.kv_heads != cfg.n_heads:  # GQA: narrow K/V projections
-                kv_w = cfg.kv_heads * (cfg.d_model // cfg.n_heads)
-                p[f"l{i}/wk"] = p[f"l{i}/wk"][:, :kv_w]
-                p[f"l{i}/wv"] = p[f"l{i}/wv"][:, :kv_w]
-            p[f"l{i}/wo"] = s * jax.random.normal(
-                k2, (cfg.d_model, cfg.d_model)
-            )
+            # per-layer reshards). wq, the gate and wo's rows are
+            # n_heads * head_width wide (d_model unless ``head_dim`` says
+            # otherwise), wk and wv kv_heads * head_width
+            wide = cfg.n_heads * cfg.head_width
+            narrow = cfg.kv_heads * cfg.head_width  # GQA: narrow K/V
+            wqkv = s * jax.random.normal(k1, (cfg.d_model, 3 * wide))
+            wq, wk, wv = jnp.split(wqkv, 3, axis=1)
+            p[f"l{i}/wq"] = wq
+            p[f"l{i}/wk"], p[f"l{i}/wv"] = wk[:, :narrow], wv[:, :narrow]
+            p[f"l{i}/wo"] = s * jax.random.normal(k2, (wide, cfg.d_model))
+            if cfg.attn_gate:  # a key of its own: the four above as ever
+                p[f"l{i}/wg"] = s * jax.random.normal(
+                    jax.random.fold_in(k2, 1), (cfg.d_model, wide)
+                )
         if ffn_kind == "switch":
             moe = init_moe(k3, cfg.d_model, cfg.d_ff, cfg.n_experts)
             p[f"l{i}/moe_router"] = moe["router"]
@@ -440,16 +493,29 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
     and ``buffer_passes`` [2] int32 (passes over the sorted buffer's
     head, and how many of them needed its tail), stacked by layer
     ``top_e`` [layers, T, k] (the experts each token chose) and the
-    router probes ``probe_x``, ``probe_e``, ``probe_w``;
-    empty for a model with no such layer.
+    router probes ``probe_x``, ``probe_e``, ``probe_w``; none of these
+    for a model with no such layer. With "kda" layers also
+    ``kda_scan_tokens`` (int32: tokens x such layers, what their
+    recurrence computed in this forward pass) and, stacked by such
+    layer, ``probe_kda_state`` and ``probe_kda_g`` (models/kda.py: the
+    recurrence's last state and log-decay, in the bits it computed with).
 
-    Named scopes, for the device trace: ``lm_attn`` (projections and
-    attention), ``lm_ffn`` or ``lm_moe_*`` (models/moe.py), ``lm_head``.
+    Named scopes, for the device trace: ``lm_attn`` (an "mha" or "mla"
+    layer's projections and attention), ``lm_kda_*`` (models/kda.py),
+    ``lm_ffn`` or ``lm_moe_*`` (models/moe.py), ``lm_head``.
     """
     b, s = tokens.shape
-    hd = cfg.d_model // cfg.n_heads
+    hd = cfg.head_width
+    width = cfg.n_heads * hd  # of q, the gate and the attention's output
     dtype = jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else jnp.float32
     kinds = cfg.layer_kinds
+    n_kda = sum(a == "kda" for a, _ in kinds)
+    if n_kda and mesh.shape[axis] > 1:
+        raise NotImplementedError(
+            "a 'kda' layer over a sequence-sharded mesh (its state handed "
+            f"from chip to chip) is not built: the {axis!r} axis has "
+            f"{mesh.shape[axis]} devices"
+        )
     if cfg.norm == "rmsnorm":
         norm = functools.partial(rms_norm, eps=cfg.norm_eps)
     else:
@@ -490,21 +556,21 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
         if cfg.rope:  # rotate BEFORE the GQA broadcast: k is still narrow
             q = _rotate(
                 q.reshape(b, s, cfg.n_heads, hd), *rope_cs
-            ).reshape(b, s, cfg.d_model)
+            ).reshape(b, s, width)
             k = _rotate(
                 k.reshape(b, s, cfg.kv_heads, hd), *rope_cs
             ).reshape(b, s, cfg.kv_heads * hd)
         if cfg.kv_heads != cfg.n_heads:
             # GQA: broadcast each K/V head over its query-head group up
             # front; every attention schedule below then sees full-width
-            # [B, S, d] (training keeps the PARAM saving; the cache
-            # saving is the decode path's, which stays grouped)
+            # [B, S, n_heads * hd] (training keeps the PARAM saving; the
+            # cache saving is the decode path's, which stays grouped)
             def expand(t):
                 t = t.reshape(b, s, cfg.kv_heads, 1, hd)
                 t = jnp.broadcast_to(
                     t, (b, s, cfg.kv_heads, cfg.n_heads // cfg.kv_heads, hd)
                 )
-                return t.reshape(b, s, cfg.d_model)
+                return t.reshape(b, s, width)
 
             k = expand(k)
             v = expand(v)
@@ -515,19 +581,28 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
 
         if cfg.attention == "a2a":
             # Ulysses: q/k/v stay [B, S, d]; the layer splits heads itself
-            return ulysses_attention(
+            return gated(ulysses_attention(
                 q, k, v, mesh=mesh, axis=axis, n_heads=cfg.n_heads,
                 causal=True,
-            )
+            ), h, lp)
         att = ring_attention(
             heads(q), heads(k), heads(v), mesh=mesh, axis=axis,
             causal=True, impl=impl, window=cfg.window,
         )
-        return (
+        att = (
             att.reshape(b, cfg.n_heads, s, hd)
             .transpose(0, 2, 1, 3)
-            .reshape(b, s, cfg.d_model)
+            .reshape(b, s, width)
         )
+        return gated(att, h, lp)
+
+    def gated(att, h, lp):
+        if not cfg.attn_gate:
+            return att
+        gate = jax.nn.sigmoid(jnp.dot(
+            h, lp["wg"].astype(dtype), preferred_element_type=jnp.float32
+        ))
+        return (att.astype(jnp.float32) * gate).astype(dtype)
 
     def mla(h, lp):
         m = cfg.mla
@@ -546,10 +621,18 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
     def layer(x, lp, att_kind, ffn_kind):
         cast = lambda k: lp[k].astype(dtype)  # noqa: E731
         stats = {}
-        with jax.named_scope("lm_attn"):
-            h = norm(x, lp["ln1"])
-            att = mla(h, lp) if att_kind == "mla" else mha(h, lp)
-            x = x + att.astype(dtype) @ cast("wo")
+        if att_kind == "kda":
+            with jax.named_scope("lm_kda_proj"):
+                h = norm(x, lp["ln1"])
+            att, stats = kdalib.kda_attention(
+                h, lp, cfg.kda, cfg.norm_eps, dtype
+            )
+            x = x + att
+        else:
+            with jax.named_scope("lm_attn"):
+                h = norm(x, lp["ln1"])
+                att = mla(h, lp) if att_kind == "mla" else mha(h, lp)
+                x = x + att.astype(dtype) @ cast("wo")
         h2 = norm(x, lp["ln2"])
         if ffn_kind == "switch":
             moe_p = {
@@ -565,8 +648,9 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
                     capacity_factor=cfg.capacity_factor,
                 ).astype(dtype)
         elif ffn_kind == "moe":
-            y, stats = topk_moe_ffn(lp, h2, cfg.moe, dtype)
+            y, moe_stats = topk_moe_ffn(lp, h2, cfg.moe, dtype)
             x = x + y
+            stats = {**stats, **moe_stats}
         else:
             with jax.named_scope("lm_ffn"):
                 if cfg.ffn_act == "swiglu":
@@ -605,12 +689,11 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
         if stats:
             per_layer.append(stats)
     total = {}
-    if per_layer:
-        total = {
-            k: sum(s[k] for s in per_layer) if k in MOE_COUNTS
-            else jnp.stack([s[k] for s in per_layer])
-            for k in per_layer[0]
-        }
+    for k in sorted({k for stats in per_layer for k in stats}):
+        have = [stats[k] for stats in per_layer if k in stats]
+        total[k] = sum(have) if k in MOE_COUNTS else jnp.stack(have)
+    if n_kda:
+        total[KDA_SCAN_TOKENS] = jnp.asarray(b * s * n_kda, jnp.int32)
     with jax.named_scope("lm_head"):
         xn = norm(x.astype(jnp.float32), params["ln_f"])
         if cfg.tie_head:

@@ -999,6 +999,12 @@ def lm_instruments(reg: MetricsRegistry) -> Dict[str, object]:
             "head, which then cost more than the whole buffer did",
             labelnames=("part",),
         ),
+        "kda_scan_tokens": reg.ensure_counter(
+            "ps_lm_kda_scan_tokens_total",
+            "token-layers the recurrence of the gated delta-rule ('kda') "
+            "layers computed (forward count: tokens x such layers, "
+            "whatever the chunk of the scan)",
+        ),
     }
 
 

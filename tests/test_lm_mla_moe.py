@@ -653,6 +653,7 @@ def _serving_calls():
 ])
 @pytest.mark.parametrize("kind,word", [
     ("mla", "latent attention"), ("moe", "top-k expert layer"),
+    ("kda", "gated delta-rule layer"),
 ])
 def test_serving_entry_points_refuse_the_layer_kind_by_name(
     setup, entry, kind, word
@@ -662,6 +663,14 @@ def test_serving_entry_points_refuse_the_layer_kind_by_name(
         cfg = tfm.LMConfig(
             vocab=512, d_model=64, n_heads=4, n_layers=1,
             layers=(("mha", "moe"),), moe=cfg.moe,
+        )
+    if kind == "kda":  # a dense FFN under the linear-attention layer
+        from parameter_server_tpu.models.kda import KDAConfig
+
+        cfg = tfm.LMConfig(
+            vocab=512, d_model=64, n_heads=4, n_layers=1,
+            layers=(("kda", "dense"),),
+            kda=KDAConfig(n_heads=4, head_dim=16, gate_rank=8),
         )
     with pytest.raises(NotImplementedError, match=word):
         _serving_calls()[entry](params, cfg)
@@ -732,7 +741,7 @@ def test_a_gated_silu_ffn_under_rmsnorm_and_an_untied_head_trains():
 
 
 @pytest.mark.parametrize("over,message", [
-    ({"model_type": "deepseek_v3"}, "only mistral4"),
+    ({"model_type": "deepseek_v3"}, "described here are mistral4, solar_open2"),
     ({"first_k_dense_replace": 1}, "leading dense layers"),
     ({"n_group": 2}, "group-limited"),
     ({"hidden_act": "gelu"}, "gated SiLU"),
