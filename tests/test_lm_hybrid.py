@@ -173,29 +173,93 @@ def test_the_blocked_reference_is_the_plain_one(setup, both_grads):
     assert max(rel(grads[k], want_grads[k]) for k in LEAVES) < 1e-5
 
 
-def test_the_bf16_path_stays_within_its_tolerance(setup):
-    """bf16 matmul inputs and activations against the program in f32, at
-    the weights ``init_lm`` draws (sigma 0.02, the cell's): the loss
-    within 2e-3, every leaf's gradient within 5% in relative L2 (2.5%
-    read; the scan alone is 0.4% off) but the routers' and the routed
-    experts', within 20% (14.3% read: with top-2 of 8 a choice that
-    flips on a rounded router input moves a token between experts, and
-    four layers of them feed each other). At the fixture's weights x 5
-    the flips cascade and every leaf reads 30%: a statement about toy
-    routers, not about bf16."""
+@pytest.fixture(scope="module")
+def bf16_grads(setup):
+    """``(params, the bf16 model, (loss, grads) in f32, the same in
+    bf16)`` at the weights ``init_lm`` draws (sigma 0.02, the cell's)."""
     desc, cfg, _, tokens = setup
     params = tfm.init_lm(jax.random.PRNGKey(0), cfg)
-    want, want_grads = loss_and_grads_of(cfg)(params, tokens)
-    cfg = lm_trainer.model_from_description(desc, bf16=True, remat=True)
-    loss, grads = loss_and_grads_of(cfg)(params, tokens)
+    low = lm_trainer.model_from_description(desc, bf16=True, remat=True)
+    return (
+        params, low, loss_and_grads_of(cfg)(params, tokens),
+        loss_and_grads_of(low)(params, tokens),
+    )
+
+
+def routed(k: str) -> bool:
+    return "/we_" in k or "router" in k or k.endswith("ln2")
+
+
+def test_the_bf16_path_stays_within_its_tolerance(bf16_grads):
+    """bf16 matmul inputs and activations against the program in f32:
+    the loss within 2e-3, every leaf's gradient within 5% in relative L2
+    (most read 2.5-4%) but the routers' and the routed experts', within
+    20% (17.1% read: with top-2 of 8 a choice that flips on a rounded
+    router input moves a token between experts, and four layers of them
+    feed each other), and ``a_log``, within 5.5%: four numbers a layer
+    that each sum a head's whole log-decay gradient, 5.15% read in the
+    last layer. That reading is the FORWARD's rounding, not the
+    backward rule's (the test below: the same forward differentiated in
+    f32 reads 5.17%); PR 33's autodiff read 4.88% and sat under 5% by
+    where its own roundings fell. At the fixture's weights x 5 the flips
+    cascade and every leaf reads 30%: a statement about toy routers, not
+    about bf16."""
+    _, _, (want, want_grads), (loss, grads) = bf16_grads
     assert abs(float(loss) - float(want)) < 2e-3
     worst = {k: rel(grads[k], want_grads[k]) for k in LEAVES}
-    routed = {
-        k: v for k, v in worst.items()
-        if "/we_" in k or "router" in k or k.endswith("ln2")
-    }
-    assert max(routed.values()) < 0.2, routed
-    assert max(v for k, v in worst.items() if k not in routed) < 0.05, worst
+    assert max(v for k, v in worst.items() if routed(k)) < 0.2, worst
+    a_log = {k: v for k, v in worst.items() if k.endswith("a_log")}
+    assert len(a_log) == 3 and max(a_log.values()) < 0.055, a_log
+    assert max(
+        v for k, v in worst.items() if not routed(k) and k not in a_log
+    ) < 0.05, worst
+
+
+def test_the_rule_in_bf16_rounds_no_more_than_autodiff_did(
+    setup, bf16_grads, monkeypatch
+):
+    """What the backward rule's own bf16 costs, apart from the
+    forward's: the same bf16 forward differentiated (a) by the rule run
+    in f32, the chunks' matrices it computes again and every product,
+    and (b) by autodiff, as PR 33 did. The rule as it runs is within
+    1.5% of (a) in every leaf (1.31% read) and no further from it than
+    autodiff is (1.45%). And (a) itself reads 5.17% on the last layer's
+    ``a_log`` against the f32 program: no backward of this forward meets
+    the 5% the other leaves are held to. When that line fails the
+    forward has become more exact: hold ``a_log`` to 5% again."""
+    _, _, _, tokens = setup
+    params, low, (_, f32_grads), (_, rule) = bf16_grads
+
+    def by_autodiff(q, k, v, g, beta, *, chunk, dtype):
+        return kda_op._forward(q, k, v, g, beta, chunk, jnp.dtype(dtype))
+
+    def in_f32(q, k, v, g, beta, *, chunk, dtype):
+        forward = lambda *a: by_autodiff(  # noqa: E731
+            *a, chunk=chunk, dtype=dtype
+        )
+        fn = jax.custom_vjp(forward)
+        fn.defvjp(
+            lambda *a: (forward(*a), a),
+            lambda a, ct: kda_op._kda_bwd(
+                chunk, jnp.dtype(jnp.float32), a, ct
+            ),
+        )
+        return fn(q, k, v, g, beta)
+
+    monkeypatch.setattr(kdalib, "kda_chunked", in_f32)
+    _, exact = loss_and_grads_of(low)(params, tokens)
+    monkeypatch.setattr(kdalib, "kda_chunked", by_autodiff)
+    _, autodiff = loss_and_grads_of(low)(params, tokens)
+    off = lambda grads: max(  # noqa: E731
+        rel(grads[k], exact[k]) for k in LEAVES if not routed(k)
+    )
+    assert 0.002 < off(rule) < 0.015
+    assert off(rule) < 1.05 * off(autodiff)
+    last = "l3/a_log"
+    assert rel(exact[last], f32_grads[last]) > 0.05
+    assert abs(
+        rel(rule[last], f32_grads[last]) - rel(exact[last], f32_grads[last])
+    ) < 0.001
 
 
 def test_the_step_returns_the_scans_token_layers(setup, monkeypatch):
@@ -241,14 +305,14 @@ def test_a_sequence_sharded_mesh_is_refused_by_name(setup):
 # -- the chunked scan against the recurrence ---------------------------------
 
 
-def scan_inputs(seed: int, s: int = 100, strength: float = 1.0):
+def scan_inputs(seed: int, s: int = 100, strength: float = 1.0, h: int = 3):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa
-    q = unit(jax.random.normal(ks[0], (2, s, 3, 16)))
-    k = unit(jax.random.normal(ks[1], (2, s, 3, 16)))
-    v = jax.random.normal(ks[2], (2, s, 3, 16))
-    g = -strength * jax.nn.softplus(jax.random.normal(ks[3], (2, s, 3, 16)))
-    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (2, s, 3)))
+    q = unit(jax.random.normal(ks[0], (2, s, h, 16)))
+    k = unit(jax.random.normal(ks[1], (2, s, h, 16)))
+    v = jax.random.normal(ks[2], (2, s, h, 16))
+    g = -strength * jax.nn.softplus(jax.random.normal(ks[3], (2, s, h, 16)))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (2, s, h)))
     return q, k, v, g, beta
 
 
@@ -308,6 +372,218 @@ def test_the_scan_takes_the_largest_divisor_of_the_heads(
         loops = str(jax.make_jaxpr(fn)(*args)).count("scan[")
     assert loops == (passes > 1) + 1  # over the passes, over the chunks
     assert rel(got, want) < 5e-6 and rel(state, want_state) < 5e-6
+
+
+# -- the scan's gradient, written by hand (ops/kda.py's custom_vjp) -----------
+
+INPUTS = "q k v g beta".split()
+
+
+def loss_of_both(fn):
+    """A loss of the outputs AND of the state after the last token."""
+    def loss(*a):
+        out, state = fn(*a)
+        return jnp.sum(jnp.sin(out)) + jnp.sum(jnp.cos(2.0 * state))
+    return loss
+
+
+def grads_of_both(fn, args):
+    return jax.jit(jax.grad(loss_of_both(fn), argnums=range(5)))(*args)
+
+
+def all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from all_eqns(sub)
+
+
+def scans_in(jaxpr) -> list:
+    """Every ``scan`` equation of a jaxpr, those inside others too."""
+    return [eqn for eqn in all_eqns(jaxpr) if eqn.primitive.name == "scan"]
+
+
+def over_the_chunks(scans) -> list:
+    """Those that carry a state [B, H, K, V]: ``(reverse, carry aval)``."""
+    out = []
+    for eqn in scans:
+        lo = eqn.params["num_consts"]
+        carry = [v.aval for v in eqn.invars[lo:lo + eqn.params["num_carry"]]]
+        if len(carry) == 1 and carry[0].ndim == 4:
+            out.append((eqn.params["reverse"], carry[0]))
+    return out
+
+
+@pytest.mark.parametrize("chunk,s", [(16, 128), (64, 100), (32, 40)],
+                         ids=["whole", "ragged", "ragged_below_two_chunks"])
+def test_a_cotangent_on_the_last_state_is_honoured(chunk, s):
+    args = scan_inputs(11 + s, s)
+    with jax.default_matmul_precision("highest"):
+        want = grads_of_both(kda_op.kda_recurrent, args)
+        got = grads_of_both(
+            lambda *a: kda_op.kda_chunked(*a, chunk=chunk), args
+        )
+        # the state's share alone: none of q's, and no rounding of the
+        # others'
+        state_only = jax.jit(jax.grad(
+            lambda *a: jnp.sum(jnp.cos(
+                2.0 * kda_op.kda_chunked(*a, chunk=chunk)[1]
+            )), argnums=range(5),
+        ))(*args)
+    for name, a, b, c in zip(INPUTS, got, want, state_only):
+        assert bool(jnp.isfinite(a).all()), name
+        assert rel(a, b) < 5e-5, name
+        share = float(jnp.linalg.norm(c)) / float(jnp.linalg.norm(b))
+        assert share == 0.0 if name == "q" else share > 0.01, name
+
+
+@pytest.mark.parametrize("heads,heads_per_pass,passes", [
+    (3, 1, 3), (3, 2, 3), (4, 2, 2),
+], ids=["one", "no_divisor", "two"])
+def test_the_gradient_in_passes_is_the_gradient(
+    heads, heads_per_pass, passes, monkeypatch
+):
+    args = scan_inputs(23, 100, h=heads)
+    fn = lambda *a: kda_op.kda_chunked(*a, chunk=16)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        want = grads_of_both(kda_op.kda_recurrent, args)
+        at_once = grads_of_both(lambda *a: fn(*a), args)
+        monkeypatch.setattr(kda_op, "HEADS_PER_PASS", heads_per_pass)
+        got = grads_of_both(lambda *a: fn(*a), args)
+        jaxpr = jax.make_jaxpr(
+            jax.grad(loss_of_both(lambda *a: fn(*a)), argnums=range(5))
+        )(*args)
+    # a loop over the passes in each rule, and in them a pass's scans
+    # over its chunks: one forward, and forward again and in reverse
+    scans = scans_in(jaxpr.jaxpr)
+    assert len(scans) == 5
+    assert [r for r, _ in over_the_chunks(scans)] == [False, False, True]
+    for name, a, b, c in zip(INPUTS, got, want, at_once):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert rel(a, b) < 5e-5, name
+        assert rel(a, c) < 2e-6, name
+
+
+def test_the_gradient_in_bf16_is_near_the_f32_one_and_its_state_is_f32():
+    """bf16 inputs and products: the gradients come back in their
+    inputs' types, finite, and within what bf16 products cost (measured
+    0.006-0.02 of the f32 gradient's norm); the forward's state and the
+    backward's dS are carried in f32 whatever the products read."""
+    args = scan_inputs(31, 128)
+    low = tuple(t.astype(jnp.bfloat16) for t in args)
+    fn = lambda *a: kda_op.kda_chunked(*a, chunk=32)  # noqa: E731
+    want = grads_of_both(fn, tuple(t.astype(jnp.float32) for t in low))
+    got = grads_of_both(lambda *a: fn(*a), low)
+    for name, a, b in zip(INPUTS, got, want):
+        assert a.dtype == jnp.bfloat16, name
+        assert bool(jnp.isfinite(a.astype(jnp.float32)).all()), name
+        assert rel(a.astype(jnp.float32), b) < 0.04, name
+    out, state = jax.jit(fn)(*low)
+    assert out.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+    jaxpr = jax.make_jaxpr(
+        jax.grad(loss_of_both(lambda *a: fn(*a)), argnums=range(5))
+    )(*low)
+    carried = over_the_chunks(scans_in(jaxpr.jaxpr))
+    assert [reverse for reverse, _ in carried] == [False, False, True]
+    for _, aval in carried:
+        assert aval.shape == (2, 3, 16, 16) and aval.dtype == jnp.float32
+
+
+def test_the_padded_tail_gets_no_gradient_and_leaves_ds_as_it_is():
+    """100 tokens in chunks of 16 are padded to 112. The same sequence
+    handed over padded to 128 by the caller (q, k, v, beta of zeros and
+    a log-decay of zero: tokens that leave the state as it is), its
+    last chunk nothing but padding: the real tokens get the gradient
+    they got, bit for bit where the arithmetic is the same (dS passes
+    the empty chunk as it is), and the padding gets exactly none. Its
+    log-decay alone gets what a decay of the state would change."""
+    s, padded = 100, 128
+    args = scan_inputs(41, s)
+    wide = tuple(
+        jnp.pad(t, ((0, 0), (0, padded - s)) + ((0, 0),) * (t.ndim - 2))
+        for t in args
+    )
+    fn = lambda *a: kda_op.kda_chunked(*a, chunk=16)  # noqa: E731
+
+    def real_tokens_only(*a):
+        out, state = fn(*a)
+        return out[:, :s], state
+
+    with jax.default_matmul_precision("highest"):
+        want = grads_of_both(fn, args)
+        got = grads_of_both(real_tokens_only, wide)
+    for name, a, b in zip(INPUTS, got, want):
+        assert rel(a[:, :s], b) < 1e-6, name
+        if name != "g":
+            assert float(jnp.max(jnp.abs(a[:, s:]))) == 0.0, name
+    # one value for all the padding of a head's channel: the sum of dS * S
+    tail = got[3][:, s:]
+    assert float(jnp.max(jnp.abs(tail))) > 0.0
+    assert float(jnp.max(jnp.abs(tail - tail[:, :1]))) < 1e-5
+
+
+def test_the_gradient_is_the_rule_written_by_hand():
+    """Autodiff does not see the inside of the recurrence: the forward
+    is one ``custom_vjp`` call, and its gradient holds the forward
+    rule's scan over the chunks and the backward rule's two (forward
+    again for the state each chunk starts from, then in reverse with
+    dS), no rematerialised region and no loop transposed: the chunk's
+    inverse is substituted forward in each rule and its gradient is two
+    products."""
+    args = scan_inputs(3, 64)
+    fn = lambda *a: kda_op.kda_chunked(*a, chunk=16)  # noqa: E731
+    forward = jax.make_jaxpr(fn)(*args)
+    assert str(forward).count("custom_vjp_call") == 1
+    grad = jax.make_jaxpr(
+        jax.grad(loss_of_both(fn), argnums=range(5))
+    )(*args)
+    assert "custom_vjp_call" not in str(grad)  # taken by its rules
+    assert "checkpoint" not in str(grad) and "remat" not in str(grad)
+    scans = scans_in(grad.jaxpr)
+    assert str(grad).count("scan[") == len(scans) == 3
+    assert [r for r, _ in over_the_chunks(scans)] == [False, False, True]
+    # SUB - 1 substitution steps a rule; autodiff's transpose of them
+    # would be as many again, twice over
+    steps = lambda jaxpr: sum(  # noqa: E731
+        eqn.primitive.name == "dot_general"
+        and eqn.outvars[0].aval.ndim == eqn.invars[0].aval.ndim
+        and eqn.invars[1].aval.ndim == eqn.invars[0].aval.ndim + 1
+        for eqn in all_eqns(jaxpr)
+    )
+    assert steps(forward.jaxpr) == kda_op.SUB - 1
+    assert steps(grad.jaxpr) == 2 * (kda_op.SUB - 1)
+
+
+def test_a_capture_splits_by_phase_of_the_step():
+    """``script/lm_scope_split.py`` on the benchmark's recorded capture
+    (PR 33's program: each pass rematerialised inside the layer): the
+    three phases are the whole of the scope, and the recomputation, the
+    layer's and the passes', is no part of the backward's."""
+    import importlib.util
+
+    from chipbench import trace
+    from chipbench.readers import lm_common
+
+    spec = importlib.util.spec_from_file_location(
+        "lm_scope_split", os.path.join(ROOT, "script", "lm_scope_split.py")
+    )
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    tr = trace.load(os.path.join(
+        ROOT, "chipbench", "fixtures",
+        "solar_open2_ep40.packed8k_mb1.trace.json.gz",
+    ))
+    steps = lm_common.step_seconds_and_count(tr)[1]
+    out = tool.split(tr, steps, "lm_kda_scan")
+    assert list(out) == ["forward", "recomputation", "backward"]
+    total = 1e3 * sum(
+        o.self_s for ops in tr.ops.values() for o in ops
+        if "lm_kda_scan" in o.scope
+    ) / steps
+    assert abs(sum(out.values()) - total) < 1e-6 * total
+    assert out["recomputation"] > out["forward"] > 0
+    assert out["backward"] > 0
+    assert sum(tool.split(tr, steps, "no_such_scope").values()) == 0.0
 
 
 def test_the_scan_refuses_a_chunk_that_is_no_power_of_two():
