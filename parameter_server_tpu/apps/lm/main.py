@@ -18,12 +18,14 @@ runs anywhere.
 
 ``--model-config FILE`` takes the model from a description file instead
 of the width flags (``trainer.model_from_description``: a ``mistral4``
-description's latent attention or a ``solar_open2`` description's gated
-delta-rule and gated GQA layers, a dropless top-k expert layer beside a
-shared expert, RMSNorm, an untied head over the file's vocabulary); bytes
-are then ids below 256 of that vocabulary. Such a model trains here; the
-generation flags refuse it by name, because the serving forwards have no
-latent cache and carry no recurrent state.
+description's latent attention, a ``solar_open2`` description's gated
+delta-rule and gated GQA layers or a ``mellum`` description's windowed
+and full GQA layers with rotary tables per kind, a dropless top-k expert
+layer with or without a shared expert, RMSNorm, an untied head over the
+file's vocabulary); bytes are then ids below 256 of that vocabulary.
+Such a model trains here; the generation flags refuse it by name,
+because the serving forwards have no latent cache, carry no recurrent
+state and read one window and one rotary table for every layer.
 """
 
 from __future__ import annotations

@@ -39,10 +39,12 @@ def load_description(path: str) -> dict:
 def model_from_description(desc: dict, *, attention: str = "ring_flash",
                            remat: bool = False, bf16: bool = False):
     """An ``LMConfig`` for a description of one of ``MODEL_TYPES``, each
-    with a top-k expert layer beside shared experts in every layer,
-    RMSNorm and an untied head: ``mistral4`` (latent attention) or
-    ``solar_open2`` (gated NoPE GQA layers at ``gqa_layers``, gated
-    delta-rule layers, "kda", everywhere else)."""
+    with a top-k expert layer in every layer, RMSNorm and an untied
+    head: ``mistral4`` (latent attention, shared experts beside the
+    routed), ``solar_open2`` (gated NoPE GQA layers at ``gqa_layers``,
+    gated delta-rule layers, "kda", everywhere else; shared experts) or
+    ``mellum`` (rotated GQA layers with a q/k norm, windowed or full by
+    ``layer_types``, rotary tables per kind; no shared expert)."""
     from ...models.moe import TopKMoEConfig
 
     kind = desc.get("model_type")
@@ -51,10 +53,13 @@ def model_from_description(desc: dict, *, attention: str = "ring_flash",
             f"model_type {kind!r}: the model types described here are "
             f"{', '.join(MODEL_TYPES)}"
         )
-    if desc.get("hidden_act", "silu") != "silu" or desc.get(
-        "attention_bias"
-    ) or desc.get("mlp_bias"):
+    if desc.get("hidden_act", "silu") != "silu":
         raise ValueError("the layer is a gated SiLU FFN without biases")
+    for key in ("attention_bias", "mlp_bias"):
+        if desc.get(key):
+            raise ValueError(
+                f"{key} true: the layers are built without biases"
+            )
     if desc.get("n_group", 1) != 1 or desc.get("topk_group", 1) != 1:
         raise ValueError("group-limited routing is not built")
     if desc.get("first_k_dense_replace", 0) != 0:
@@ -63,6 +68,9 @@ def model_from_description(desc: dict, *, attention: str = "ring_flash",
             "described here"
         )
     published, share = desc.get("published", {}), desc.get("share", {})
+    # the experts held here and the router's width, under the family's
+    # key: ``n_routed_experts``, or ``num_experts`` (mellum)
+    experts = "num_experts" if kind == "mellum" else "n_routed_experts"
     common = dict(
         vocab=desc["vocab_size"], d_model=desc["hidden_size"],
         n_heads=desc["num_attention_heads"],
@@ -72,36 +80,50 @@ def model_from_description(desc: dict, *, attention: str = "ring_flash",
         tie_head=desc["tie_word_embeddings"], norm="rmsnorm",
         norm_eps=desc["rms_norm_eps"], ffn_act="swiglu", scale_emb=False,
         moe=TopKMoEConfig(
-            n_experts=published.get(
-                "n_routed_experts", desc["n_routed_experts"]
-            ),
+            n_experts=published.get(experts, desc[experts]),
             top_k=desc["num_experts_per_tok"],
             d_expert=desc["moe_intermediate_size"],
-            n_shared=desc["n_shared_experts"],
-            experts_held=desc["n_routed_experts"],
+            n_shared=desc.get("n_shared_experts", 0),
+            experts_held=desc[experts],
             expert_offset=share.get("expert_offset", 0),
             norm_topk_prob=desc["norm_topk_prob"],
-            routed_scaling_factor=float(desc["routed_scaling_factor"]),
+            routed_scaling_factor=float(
+                desc.get("routed_scaling_factor", 1.0)
+            ),
         ),
     )
     return _ATTENTION_OF[kind](desc, common)
 
 
+def _yarn(rp: dict):
+    """A ``rope_parameters`` block's ``YarnRope``; None for a plain
+    table. Another ``rope_type`` is refused by name."""
+    from ...models.latent_attention import YarnRope
+
+    rope_type = rp.get("rope_type", rp.get("type", "default"))
+    if rope_type == "default":
+        return None
+    if rope_type != "yarn":
+        raise ValueError(
+            f"rope_type {rope_type!r}: rotary tables are built plain "
+            "('default') or under 'yarn'"
+        )
+    return YarnRope(
+        factor=rp["factor"],
+        original_max_position=rp["original_max_position_embeddings"],
+        beta_fast=rp["beta_fast"], beta_slow=rp["beta_slow"],
+        mscale=rp.get("mscale", 1.0),
+        mscale_all_dim=rp.get("mscale_all_dim", 0.0),
+        position_scale_beta=rp.get("llama_4_scaling_beta", 0.0),
+        attention_factor=rp.get("attention_factor"),
+    )
+
+
 def _mistral4(desc: dict, common: dict):
-    from ...models.latent_attention import MLAConfig, YarnRope
+    from ...models.latent_attention import MLAConfig
     from ...models.transformer import LMConfig
 
     rp = desc["rope_parameters"]
-    yarn = None
-    if rp.get("rope_type", rp.get("type")) == "yarn":
-        yarn = YarnRope(
-            factor=rp["factor"],
-            original_max_position=rp["original_max_position_embeddings"],
-            beta_fast=rp["beta_fast"], beta_slow=rp["beta_slow"],
-            mscale=rp.get("mscale", 1.0),
-            mscale_all_dim=rp.get("mscale_all_dim", 0.0),
-            position_scale_beta=rp.get("llama_4_scaling_beta", 0.0),
-        )
     return LMConfig(
         **common, rope_theta=rp["rope_theta"],
         layers=(("mla", "moe"),) * common["n_layers"],
@@ -110,7 +132,7 @@ def _mistral4(desc: dict, common: dict):
             qk_nope_head_dim=desc["qk_nope_head_dim"],
             qk_rope_head_dim=desc["qk_rope_head_dim"],
             v_head_dim=desc["v_head_dim"],
-            rope_interleave=desc["rope_interleave"], yarn=yarn,
+            rope_interleave=desc["rope_interleave"], yarn=_yarn(rp),
         ),
     )
 
@@ -160,7 +182,63 @@ def _solar_open2(desc: dict, common: dict):
     )
 
 
-_ATTENTION_OF = {"mistral4": _mistral4, "solar_open2": _solar_open2}
+def _mellum(desc: dict, common: dict):
+    """Layer ``i`` is a GQA layer with a q/k norm, rotated, that sees
+    the last ``sliding_window`` keys where ``layer_types[i]`` is
+    ``sliding_attention`` ("swa") and all of them where it is
+    ``full_attention`` ("mha"), each kind with the rotary tables of
+    ``rope_parameters[<layer type>]``."""
+    from ...models.transformer import LMConfig, Rope
+
+    kinds = {"sliding_attention": "swa", "full_attention": "mha"}
+    types = desc["layer_types"]
+    ffns = desc.get("mlp_layer_types", ["sparse"] * len(types))
+    if len(types) != common["n_layers"] or len(ffns) != len(types):
+        raise ValueError(
+            f"layer_types / mlp_layer_types describe {len(types)} / "
+            f"{len(ffns)} layers, num_hidden_layers is {common['n_layers']}"
+        )
+    for name in types:
+        if name not in kinds:
+            raise ValueError(
+                f"layer_types: {name!r} is not one of {', '.join(kinds)}"
+            )
+    if set(ffns) != {"sparse"}:
+        raise ValueError(
+            f"mlp_layer_types {sorted(set(ffns))}: a mellum description's "
+            "layers are built with the expert layer ('sparse') only; a "
+            "'dense' entry is not built"
+        )
+    windowed = "sliding_attention" in types
+    if windowed and not desc.get("use_sliding_window", True):
+        raise ValueError(
+            "use_sliding_window false beside sliding_attention layers: "
+            "which of the two the model means is not described here"
+        )
+    if desc.get("tie_word_embeddings"):
+        raise ValueError(
+            "tie_word_embeddings true: a mellum description is built with "
+            "an untied head only"
+        )
+    rp = desc["rope_parameters"]
+    full = rp.get("full_attention", rp.get("sliding_attention"))
+    window = rp.get("sliding_attention")
+    swa_rope = None
+    if windowed and window != full:
+        swa_rope = Rope(float(window["rope_theta"]), _yarn(window))
+    return LMConfig(
+        **common, n_kv_heads=desc["num_key_value_heads"],
+        head_dim=desc["head_dim"], qk_norm=True, rope=True,
+        rope_theta=float(full["rope_theta"]), rope_yarn=_yarn(full),
+        swa_rope=swa_rope,
+        window=desc["sliding_window"] if windowed else None,
+        layers=tuple((kinds[name], "moe") for name in types),
+    )
+
+
+_ATTENTION_OF = {
+    "mistral4": _mistral4, "solar_open2": _solar_open2, "mellum": _mellum,
+}
 MODEL_TYPES = tuple(_ATTENTION_OF)
 
 
@@ -197,8 +275,8 @@ class Launch:
     loss: object  # device scalar: the launch's last step's loss
     # device arrays (``lm_forward_with_stats``): the counts
     # (``transformer.STEP_COUNTS``: ``expert_rows``, ``buffer_passes``,
-    # ``kda_scan_tokens``) summed over the launch's steps; its last
-    # step's choices and router probes
+    # ``kda_scan_tokens``, ``attn_token_layers``) summed over the
+    # launch's steps; its last step's choices and router probes
     stats: dict
     tokens: int
 
@@ -396,9 +474,15 @@ class Trainer:
         """``(loss, counts)`` of a launch on the host, counted: its
         ``expert_rows`` and ``buffer_passes`` where the model has the
         dropless layer, its ``kda_scan_tokens`` where it has the gated
-        delta-rule layer. The launch's choices and probes stay on the
-        device (``launch.stats``)."""
-        from ...models.transformer import KDA_SCAN_TOKENS, STEP_COUNTS
+        delta-rule layer, its ``attn_token_layers`` where it has
+        windowed layers beside full ones. The launch's choices and
+        probes stay on the device (``launch.stats``)."""
+        from ...models.transformer import (
+            ATTN_TOKEN_LAYER_KINDS,
+            ATTN_TOKEN_LAYERS,
+            KDA_SCAN_TOKENS,
+            STEP_COUNTS,
+        )
 
         with self.loop_phase("collect_wait"):
             loss = float(launch.loss)
@@ -413,6 +497,12 @@ class Trainer:
                     self._counters["kda_scan_tokens"].inc(
                         int(counts[KDA_SCAN_TOKENS])
                     )
+                for kind, n in zip(
+                    ATTN_TOKEN_LAYER_KINDS, counts.get(ATTN_TOKEN_LAYERS, ())
+                ):
+                    self._counters["attention_token_layers"].labels(
+                        kind=kind
+                    ).inc(int(n))
                 for j, n in enumerate(counts.get("expert_rows", ())):
                     self._counters["expert_rows"].labels(
                         expert=str(self.cfg.moe.expert_offset + j)

@@ -33,7 +33,10 @@ import numpy as np
 @dataclasses.dataclass(frozen=True)
 class YarnRope:
     """YaRN's change of the rotary frequencies (Peng et al. 2023), with
-    the keys of the DeepSeek-V3 family's ``rope_parameters``."""
+    the keys of the DeepSeek-V3 family's ``rope_parameters``. Latent
+    attention's rotary key and the "mha" / "swa" layers of
+    ``models/transformer.py`` read it through the same two functions,
+    ``rope_inv_freq`` and ``rope_attention_factor``."""
 
     factor: float
     original_max_position: int
@@ -44,6 +47,9 @@ class YarnRope:
     # the query at position p is scaled by 1 + beta ln(1 + floor(p /
     # original_max_position)) (``llama_4_scaling_beta``); 0 = off
     position_scale_beta: float = 0.0
+    # the factor on cos and sin where the description states it
+    # (``attention_factor``): used as given, in place of the mscales
+    attention_factor: Optional[float] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,11 +113,14 @@ def rope_inv_freq(dim: int, theta: float, yarn: Optional[YarnRope]):
 
 
 def rope_attention_factor(yarn: Optional[YarnRope]) -> float:
-    """The factor on cos and sin: mscale(factor, mscale) over
-    mscale(factor, mscale_all_dim) when both keys are set (1 when they
-    are equal), the plain mscale(factor) otherwise."""
+    """The factor on cos and sin: ``attention_factor`` where it is
+    stated; else mscale(factor, mscale) over mscale(factor,
+    mscale_all_dim) when both keys are set (1 when they are equal), the
+    plain mscale(factor) otherwise."""
     if yarn is None:
         return 1.0
+    if yarn.attention_factor is not None:
+        return yarn.attention_factor
     if yarn.mscale and yarn.mscale_all_dim:
         return _yarn_mscale(yarn.factor, yarn.mscale) / _yarn_mscale(
             yarn.factor, yarn.mscale_all_dim

@@ -16,6 +16,7 @@ axis, at exact-attention quality.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Dict, Tuple
@@ -29,20 +30,33 @@ from . import kda as kdalib
 from . import latent_attention as latent
 from .attention import ring_attention, ulysses_attention
 from .kda import KDAConfig
-from .latent_attention import MLAConfig, rms_norm
+from .latent_attention import MLAConfig, YarnRope, rms_norm
 from .moe import (
     MOE_COUNTS, TOP_E, TopKMoEConfig, init_moe, init_topk_moe, moe_ffn, swiglu,
     topk_moe_ffn,
 )
 
-ATTENTION_KINDS = ("mha", "mla", "kda")
+ATTENTION_KINDS = ("mha", "swa", "mla", "kda")
 FFN_KINDS = ("dense", "switch", "moe")
 # the stats of ``lm_forward_with_stats`` that are counts: they add up
 # over layers and steps, where the others are kept by layer and of the
 # last step. ``kda_scan_tokens``: token-layers the recurrence of the
-# "kda" layers computed (forward count: tokens x such layers)
+# "kda" layers computed (forward count: tokens x such layers).
+# ``attn_token_layers`` [2]: tokens x "swa" layers and tokens x "mha"
+# layers, of a model that has both (``ATTN_TOKEN_LAYER_KINDS``)
 KDA_SCAN_TOKENS = "kda_scan_tokens"
-STEP_COUNTS = MOE_COUNTS + (KDA_SCAN_TOKENS,)
+ATTN_TOKEN_LAYERS = "attn_token_layers"
+ATTN_TOKEN_LAYER_KINDS = ("window", "full")
+STEP_COUNTS = MOE_COUNTS + (KDA_SCAN_TOKENS, ATTN_TOKEN_LAYERS)
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """The rotary tables of one kind of layer: theta and, optionally,
+    YaRN's change of the frequencies (``latent_attention.YarnRope``)."""
+
+    theta: float = 10000.0
+    yarn: "YarnRope | None" = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,7 +102,10 @@ class LMConfig:
     # sliding-window (local) attention span: each position attends to
     # the `window` most recent tokens only. Implemented by the flash
     # kernels (out-of-window blocks are skipped — O(window)/query), so
-    # it requires a flash attention mode; None = full causal attention
+    # it requires a flash attention mode; None = full causal attention.
+    # It spans the "swa" layers of ``layers`` and leaves the "mha"
+    # layers beside them full; in a model without a "swa" layer (the
+    # byte LM, the serving forwards) it spans every "mha" layer
     window: "int | None" = None
     # grouped-query attention: K/V carry only this many heads, each
     # serving n_heads/n_kv_heads query heads (1 = MQA). Shrinks wk/wv
@@ -115,9 +132,19 @@ class LMConfig:
     # position iota with the sequence; zigzag uses its permutation as
     # the position ids), the decode path rotates at the absolute cache
     # slot, and window/GQA are unaffected (rotation acts per head-dim
-    # pair before any masking/grouping)
+    # pair before any masking/grouping). Where a model has two kinds of
+    # such layer the tables are per kind: ``rope_theta`` and
+    # ``rope_yarn`` make the "mha" layers', ``swa_rope`` the "swa"
+    # layers' (None: the same table). Each kind's tables are made once,
+    # outside the layers. YaRN and ``swa_rope`` train only
     rope: bool = False
     rope_theta: float = 10000.0
+    rope_yarn: "YarnRope | None" = None
+    swa_rope: "Rope | None" = None
+    # RMSNorm over each q and each k head's ``head_dim`` channels before
+    # the rotation, one scale vector each a layer (leaves ``q_norm``,
+    # ``k_norm`` [head_dim], ``norm_eps``). Training only
+    qk_norm: bool = False
     # decode KV-cache storage: None = the compute dtype (bf16 under
     # bfloat16 — the existing behavior); "int8" = per-token-per-head
     # symmetric int8 quantization (one f32 scale per [layer, batch,
@@ -148,7 +175,9 @@ class LMConfig:
     # with every ``moe_every``-th FFN "switch". The serving forwards run
     # "mha" with "dense" or "switch" and refuse the others by name.
     # Attention "kda" is the gated delta-rule linear-attention layer
-    # (models/kda.py, ``kda`` below): one chip's sequence only
+    # (models/kda.py, ``kda`` below): one chip's sequence only.
+    # Attention "swa" is an "mha" layer that sees the last ``window``
+    # keys, beside "mha" layers that see them all
     layers: "tuple | None" = None
     mla: "MLAConfig | None" = None
     moe: "TopKMoEConfig | None" = None
@@ -188,6 +217,10 @@ class LMConfig:
                 )
         if any(a == "kda" for a, _ in kinds) and self.kda is None:
             raise ValueError("a 'kda' layer needs LMConfig.kda")
+        if any(a == "swa" for a, _ in kinds) and self.window is None:
+            raise ValueError(
+                "a 'swa' layer needs LMConfig.window (the span it sees)"
+            )
         if any(f == "moe" for _, f in kinds) and self.moe is None:
             raise ValueError("a 'moe' layer needs LMConfig.moe")
         if self.kv_cache_dtype not in (None, "int8"):
@@ -234,6 +267,11 @@ class LMConfig:
                 f"LMConfig.rope pairs head dimensions: head_dim="
                 f"{self.head_width} must be even"
             )
+        if not self.rope and (self.rope_yarn or self.swa_rope):
+            raise ValueError(
+                "LMConfig.rope_yarn / swa_rope describe rotary tables: "
+                "they need LMConfig.rope"
+            )
 
     @property
     def kv_heads(self) -> int:
@@ -259,10 +297,33 @@ class LMConfig:
 
 def refuse_serving(cfg: LMConfig, where: str) -> None:
     """The cached forwards compute "mha" attention (heads of d_model /
-    n_heads, no output gate) over a K/V cache and a dense or switch FFN.
-    A layer of another kind ("mla", "kda", "moe"), an explicit head
-    width or an output gate is refused by name: decoding it through
-    those would be a different model."""
+    n_heads, no output gate, ONE ``cfg.window`` and one plain rotary
+    table for every layer) over a K/V cache and a dense or switch FFN.
+    A layer of another kind ("mla", "kda", "moe"), windowed layers
+    beside full ones ("swa" beside "mha"), rotary tables per kind of
+    layer or under YaRN, a q/k norm, an explicit head width or an output
+    gate is refused by name: decoding it through those would be a
+    different model."""
+    attention_kinds = {att for att, _ in cfg.layer_kinds}
+    if {"swa", "mha"} <= attention_kinds:
+        raise NotImplementedError(
+            f"{where}: windowed layers beside full ones ('swa' beside "
+            "'mha') have no decode path yet (the cached forwards read one "
+            "LMConfig.window for every layer, and a ring of window tokens "
+            "beside a full cache is not built); this model trains through "
+            "lm_forward only"
+        )
+    if cfg.rope_yarn is not None or cfg.swa_rope is not None:
+        raise NotImplementedError(
+            f"{where}: the cached forwards rotate by one plain table; "
+            "rotary tables per kind of layer or under YaRN "
+            "(LMConfig.swa_rope / rope_yarn) train through lm_forward only"
+        )
+    if cfg.qk_norm:
+        raise NotImplementedError(
+            f"{where}: the cached forwards have no q/k norm; "
+            "LMConfig.qk_norm trains through lm_forward only"
+        )
     for att, ffn in cfg.layer_kinds:
         if att == "kda":
             raise NotImplementedError(
@@ -341,6 +402,9 @@ def init_lm(key: jax.Array, cfg: LMConfig) -> Dict[str, jax.Array]:
                 p[f"l{i}/wg"] = s * jax.random.normal(
                     jax.random.fold_in(k2, 1), (cfg.d_model, wide)
                 )
+            if cfg.qk_norm:
+                p[f"l{i}/q_norm"] = jnp.ones((cfg.head_width,))
+                p[f"l{i}/k_norm"] = jnp.ones((cfg.head_width,))
         if ffn_kind == "switch":
             moe = init_moe(k3, cfg.d_model, cfg.d_ff, cfg.n_experts)
             p[f"l{i}/moe_router"] = moe["router"]
@@ -419,17 +483,26 @@ def _ln(x, scale):
     return (x - mu) * jax.lax.rsqrt(var + 1e-6) * scale
 
 
-def _rope_tables(positions, head_dim: int, theta: float):
+def _rope_tables(positions, head_dim: int, theta: float,
+                 yarn: "YarnRope | None" = None):
     """cos/sin rotation tables (f32) for ``apply_rope``: angles are
     pos * theta^(-i/half). Computed in f32 regardless of the activation
     dtype — bf16 positions lose integer precision past 256. Hoist these
     out of per-layer code: they depend only on positions and theta, and
     inside a ``jax.checkpoint`` region they would be recomputed in every
-    layer's backward pass."""
-    half = head_dim // 2
-    inv = theta ** (jnp.arange(half, dtype=jnp.float32) / -half)
+    layer's backward pass. Under ``yarn`` the frequencies and the factor
+    on cos and sin are ``latent_attention``'s (the one implementation)."""
+    if yarn is None:
+        half = head_dim // 2
+        inv = theta ** (jnp.arange(half, dtype=jnp.float32) / -half)
+    else:
+        inv = jnp.asarray(
+            latent.rope_inv_freq(head_dim, theta, yarn), jnp.float32
+        )
     ang = jnp.asarray(positions, jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    factor = latent.rope_attention_factor(yarn)  # 1.0 without YaRN
+    return (cos, sin) if factor == 1.0 else (cos * factor, sin * factor)
 
 
 def _rotate(x: jax.Array, cos, sin) -> jax.Array:
@@ -499,10 +572,14 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
     recurrence computed in this forward pass) and, stacked by such
     layer, ``probe_kda_state`` and ``probe_kda_g`` (models/kda.py: the
     recurrence's last state and log-decay, in the bits it computed with).
+    With "swa" layers also ``attn_token_layers`` [2] int32 (tokens x
+    "swa" layers, tokens x "mha" layers: ``ATTN_TOKEN_LAYER_KINDS``).
 
-    Named scopes, for the device trace: ``lm_attn`` (an "mha" or "mla"
-    layer's projections and attention), ``lm_kda_*`` (models/kda.py),
-    ``lm_ffn`` or ``lm_moe_*`` (models/moe.py), ``lm_head``.
+    Named scopes, for the device trace: ``lm_attn`` (an "mha", "swa" or
+    "mla" layer's projections and attention; in a model with "swa"
+    layers ``attn_window`` or ``attn_full`` nested inside it, by the
+    layer's kind), ``lm_kda_*`` (models/kda.py), ``lm_ffn`` or
+    ``lm_moe_*`` (models/moe.py), ``lm_head``.
     """
     b, s = tokens.shape
     hd = cfg.head_width
@@ -510,6 +587,7 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
     dtype = jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else jnp.float32
     kinds = cfg.layer_kinds
     n_kda = sum(a == "kda" for a, _ in kinds)
+    n_swa = sum(a == "swa" for a, _ in kinds)
     if n_kda and mesh.shape[axis] > 1:
         raise NotImplementedError(
             "a 'kda' layer over a sequence-sharded mesh (its state handed "
@@ -526,11 +604,17 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
     # permutation) and closed over by every layer — under remat they
     # enter jax.checkpoint as inputs, not per-layer recomputation
     positions = _rope_position_ids(cfg, s, mesh, axis)
-    rope_cs = (
-        _rope_tables(positions[None, :, None], hd, cfg.rope_theta)
-        if cfg.rope
-        else None
-    )
+    rope_cs, made = {}, {}  # by kind of layer present; by Rope
+    if cfg.rope:
+        full = Rope(cfg.rope_theta, cfg.rope_yarn)
+        for kind, rope in (("mha", full), ("swa", cfg.swa_rope or full)):
+            if not any(a == kind for a, _ in kinds):
+                continue
+            if rope not in made:  # two kinds may share one table
+                made[rope] = _rope_tables(
+                    positions[None, :, None], hd, rope.theta, rope.yarn
+                )
+            rope_cs[kind] = made[rope]
     mla_cs = mla_qscale = None
     if any(a == "mla" for a, _ in kinds):
         mla_cs = latent.rope_tables(
@@ -548,18 +632,23 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
         "a2a": None,
     }[cfg.attention]
 
-    def mha(h, lp):
+    def mha(h, lp, kind):
         cast = lambda k: lp[k].astype(dtype)  # noqa: E731
         q = h @ cast("wq")
         k = h @ cast("wk")
         v = h @ cast("wv")
-        if cfg.rope:  # rotate BEFORE the GQA broadcast: k is still narrow
-            q = _rotate(
-                q.reshape(b, s, cfg.n_heads, hd), *rope_cs
-            ).reshape(b, s, width)
-            k = _rotate(
-                k.reshape(b, s, cfg.kv_heads, hd), *rope_cs
-            ).reshape(b, s, cfg.kv_heads * hd)
+        if cfg.qk_norm or cfg.rope:
+            # by head, BEFORE the GQA broadcast: k is still narrow
+            q = q.reshape(b, s, cfg.n_heads, hd)
+            k = k.reshape(b, s, cfg.kv_heads, hd)
+            if cfg.qk_norm:
+                q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+                k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+            if cfg.rope:
+                q = _rotate(q, *rope_cs[kind])
+                k = _rotate(k, *rope_cs[kind])
+            q = q.reshape(b, s, width)
+            k = k.reshape(b, s, cfg.kv_heads * hd)
         if cfg.kv_heads != cfg.n_heads:
             # GQA: broadcast each K/V head over its query-head group up
             # front; every attention schedule below then sees full-width
@@ -587,7 +676,9 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
             ), h, lp)
         att = ring_attention(
             heads(q), heads(k), heads(v), mesh=mesh, axis=axis,
-            causal=True, impl=impl, window=cfg.window,
+            causal=True, impl=impl,
+            # the "swa" layers' span; every layer's where none is "swa"
+            window=cfg.window if kind == "swa" or not n_swa else None,
         )
         att = (
             att.reshape(b, cfg.n_heads, s, hd)
@@ -629,9 +720,17 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
             )
             x = x + att
         else:
-            with jax.named_scope("lm_attn"):
+            # a model with "swa" layers names each layer's kind
+            inner = contextlib.nullcontext() if not n_swa else (
+                jax.named_scope(
+                    "attn_window" if att_kind == "swa" else "attn_full"
+                )
+            )
+            with jax.named_scope("lm_attn"), inner:
                 h = norm(x, lp["ln1"])
-                att = mla(h, lp) if att_kind == "mla" else mha(h, lp)
+                att = (
+                    mla(h, lp) if att_kind == "mla" else mha(h, lp, att_kind)
+                )
                 x = x + att.astype(dtype) @ cast("wo")
         h2 = norm(x, lp["ln2"])
         if ffn_kind == "switch":
@@ -694,6 +793,11 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
         total[k] = sum(have) if k in MOE_COUNTS else jnp.stack(have)
     if n_kda:
         total[KDA_SCAN_TOKENS] = jnp.asarray(b * s * n_kda, jnp.int32)
+    if n_swa:
+        n_full = sum(a == "mha" for a, _ in kinds)
+        total[ATTN_TOKEN_LAYERS] = jnp.asarray(
+            [b * s * n_swa, b * s * n_full], jnp.int32
+        )
     with jax.named_scope("lm_head"):
         xn = norm(x.astype(jnp.float32), params["ln_f"])
         if cfg.tie_head:

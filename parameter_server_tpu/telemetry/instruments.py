@@ -1005,6 +1005,14 @@ def lm_instruments(reg: MetricsRegistry) -> Dict[str, object]:
             "layers computed (forward count: tokens x such layers, "
             "whatever the chunk of the scan)",
         ),
+        "attention_token_layers": reg.ensure_counter(
+            "ps_lm_attention_token_layers_total",
+            "token-layers of softmax attention in a model that has "
+            "sliding-window layers beside full ones, by the layer's kind "
+            "(forward count: tokens x layers of the kind; window = each "
+            "query sees the last LMConfig.window keys, full = all of them)",
+            labelnames=("kind",),
+        ),
     }
 
 

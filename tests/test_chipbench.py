@@ -24,15 +24,15 @@ sys.path.insert(0, REPO)
 
 _MODULES = (
     "chipbench.tests.test_seam", "chipbench.tests.test_lm_cell",
-    "chipbench.tests.test_lm_hybrid_cell",
+    "chipbench.tests.test_lm_hybrid_cell", "chipbench.tests.test_lm_swa_cell",
 )
 pytest.register_assert_rewrite(*_MODULES)
 
 from chipbench.tests import (  # noqa: E402
-    test_lm_cell, test_lm_hybrid_cell, test_seam,
+    test_lm_cell, test_lm_hybrid_cell, test_lm_swa_cell, test_seam,
 )
 
-for _mod in (test_seam, test_lm_cell, test_lm_hybrid_cell):
+for _mod in (test_seam, test_lm_cell, test_lm_hybrid_cell, test_lm_swa_cell):
     for _name, _obj in vars(_mod).items():
         # its tests, and the fixture they ask for by name
         if _name.startswith("test_") or _name == "harness":

@@ -654,11 +654,25 @@ def _serving_calls():
 @pytest.mark.parametrize("kind,word", [
     ("mla", "latent attention"), ("moe", "top-k expert layer"),
     ("kda", "gated delta-rule layer"),
+    ("swa", "windowed layers beside full ones"),
+    ("rope_by_kind", "rotary tables per kind"),
+    ("qk_norm", "no q/k norm"),
 ])
 def test_serving_entry_points_refuse_the_layer_kind_by_name(
     setup, entry, kind, word
 ):
     _, cfg, params, _ = setup
+    if kind == "swa":  # a windowed layer beside a full one, dense FFNs
+        cfg = tfm.LMConfig(
+            n_layers=2, layers=(("swa", "dense"), ("mha", "dense")), window=4,
+        )
+    if kind == "rope_by_kind":  # every layer windowed, a table of its own
+        cfg = tfm.LMConfig(
+            n_layers=1, layers=(("swa", "dense"),), window=4, rope=True,
+            swa_rope=tfm.Rope(500000.0),
+        )
+    if kind == "qk_norm":
+        cfg = tfm.LMConfig(n_layers=1, qk_norm=True)
     if kind == "moe":  # plain heads, the new expert layer
         cfg = tfm.LMConfig(
             vocab=512, d_model=64, n_heads=4, n_layers=1,
