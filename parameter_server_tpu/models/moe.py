@@ -28,15 +28,17 @@ move it. So the buffer is computed in two parts of static size
 experts expect (tokens x ``top_k`` x held / ``n_experts``), always; the
 TAIL, every row after it, under ``lax.cond`` in the steps in which a
 held assignment falls there, its output added to the head's. Every
-assignment still has its row whatever the router does. With a quarter
-of the experts or more held the head is the whole buffer, and there is
-no tail and no conditional. The branch not taken costs nothing because
-``_head_and_tail`` differentiates the tail itself: autodiff would carry
-the tail's residuals out of the conditional and fill them with zeros
-where it did not run, and add three zero weight gradients; here the
-backward pass is again a conditional, which recomputes the tail from
-its inputs and adds its gradients to the head's inside the branch
-taken, so that the other branch is an identity.
+assignment still has its row whatever the router does. Past an eighth
+of the experts held the head stops at half the buffer (a quarter held:
+twice the expected rows); with half of the experts or more held it is
+the whole buffer, and there is no tail and no conditional. The branch
+not taken costs nothing because ``_head_and_tail`` differentiates the
+tail itself: autodiff would carry the tail's residuals out of the
+conditional and fill them with zeros where it did not run, and add
+three zero weight gradients; here the backward pass is again a
+conditional, which recomputes the tail from its inputs and adds its
+gradients to the head's inside the branch taken, so that the other
+branch is an identity.
 """
 
 from __future__ import annotations
@@ -354,7 +356,30 @@ PROBE_TOKENS = 256
 # where the whole buffer took 93.5; head and tail 24 ms more than the
 # whole buffer at the same load (each part's combine gathers every
 # slot).
+#
+# The multiple shrinks as the share held grows (``head_rows``): the sum
+# over 16 of 64 experts moves less against its mean than the sum over 8
+# of 128, and cannot pass 4x at all. The records at a quarter held
+# (PERF.md, PR 35 and 36, mellum2_ep4.packed8k_mb1: 16 of 64, 65,536
+# rows, 16,384 expected): a layer draws 75-117% of the expected at a
+# run's first launch and, in the late layers, 150-268% by its 33rd: the
+# tail ran in 55 of 3,640 counted passes (1.5%) of windows of 33
+# launches and in 13% of the passes of windows of 85, so that share is
+# a window's and rises with its length. A pass that takes the tail
+# costs 10.5 ms more than the whole buffer at the same load and 17 more
+# than a head that held its rows (one layer alone, forward and backward:
+# 84.0, 73.6 and 66.9 ms at 34,078 rows). A head of 2.5x costs 13.9 ms
+# of 698 in every step, 1.7 a layer pass: it pays from a tenth of the
+# passes drawing between 2x and 2.5x. On shared seeds it read 0.9-1.8%
+# under the head of 2x in windows of 33 launches and -0.4%, +0.6% in
+# windows of 85. So past an eighth held the head stops at half the
+# buffer, which is 2x at a quarter: from a half held on it is the whole
+# buffer.
 HEAD_FACTOR = 4
+# the least multiple, which a share past a quarter held falls back on
+# (three eighths: 2x is 3/4 of the buffer). No cell holds such a share:
+# that band is run by the CPU tests alone and its 2 is not measured
+HEAD_FLOOR = 2
 # the head is rounded up to this many rows: a whole number of the tiles
 # the gathers and ``ragged_dot`` work in
 ROW_TILE = 512
@@ -362,11 +387,16 @@ ROW_TILE = 512
 
 def head_rows(tokens: int, cfg: TopKMoEConfig) -> int:
     """Rows of the sorted buffer's head for ``tokens`` tokens, from the
-    share of the experts held; ``tokens * top_k`` (no tail) where that
-    share is a quarter or more."""
+    share of the experts held: ``HEAD_FACTOR`` times the rows they
+    expect, but at most half the buffer and never under ``HEAD_FLOOR``
+    times those rows, in whole tiles; ``tokens * top_k`` (no tail) where
+    that share is a half or more, or the buffer under a tile."""
     rows = tokens * cfg.top_k
     expected = -(-rows * cfg.held // cfg.n_experts)
-    return min(rows, -(-HEAD_FACTOR * expected // ROW_TILE) * ROW_TILE)
+    head = max(
+        HEAD_FLOOR * expected, min(HEAD_FACTOR * expected, rows // 2)
+    )
+    return min(rows, -(-head // ROW_TILE) * ROW_TILE)
 
 
 def split_buffer(index, n_head: int):

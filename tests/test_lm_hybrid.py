@@ -684,8 +684,9 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(held):
 
 def test_a_tail_of_few_heads_is_one_piece_and_a_long_one_equal_pieces():
     """8 of 128 held at 16,384 tokens top-4 (a tail of 3 heads): one
-    piece, the program it was. 8 of 320 at 8,192 tokens top-8 (a tail of
-    8.8 heads): three pieces of whole tiles that cover it."""
+    piece, the program it was; 16 of 64 at 8,192 tokens top-8 (a tail of
+    one head) too. 8 of 320 at 8,192 tokens top-8 (a tail of 8.8
+    heads): three pieces of whole tiles that cover it."""
     from parameter_server_tpu.models import moe as moelib
 
     mistral = moelib.TopKMoEConfig(
@@ -693,6 +694,11 @@ def test_a_tail_of_few_heads_is_one_piece_and_a_long_one_equal_pieces():
     )
     assert moelib.head_rows(16384, mistral) == 16384
     assert moelib._tail_pieces(16384, 65536 - 16384) == (1, 49152)
+    mellum = moelib.TopKMoEConfig(
+        n_experts=64, top_k=8, d_expert=8, experts_held=16
+    )
+    assert moelib.head_rows(8192, mellum) == 32768
+    assert moelib._tail_pieces(32768, 65536 - 32768) == (1, 32768)
     solar = moelib.TopKMoEConfig(
         n_experts=320, top_k=8, d_expert=8, experts_held=8
     )

@@ -374,37 +374,58 @@ def test_topk_config_refuses_a_share_outside_the_experts():
 TAIL_TOKENS = 1024
 
 
-def _tail_layer(routing: str = "alone"):
-    """``(share, lp, x, m)``: a layer that holds expert 5 of 16. Left
-    ``alone`` the router sends it about 128 rows; ``forced`` every token
-    picks it first; at the ``boundary`` exactly the first 512 do, and no
-    other: the head is full and the tail empty."""
+def _tail_layer(routing: str = "alone", held: int = 1):
+    """``(share, lp, x, m)``: a layer that holds expert 5 of 16, or with
+    ``held`` 4 experts 4-7 (a quarter: 512 rows expected, a head of
+    1,024 and a tail of 1,024). Left ``alone`` the router sends it about
+    the expected rows; ``forced`` every token picks expert 5 first (and
+    with 4 held some pick another held one second: past the head); at
+    the ``boundary`` exactly the head's rows are held: the first 512
+    tokens pick expert 5 and no other does, or with 4 held every token
+    picks expert 5 and none picks 4, 6 or 7. The head is full and the
+    tail empty."""
     full = moelib.TopKMoEConfig(n_experts=16, top_k=2, d_expert=32, n_shared=1)
     lp = moelib.init_topk_moe(jax.random.PRNGKey(3), 64, full, 0.3)
-    lp = {k: v[5:6] if k.startswith("we_") else v for k, v in lp.items()}
+    offset = 5 if held == 1 else 4
+    lp = {
+        k: v[offset:offset + held] if k.startswith("we_") else v
+        for k, v in lp.items()
+    }
     lp["ln2"] = jnp.ones((64,))
     x = jax.random.normal(jax.random.PRNGKey(4), (2, TAIL_TOKENS // 2, 64))
     if routing != "alone":
         # the held expert reads the first coordinate alone; the others'
         # logits stay small, so its weight is neither 0 nor 1
         lp["router"] = (0.1 * lp["router"]).at[:, 5].set(0.0).at[0, 5].set(4.0)
-        first = jnp.arange(TAIL_TOKENS).reshape(2, -1) < 512
-        sign = 1.0 if routing == "forced" else jnp.where(first, 1.0, -1.0)
+        sign = 1.0
+        if routing == "boundary" and held == 1:
+            first = jnp.arange(TAIL_TOKENS).reshape(2, -1) < 512
+            sign = jnp.where(first, 1.0, -1.0)
+        elif routing == "boundary":
+            others = jnp.array([4, 6, 7])
+            lp["router"] = lp["router"].at[0, others].set(-4.0)
         x = x.at[..., 0].set(sign * (jnp.abs(x[..., 0]) + 1.0))
-    share = dataclasses.replace(full, experts_held=1, expert_offset=5)
-    m = {**ref.model(small_desc()), "experts": 16, "held": 1, "offset": 5}
+    share = dataclasses.replace(full, experts_held=held, expert_offset=offset)
+    m = {
+        **ref.model(small_desc()), "experts": 16, "held": held,
+        "offset": offset,
+    }
     return share, lp, x, m
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-@pytest.mark.parametrize("routing,rows,tail", [
-    ("alone", None, 0), ("forced", TAIL_TOKENS, 1), ("boundary", 512, 0),
+@pytest.mark.parametrize("held,head,routing,tail", [
+    (1, 512, "alone", 0), (1, 512, "forced", 1), (1, 512, "boundary", 0),
+    (4, 1024, "alone", 0), (4, 1024, "forced", 1), (4, 1024, "boundary", 0),
 ])
-def test_head_and_tail_are_the_reference_layer(routing, rows, tail, remat):
+def test_head_and_tail_are_the_reference_layer(
+        held, head, routing, tail, remat):
     """Output and every gradient, whether the tail runs or not, also
-    when the layer is rematerialised around the saved choices."""
-    share, lp, x, m = _tail_layer(routing)
-    assert moelib.head_rows(TAIL_TOKENS, share) == 512
+    when the layer is rematerialised around the saved choices; with a
+    sixteenth of the experts held (a head of 4 x the expected rows) and
+    with a quarter (2 x: half the buffer)."""
+    share, lp, x, m = _tail_layer(routing, held)
+    assert moelib.head_rows(TAIL_TOKENS, share) == head
     weigh = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
 
     def mine(lp, x):
@@ -428,8 +449,13 @@ def test_head_and_tail_are_the_reference_layer(routing, rows, tail, remat):
         (_, want), (w_lp, w_x) = jax.value_and_grad(
             theirs, argnums=(0, 1), has_aux=True
         )(lp, x)
-    held = int(stats["expert_rows"].sum())
-    assert held == rows if rows is not None else 0 < held < 512
+    rows = int(stats["expert_rows"].sum())
+    if routing == "alone":
+        assert 0 < rows < head
+    elif routing == "boundary":
+        assert rows == head
+    else:
+        assert rows > head and (held > 1 or rows == TAIL_TOKENS)
     assert stats["buffer_passes"].tolist() == [1, tail]
     assert rel(y, want) < 1e-5
     assert rel(g_x, w_x) < 1e-5
@@ -439,12 +465,19 @@ def test_head_and_tail_are_the_reference_layer(routing, rows, tail, remat):
 
 
 @pytest.mark.parametrize("tokens,held,experts,top_k,want", [
-    (16384, 8, 128, 4, 16384),  # the cell: a quarter of its 65,536 rows
+    # the three cells: 4 x 4,096 expected, a quarter of 65,536 rows; 4 x
+    # 1,639 in whole tiles; 4 x 16,384 is the buffer, so half of it
+    (16384, 8, 128, 4, 16384),
+    (8192, 8, 320, 8, 6656),
+    (8192, 16, 64, 8, 32768),
     (TAIL_TOKENS, 1, 16, 2, 512),
     (4096, 1, 128, 4, 512),  # 4 x 128 expected: one tile
     (4000, 3, 128, 4, 1536),  # 4 x 375 expected, rounded up to the tile
-    (80, 2, 8, 2, 160),  # a quarter held: the whole buffer
-    (80, 1, 16, 2, 160),  # fewer rows than a tile: the whole buffer
+    (8192, 9, 64, 8, 32768),  # just past an eighth: half the buffer
+    (8192, 24, 64, 8, 49152),  # three eighths: 2 x the expected rows
+    (8192, 32, 64, 8, 65536),  # a half held: the whole buffer
+    (80, 2, 8, 2, 160),  # fewer rows than a tile: the whole buffer
+    (80, 1, 16, 2, 160),
     (16384, None, 128, 4, 65536),  # all held
 ])
 def test_the_head_follows_the_share_of_the_experts_held(
@@ -456,13 +489,13 @@ def test_the_head_follows_the_share_of_the_experts_held(
 
 
 @pytest.mark.parametrize("held,experts,conds", [
-    (1, 16, 1), (4, 16, 0), (8, 16, 0), (None, 16, 0),
+    (1, 16, 1), (4, 16, 1), (6, 16, 1), (8, 16, 0), (None, 16, 0),
 ])
-def test_a_quarter_of_the_experts_held_means_no_conditional(
+def test_half_of_the_experts_held_means_no_conditional(
         held, experts, conds):
-    """With no tail the traced layer has no ``cond``, forward or
-    backward; with one, one each way (the recomputed forward's is the
-    forward's again)."""
+    """With no tail (half of the experts or more held) the traced layer
+    has no ``cond``, forward or backward; with one, one each way (the
+    recomputed forward's is the forward's again)."""
     cfg = moelib.TopKMoEConfig(
         n_experts=experts, top_k=2, d_expert=32, experts_held=held
     )

@@ -433,7 +433,9 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(held):
     """The parts of the expert layer's result that all 4 / ``held``
     shares give (the program's layer, told which experts it holds; no
     shared expert to count once) are the uncut reference layer. 1 held
-    of 4 is the cell's quarter: the whole buffer, no tail."""
+    of 4 is the cell's quarter, but 48 tokens x 2 choices are a buffer
+    under a tile: still whole, no tail (the quarter's head and tail are
+    held to the reference in ``test_lm_mla_moe.py``)."""
     from parameter_server_tpu.models import moe as moelib
 
     desc = small_desc()
