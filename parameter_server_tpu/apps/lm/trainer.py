@@ -9,10 +9,14 @@ both go through :func:`build_trainer`.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import functools
 import json
+import logging
+import statistics
+import time
 from typing import Optional
 
 import numpy as np
@@ -25,6 +29,18 @@ _LOOP_SPANS = {
     "collect_wait": "train.collect.wait",
     "collect_host": "train.collect.host",
 }
+# the phases, and the caller's time between them, in a launch's record
+_RECORD_FIELDS = dict(zip(
+    _LOOP_SPANS, ("ingest_s", "submit_s", "wait_s", "host_s")
+), outside="outside_s")
+# A launch is stalled when its interval (one collect's end to the next)
+# is over twice the running median and over it by half a second: an
+# interval is 0.69 to 1.08 s in the three LM cells (ledger, PR 36), the
+# launches seen took 1.6 to 4.5 s more than their neighbours (PERF.md
+# section 7). None is judged before 4 intervals are in, so the launches
+# that compile are not; a stall's line holds the 8 records before it.
+STALL_RATIO, STALL_EXCESS_S = 2.0, 0.5
+STALL_HISTORY, STALL_MIN_INTERVALS, STALL_CONTEXT = 32, 4, 8
 
 
 def load_description(path: str) -> dict:
@@ -279,6 +295,8 @@ class Launch:
     # launch's steps; its last step's choices and router probes
     stats: dict
     tokens: int
+    flow: int = 0  # the launch's flow id: its four phases and its record
+    t_submit: float = 0.0  # the trainer's clock as its submit began
 
 
 class Trainer:
@@ -306,6 +324,14 @@ class Trainer:
         self.n_data = mesh.shape["data"]
         self.params = self.opt = None
         self._counters, self._loop_seconds = None, {}
+        # the launch record, timed by ``clock`` (a test hands another)
+        self.clock, self._collected = time.perf_counter, 0
+        self._flow = None  # the next launch's, from its first phase on
+        self._last_end = self._mark = None  # the previous collect's end
+        self._since = dict.fromkeys(_RECORD_FIELDS, 0.0)  # by phase, since
+        self._history = {k: collections.deque(maxlen=STALL_HISTORY) for k in
+                         (*_RECORD_FIELDS.values(), "interval_s")}
+        self._recent = collections.deque(maxlen=STALL_CONTEXT)
         if telemetry_registry.enabled():
             from ...telemetry.instruments import (
                 app_instruments,
@@ -432,15 +458,28 @@ class Trainer:
     # -- a launch ----------------------------------------------------------
 
     @contextlib.contextmanager
-    def loop_phase(self, phase: str):
+    def loop_phase(self, phase: str, launch: Optional[Launch] = None):
         """One phase of the training loop on the trainer's thread:
-        ``ps_train_loop_seconds{phase}`` and its ``train.*`` span."""
-        from ...telemetry import spans
+        ``ps_train_loop_seconds{phase}`` and its ``train.*`` span, in the
+        flow of ``launch`` (a collect's) or of the next launch, allotted
+        when its first phase opens and taken by ``submit``."""
+        from ...telemetry import host, spans
 
-        with spans.span(
-            _LOOP_SPANS[phase], histogram=self._loop_seconds.get(phase)
-        ):
-            yield
+        if self._last_end is None:  # the loop begins here
+            host.install_hooks()
+            self._last_end, self._mark = self.clock(), host.mark()
+        if launch is None and self._flow is None:
+            self._flow = spans.new_flow()
+        t0 = self.clock()
+        try:
+            with spans.flow_scope(
+                self._flow if launch is None else launch.flow
+            ), spans.span(
+                _LOOP_SPANS[phase], histogram=self._loop_seconds.get(phase)
+            ):
+                yield
+        finally:
+            self._since[phase] += self.clock() - t0
 
     def place(self, batches) -> tuple:
         """Device arrays for one launch from its ``steps_per_launch``
@@ -464,11 +503,13 @@ class Trainer:
         )
 
     def submit(self, data: tuple) -> Launch:
+        t_submit = self.clock()
         with self.loop_phase("submit"):
             self.params, self.opt, loss, stats = self.step(
                 self.params, self.opt, *data
             )
-        return Launch(loss, stats, int(np.prod(data[0].shape)))
+        flow, self._flow = self._flow, None
+        return Launch(loss, stats, int(np.prod(data[0].shape)), flow, t_submit)
 
     def collect(self, launch: Launch):
         """``(loss, counts)`` of a launch on the host, counted: its
@@ -484,9 +525,9 @@ class Trainer:
             STEP_COUNTS,
         )
 
-        with self.loop_phase("collect_wait"):
+        with self.loop_phase("collect_wait", launch):
             loss = float(launch.loss)
-        with self.loop_phase("collect_host"):
+        with self.loop_phase("collect_host", launch):
             counts = {
                 k: np.asarray(v) for k, v in launch.stats.items()
                 if k in STEP_COUNTS
@@ -513,7 +554,67 @@ class Trainer:
                     self._counters["buffer_passes"].labels(part=part).inc(
                         int(n)
                     )
+        self._record(launch, int(sum(counts.get("buffer_passes", (0, 0))[1:])))
         return loss, counts
+
+    def _record(self, launch: Launch, tail_passes: int) -> None:
+        """ONE record of a collected launch, ``train.launch``: into the
+        process's flight recorder always (a lock and an append), through
+        the span sink if there is one; a stalled launch is also logged."""
+        from ...telemetry import blackbox, host, spans
+
+        now, mark = self.clock(), host.mark()
+        interval = now - self._last_end
+        since, self._since = self._since, dict.fromkeys(_RECORD_FIELDS, 0.0)
+        # the thread's time since the previous collect that no phase held
+        since["outside"] = max(0.0, interval - sum(since.values()))
+        past = self._history["interval_s"]
+        judged = len(past) >= STALL_MIN_INTERVALS
+        m = statistics.median(past) if judged else None
+        stalled = m is not None and interval > max(
+            STALL_RATIO * m, m + STALL_EXCESS_S
+        )
+        record = {
+            "kind": "span", "name": "train.launch", "launch": self._collected,
+            "flow": launch.flow, "dur_s": now - launch.t_submit,
+            "t_wall": time.time() - (now - launch.t_submit),  # its submit
+            **{_RECORD_FIELDS[k]: v for k, v in since.items()},
+            "interval_s": interval, "tokens": launch.tokens,
+            "tail_passes": tail_passes, "stalled": stalled,
+        }
+        events = [record]
+        if stalled:
+            # the phase whose excess over its own median is largest
+            where = max(since, key=lambda k: since[k] - statistics.median(
+                self._history[_RECORD_FIELDS[k]]
+            ))
+            events.append({
+                "kind": "span", "name": "train.launch.stalled",
+                "t_wall": time.time(), "dur_s": 0.0, "flow": launch.flow,
+                "where": where, "median_interval_s": m,
+                "launch": record, "before": list(self._recent),
+                "host": host.evidence(self._mark, mark),
+            })
+            logging.getLogger("parameter_server_tpu").warning(
+                "%s", json.dumps(events[1], default=str)
+            )
+            if self._counters is not None:
+                self._counters["stalled"].labels(where=where).inc()
+        ring, sink = blackbox.recorder(), spans.get_sink()
+        for event in events:
+            if sink is not None:
+                spans.emit(event)
+            if getattr(sink, "recorder", None) is not ring:
+                ring.emit(event)  # an armed ring has it from the sink
+        if self._counters is not None:
+            self._counters["launch_seconds"].observe(record["dur_s"])
+            self._counters["launch_interval"].observe(interval)
+        for k, h in self._history.items():
+            h.append(record[k])
+        self._recent.append(record)
+        self._last_end, self._mark, self._collected = (
+            now, mark, self._collected + 1
+        )
 
 
 def build_trainer(cfg, mesh, *, optimizer: str = "adam", lr=3e-3,

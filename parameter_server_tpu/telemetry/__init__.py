@@ -69,7 +69,6 @@ from .spans import (
     install_sink,
     maybe_new_flow,
     new_flow,
-    parked_sink,
     span,
 )
 
@@ -107,7 +106,6 @@ __all__ = [
     "install_sink",
     "maybe_new_flow",
     "new_flow",
-    "parked_sink",
     "reset_default_registry",
     "set_enabled",
     "span",

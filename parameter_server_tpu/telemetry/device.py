@@ -53,6 +53,7 @@ read :func:`snapshot`.
 
 from __future__ import annotations
 
+import collections
 import math
 import threading
 import time
@@ -706,11 +707,10 @@ class HbmMonitor:
                 if not ms:
                     continue
                 label = f"{d.platform}:{d.id}"
-                stats = {
-                    "bytes_in_use": int(ms.get("bytes_in_use", 0)),
-                    "peak_bytes_in_use": int(ms.get("peak_bytes_in_use", 0)),
-                    "bytes_limit": int(ms.get("bytes_limit", 0)),
-                }
+                stats = {k: int(ms.get(k, 0)) for k in (
+                    "bytes_in_use", "peak_bytes_in_use", "bytes_limit",
+                    "largest_free_block_bytes",  # 0 where the backend has none
+                )}
                 devices[label] = stats
         except Exception:
             pass
@@ -814,6 +814,41 @@ def install_hbm_monitor(reg=None) -> Optional[HbmMonitor]:
         except Exception:
             pass
     return mon
+
+
+# every compile and cache event of jax's, not only those the inventory's
+# own wrapper reached: (perf_counter at the end, event, seconds)
+_compile_events: collections.deque = collections.deque(maxlen=256)
+_compile_listener = False  # guarded-by: _hbm_lock
+
+
+def _on_compile_event(event: str, seconds: float, **_) -> None:
+    if event.startswith(("/jax/core/compile/", "/jax/compilation_cache/")):
+        _compile_events.append((time.perf_counter(), event, seconds))
+
+
+def install_compile_listener() -> None:
+    """The process's one ``jax.monitoring`` duration listener."""
+    global _compile_listener
+    import jax
+
+    with _hbm_lock:
+        if not _compile_listener:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_event
+            )
+        _compile_listener = True
+
+
+def compile_events_since(t0: float) -> Dict[str, Dict[str, float]]:
+    """``{event: {count, seconds}}`` ended after ``t0`` (perf_counter)."""
+    out: Dict[str, Dict[str, float]] = {}
+    for t, event, seconds in list(_compile_events):
+        if t >= t0:
+            got = out.setdefault(event, {"count": 0, "seconds": 0.0})
+            got["count"] += 1
+            got["seconds"] += seconds
+    return out
 
 
 def snapshot() -> Dict[str, Any]:

@@ -259,16 +259,14 @@ def serve_registry(
 def _timeline_tail(n: int = 64) -> dict:
     """Last ``n`` span events from the installed JSONL sink (tolerant
     of torn tails), with the sink's state DISCLOSED: an empty events
-    list under ``sink: parked`` (a sink exists but is temporarily
-    uninstalled — an embedded A/B is running) or ``sink: absent`` (no
-    sink was ever installed) means "no trace captured", which is not
-    the same claim as "nothing happened"."""
+    list under ``sink: absent`` (no sink is installed) means "no trace
+    captured", which is not the same claim as "nothing happened"."""
     from . import spans as telemetry_spans
 
     sink = telemetry_spans.get_sink()
     path = getattr(sink, "path", None)
     tail: dict = {
-        "sink": telemetry_spans.sink_state(),
+        "sink": "absent" if sink is None else "active",
         "path": path,
         "events": [],
     }

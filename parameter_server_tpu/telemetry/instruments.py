@@ -1013,6 +1013,27 @@ def lm_instruments(reg: MetricsRegistry) -> Dict[str, object]:
             "query sees the last LMConfig.window keys, full = all of them)",
             labelnames=("kind",),
         ),
+        "launch_seconds": reg.ensure_histogram(
+            "ps_lm_launch_seconds", buckets=PHASE_BUCKETS,
+            help="a launch of the LM trainer, its submit to its collect's end",
+        ),
+        "launch_interval": reg.ensure_histogram(
+            "ps_lm_launch_interval_seconds", buckets=PHASE_BUCKETS,
+            help="one collect's end to the next on the LM trainer's thread: "
+            "in a closed loop the device binds, the step's device time",
+        ),
+        "stalled": reg.ensure_counter(
+            "ps_lm_stalled_launches_total",
+            "launches over twice the running median interval and 0.5 s over "
+            "it, by the loop phase that held the excess (outside: the caller)",
+            labelnames=("where",),
+        ),
+        "gc_pause": reg.ensure_histogram(
+            "ps_host_gc_pause_seconds",
+            "pause of each garbage collection of the process (one "
+            "gc.callbacks hook, installed by the LM trainer)",
+            labelnames=("generation",), buckets=PHASE_BUCKETS,
+        ),
     }
 
 
@@ -1083,6 +1104,7 @@ cached_blackbox_instruments = _cached_family(blackbox_instruments)
 cached_bundle_instruments = _cached_family(bundle_instruments)
 cached_partition_instruments = _cached_family(partition_instruments)
 cached_consistency_instruments = _cached_family(consistency_instruments)
+cached_lm_instruments = _cached_family(lm_instruments)
 
 
 INSTRUMENT_FAMILIES = (

@@ -74,7 +74,6 @@ class JsonlSink:
 
 _sink_lock = threading.Lock()
 _sink: Optional[JsonlSink] = None
-_parked_depth = 0  # guarded-by: _sink_lock — nested parked_sink() count
 
 
 def install_sink(sink: Optional[JsonlSink]) -> Optional[JsonlSink]:
@@ -88,19 +87,6 @@ def install_sink(sink: Optional[JsonlSink]) -> Optional[JsonlSink]:
 
 def get_sink() -> Optional[JsonlSink]:
     return _sink
-
-
-def sink_state() -> str:
-    """One of ``active`` / ``parked`` / ``absent`` — so a reader of an
-    empty timeline tail (/debug/snapshot) can tell "no trace captured
-    because nothing is listening" apart from "nothing happened":
-    ``parked`` means a sink exists but is temporarily uninstalled
-    (:func:`parked_sink`), ``absent`` means no
-    sink was ever installed (or it was closed)."""
-    with _sink_lock:
-        if _sink is not None:
-            return "active"
-        return "parked" if _parked_depth > 0 else "absent"
 
 
 def close_sink() -> None:
@@ -142,28 +128,6 @@ def maybe_new_flow() -> Optional[int]:
     producer-side idiom (only pay for flow ids when tracing is on;
     ``flow_scope(None)`` downstream is a no-op)."""
     return new_flow() if _sink is not None else None
-
-
-@contextlib.contextmanager
-def parked_sink():
-    """Temporarily uninstall the span sink for a block, so that work
-    outside the traced window neither pays for tracing nor floods the
-    run's trace with off-window events. Restores the previous sink on exit. While parked,
-    :func:`sink_state` reports ``parked`` (only if a sink actually
-    existed — parking nothing is still ``absent``)."""
-    global _parked_depth
-    prev = install_sink(None)
-    had_sink = prev is not None
-    if had_sink:
-        with _sink_lock:
-            _parked_depth += 1
-    try:
-        yield
-    finally:
-        if had_sink:
-            with _sink_lock:
-                _parked_depth -= 1
-        install_sink(prev)
 
 
 def current_flow() -> Optional[int]:

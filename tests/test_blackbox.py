@@ -390,7 +390,7 @@ class TestFlightRecorder:
         assert any(
             e["name"] == "bb.idle" for e in rec.dump()["events"]
         )
-        assert telemetry_spans.sink_state() == "active"
+        assert telemetry_spans.get_sink() is not None
 
     def test_metrics_delta_samples(self):
         from parameter_server_tpu.telemetry.registry import MetricsRegistry
@@ -609,13 +609,6 @@ class TestExposition:
             tail = _timeline_tail()
             assert tail["sink"] == "active"
             assert [e["name"] for e in tail["events"]] == ["disclose.me"]
-            # parked: a sink exists but an embedded A/B uninstalled it —
-            # "no trace captured" is now distinguishable from "nothing
-            # happened"
-            with telemetry_spans.parked_sink():
-                tail = _timeline_tail()
-                assert tail["sink"] == "parked"
-                assert tail["events"] == []
         finally:
             telemetry_spans.install_sink(prev)
             sink.close()

@@ -302,6 +302,126 @@ def test_metric_file_loads_and_names_a_reader_that_exists(name):
     assert callable(reader.read)
 
 
+# the LM trainer's loop metrics (PR 37), each defined on what PR 36's
+# trainer already emitted: the three LM cells list them
+LM_LOOP = [
+    "lm_launch_interval_ms", "lm_launch_interval_late_over_early",
+    "lm_submit_host_ms", "lm_collect_host_ms", "lm_moe_tail_pass_share",
+]
+LM_CELLS = [
+    "mistral_small4_ep16.packed8k", "solar_open2_ep40.packed8k_mb1",
+    "mellum2_ep4.packed8k_mb1",
+]
+
+
+def test_the_stalled_share_has_a_file_and_no_entry_yet():
+    """Its parent has not the counter, and the harness refuses a line
+    that lacks a metric: the entry is the next PR's (PERF.md section 7)."""
+    spec = metric_spec("lm_stalled_launch_share")
+    assert spec["reader"] == "registry_delta"
+    assert spec["metric"] == "ps_lm_stalled_launches_total"
+    assert spec["per"] == {"metric": "ps_lm_launch_seconds", "field": "count"}
+    assert "lm_stalled_launch_share" not in PER_LAYER
+    for name in LM_LOOP:
+        assert PER_LAYER[name]["workloads"] == LM_CELLS, name
+        assert PER_LAYER[name]["moves"] == "examples_per_s", name
+    assert list(PER_LAYER)[-5:] == LM_LOOP  # appended, nothing moved
+
+
+def test_span_interval_on_a_hand_made_list_of_spans():
+    from chipbench.readers import span_interval
+
+    def waits(ends):
+        # out of order, among other spans, a wait of 0.25 s each
+        events = [{"name": "train.collect.wait", "t_wall": e - 0.25,
+                   "dur_s": 0.25} for e in reversed(ends)]
+        return {"spans": events + [
+            {"name": "train.submit", "t_wall": 1.0, "dur_s": 9.0},
+            {"name": "train.collect.wait", "t_wall": 3.0},  # abandoned
+        ]}
+
+    median = {"span": "train.collect.wait", "stat": "median", "scale": 1e3}
+    drift = {"span": "train.collect.wait", "stat": "late_over_early"}
+    # seven intervals: 1, 1, 1, 5 (a stalled launch), 2, 2, 2
+    ends = [10.0, 11.0, 12.0, 13.0, 18.0, 20.0, 22.0, 24.0]
+    assert span_interval.read(waits(ends), median) == pytest.approx(2000.0)
+    assert span_interval.read(waits(ends), drift) == pytest.approx(2.0)
+    assert span_interval.read(waits(ends[:7]), drift) == pytest.approx(2.0)
+    # under six intervals no thirds; one span, or none, no interval
+    assert span_interval.read(waits(ends[:6]), drift) is None
+    assert span_interval.read(waits(ends[:6]), median) == pytest.approx(1000.0)
+    assert span_interval.read(waits(ends[:1]), median) is None
+    assert span_interval.read({"spans": []}, median) is None
+    with pytest.raises(ValueError, match="late_over_early"):
+        span_interval.read(waits(ends), {**median, "stat": "mean"})
+
+
+@pytest.fixture(scope="module")
+def toy_lm_run():
+    """Nine launches of a toy LM through ``apps/lm``'s trainer, two in
+    flight, after one that compiles: the registry around them and their
+    span events, as the harness hands them to a reader."""
+    import collections
+
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from chipbench import lm_reference
+    from parameter_server_tpu.apps.lm import trainer as lm_trainer
+
+    if not treg.enabled():
+        pytest.skip("telemetry disabled")
+    desc = lm_reference.description(os.path.join(
+        REPO, "chipbench", "configs", "mistral_small4_ep16.json"
+    ), rehearsal=True)
+    trainer = lm_trainer.build_trainer(
+        lm_trainer.model_from_description(desc, remat=True),
+        Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "server")),
+        optimizer="adafactor",
+    )
+    trainer.init(5)
+    pending = collections.deque()
+
+    def launch(i):
+        with trainer.loop_phase("wait_ingest"):
+            data = trainer.place([np.asarray(jax.random.randint(
+                jax.random.PRNGKey(i), (2, 64), 0, 512
+            ))])
+        pending.append(trainer.submit(data))
+
+    launch(0)
+    trainer.collect(pending.popleft())  # compiles
+    sink, reg = ListSink(), treg.default_registry()
+    prev = spans.install_sink(sink)
+    try:
+        before = reg.export_state()
+        for i in range(1, 10):
+            launch(i)
+            if len(pending) >= 2:
+                trainer.collect(pending.popleft())
+        while pending:
+            trainer.collect(pending.popleft())
+        after = reg.export_state()
+    finally:
+        spans.install_sink(prev)
+    return {"before": before, "after": after, "spans": list(sink.events)}
+
+
+@pytest.mark.parametrize("name", LM_LOOP + ["lm_stalled_launch_share"])
+def test_lm_loop_metrics_read_a_toy_lm_run(toy_lm_run, name):
+    spec = metric_spec(name)
+    reader = importlib.import_module("chipbench.readers." + spec["reader"])
+    value = reader.read(toy_lm_run, spec)
+    if name in ("lm_moe_tail_pass_share", "lm_stalled_launch_share"):
+        assert value == 0.0  # half of the experts held; no launch stalled
+    else:  # the ratio too: eight intervals, thirds of two
+        assert value > 0, (name, value)
+    # and on a program without the counter or the span: nothing, no raise
+    bare = {"before": {}, "after": {}, "spans": []}
+    assert reader.read(bare, spec) is None
+
+
 def test_the_four_idle_metrics_share_one_order_of_buckets():
     assert set(IDLE_METRICS) | set(HOST_SIDE) <= set(PER_LAYER)
     specs = {n: metric_spec(n) for n in IDLE_METRICS}
