@@ -41,13 +41,21 @@ def _block_attn(q, k, v, mask):
     return s
 
 
+def _axis_index(axis: str, n: int):
+    """This device's place on ``axis``. On a one-device axis it is the
+    Python int 0, so that what is computed from it (a chunk's global
+    offsets) is known while the program is traced: the flash kernels then
+    list exactly the blocks their mask keeps."""
+    return 0 if n == 1 else jax.lax.axis_index(axis)
+
+
 def _ring_hops(k, v, axis: str, n: int):
     """Yield ``(kb, vb, src)`` for each of the n ring hops: the K/V chunk
     currently held and WHICH device's shard it is. The single home of the
     schedule invariant — ``ring_next``'s ppermute shifts blocks forward,
     so the held chunk's source index DEcrements — shared by both
     ring-attention impls so their causal offsets cannot desynchronize."""
-    src = jax.lax.axis_index(axis)
+    src = _axis_index(axis, n)
     kb, vb = k, v
     for step in range(n):
         yield kb, vb, src
@@ -177,7 +185,7 @@ def _ring_attention_flash(q, k, v, *, mesh, axis, causal, use_pallas,
 
     def local(q, k, v):
         b, s_loc, h = q.shape
-        my = jax.lax.axis_index(axis)
+        my = _axis_index(axis, n)
         out = jnp.zeros((b, s_loc, h), jnp.float32)
         lse = jnp.full((b, s_loc), -1e30, jnp.float32)
         for kb, vb, src in _ring_hops(k, v, axis, n):
@@ -241,7 +249,7 @@ def _ring_attention_zigzag(q, k, v, *, mesh, axis, causal, use_pallas,
                 "zigzag_permutation)"
             )
         half = s_loc // 2
-        my = jax.lax.axis_index(axis)
+        my = _axis_index(axis, n)
         q_halves = (q[:, :half], q[:, half:])
         q_offs = (my * half, (2 * n - 1 - my) * half)
         outs = [jnp.zeros((b, half, h_feat), jnp.float32) for _ in range(2)]

@@ -19,9 +19,13 @@ once by XLA outside the kernel.
 Reference parity: the reference has no attention op (linear methods +
 CXXNET convnets); this kernel exists for the framework's first-class
 long-context requirement. Math follows Dao et al.'s FlashAttention-2
-recurrence; structure follows the canonical TPU grid pattern
-(grid = (batch*heads, q blocks, k blocks), k innermost, accumulators in
-VMEM scratch persisted across the k dimension).
+recurrence. The grid is ``(batch*heads, steps)``: a step is one pair of
+a q block and a k block that can hold a kept pair, taken from tables the
+kernels read by scalar prefetch (``grid_tables``), so a block the mask
+drops whole is neither copied into VMEM nor stepped over. The pairs of
+one output block are consecutive (k innermost for the forward and dq,
+q innermost for dkv) and its accumulators live in VMEM scratch from the
+row's first pair to its last.
 
 ``flash_attention(q, k, v, ...)`` auto-selects: Pallas on TPU backends,
 an identical-math XLA path elsewhere (tests force the kernels through
@@ -105,33 +109,153 @@ def flash_attention_ref(q, k, v, q_offset, k_offset, *, causal, window=None):
 
 
 # ---------------------------------------------------------------------------
+# the grid: the block pairs a call visits
+# ---------------------------------------------------------------------------
+
+
+def _block_live(q_off, iq, block_q, k_off, k_base, block_k, causal, window):
+    """Whether a block can hold a kept pair: the one home of the bound.
+    ``grid_tables`` evaluates it over the rectangle for the forward's
+    list and both backward kernels', so they can never diverge: a block
+    is dead when (causal) even the LAST q row precedes the FIRST k row,
+    or (window) even the FIRST q row is past the LAST k row's window."""
+    if not causal:
+        return True
+    live = q_off + iq * block_q + block_q - 1 >= k_off + k_base
+    if window is not None:
+        live &= q_off + iq * block_q - (k_off + k_base + block_k - 1) < window
+    return live
+
+
+def _is_static(offset) -> bool:
+    """An offset known while the call is traced (a Python int), against
+    one that is a value of the program (``axis_index`` on a ring)."""
+    return isinstance(offset, (int, np.integer))
+
+
+def _grid_extent(sq, sk, block_q, block_k, causal, window, static, order):
+    """``(nq, nk, rows, width)`` of a kernel's list: the blocks of both
+    axes, the outer blocks of ``order`` and the most steps one of them
+    is given. With ``delta`` known the list is exact and a row may hold
+    every inner block; with it traced the length has to be fixed before
+    the offsets are: a window row of ``b`` positions sees ``window + b -
+    1`` of the inner axis, which at any alignment touches at most
+    ``ceil((window + b - 2) / inner block) + 1`` blocks, and plain
+    causal or unmasked rows keep the whole axis."""
+    nq, nk = -(-sq // block_q), -(-sk // block_k)
+    (rows, b_outer), (n_inner, b_inner) = (nq, block_q), (nk, block_k)
+    if order == "k":
+        rows, b_outer, n_inner, b_inner = n_inner, b_inner, rows, b_outer
+    width = n_inner
+    if causal and window is not None and not static:
+        width = min(n_inner, -(-(window + b_outer - 2) // b_inner) + 1)
+    return nq, nk, rows, width
+
+
+def grid_tables(sq, sk, block_q, block_k, *, causal, window, delta,
+                order):
+    """``(outer, inner, live)``, int32 ``[steps]``: the pairs of blocks
+    a kernel visits, in its order. ``order="q"`` is the forward's and
+    dq's (``outer`` a q block, ``inner`` its k blocks, ascending),
+    ``order="k"`` dkv's (``outer`` a k block, ``inner`` its q blocks).
+    ``delta = q_offset - k_offset``.
+
+    The live set is ``_block_live`` over the rectangle and nothing else.
+    Every outer block has at least one step, so its output is written
+    even where no pair of it is kept; that step has ``live`` 0.
+    A ``delta`` that is a Python int gives NumPy tables of exactly the
+    live pairs. A traced one gives ``jnp`` tables whose length is fixed
+    by ``_grid_extent``: a row's spare steps repeat its last live pair
+    with ``live`` 0, so they copy nothing (the block index does not
+    change) and compute nothing."""
+    static = _is_static(delta)
+    xp = np if static else jnp
+    nq, nk, rows, width = _grid_extent(
+        sq, sk, block_q, block_k, causal, window, static, order
+    )
+    live = xp.broadcast_to(
+        _block_live(
+            delta, xp.arange(nq)[:, None], block_q,
+            0, xp.arange(nk)[None, :] * block_k, block_k, causal, window,
+        ),
+        (nq, nk),
+    )
+    if order == "k":
+        live = live.T
+    count = live.sum(axis=1)
+    # a row's live inner blocks first, ascending (the sort is stable)
+    live_first = (
+        np.argsort(~live, axis=1, kind="stable") if static
+        else jnp.argsort(~live, axis=1, stable=True)
+    )[:, :width]
+    t = xp.arange(width)[None, :]
+    inner = xp.take_along_axis(
+        live_first, xp.minimum(t, xp.maximum(count - 1, 0)[:, None]), axis=1
+    )
+    outer = xp.broadcast_to(xp.arange(rows)[:, None], inner.shape)
+    flag = t < count[:, None]
+    if static:
+        keep = t < np.maximum(count, 1)[:, None]
+        outer, inner, flag = outer[keep], inner[keep], flag[keep]
+    return tuple(
+        x.reshape(-1).astype(xp.int32) for x in (outer, inner, flag)
+    )
+
+
+def grid_steps(sq, sk, block_q, block_k, *, causal, window, delta, order):
+    """``(visited, live)`` a head: the steps of ``grid_tables``' list
+    and how many of them hold a kept pair. ``delta=None`` stands for a
+    traced one: ``visited`` is then the list's fixed length and ``live``,
+    which only the run knows, is None."""
+    if delta is None:
+        _, _, rows, width = _grid_extent(
+            sq, sk, block_q, block_k, causal, window, False, order
+        )
+        return rows * width, None
+    outer, _, live = grid_tables(
+        sq, sk, block_q, block_k, causal=causal, window=window,
+        delta=int(delta), order=order,
+    )
+    return int(outer.shape[0]), int(live.sum())
+
+
+def _grid_step(outer_ref, inner_ref):
+    """``(outer, inner, first, last)`` of this step of the list: its
+    pair of blocks, and whether it opens and closes its outer block's
+    run, where the accumulators are initialised and written."""
+    step, n = pl.program_id(1), pl.num_programs(1)
+    row = outer_ref[step]
+    first = (step == 0) | (outer_ref[jnp.maximum(step - 1, 0)] != row)
+    last = (step == n - 1) | (outer_ref[jnp.minimum(step + 1, n - 1)] != row)
+    return row, inner_ref[step], first, last
+
+
+# ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(
-    qo_ref, ko_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
-    acc_ref, m_ref, l_ref, *, causal, scale, nk, k_len, block_q, block_k,
-    window,
+    outer_ref, inner_ref, live_ref, off_ref, q_ref, k_ref, v_ref, out_ref,
+    lse_ref, acc_ref, m_ref, l_ref, *, causal, scale, k_len, block_q,
+    block_k, window,
 ):
-    ik = pl.program_id(2)
+    iq, ik, first, last = _grid_step(outer_ref, inner_ref)
 
-    @pl.when(ik == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    iq = pl.program_id(1)
-    q_off = qo_ref[0, 0]
-    k_off = ko_ref[0, 0]
+    q_off = off_ref[0]
+    k_off = off_ref[1]
     q_pos = q_off + iq * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_q), 1
     )
     k_base = ik * block_k
-    live = _block_live(q_off, iq, block_q, k_off, k_base, block_k, causal, window)
 
-    @pl.when(live)
+    @pl.when(live_ref[pl.program_id(1)] != 0)
     def _update():
         # dot OPERANDS stay in the input dtype (bf16 runs the MXU in one
         # pass; an f32 upcast would force multi-pass emulation) while
@@ -164,7 +288,7 @@ def _fwd_kernel(
         )
         m_ref[...] = m_new
 
-    @pl.when(ik == nk - 1)
+    @pl.when(last)
     def _write():
         l = l_ref[...]
         out_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(
@@ -179,19 +303,6 @@ def _fwd_kernel(
 # ---------------------------------------------------------------------------
 # backward kernels
 # ---------------------------------------------------------------------------
-
-
-def _block_live(q_off, iq, block_q, k_off, k_base, block_k, causal, window):
-    """Whole-block skip predicate, shared by the forward and BOTH backward
-    kernels so the bound can never diverge between them: a block is dead
-    when (causal) even the LAST q row precedes the FIRST k row, or
-    (window) even the FIRST q row is past the LAST k row's window."""
-    if not causal:
-        return True
-    live = q_off + iq * block_q + block_q - 1 >= k_off + k_base
-    if window is not None:
-        live &= q_off + iq * block_q - (k_off + k_base + block_k - 1) < window
-    return live
 
 
 def _recompute_pt(q, k, lse_blk, *, causal, scale, q_pos, k_pos, k_len,
@@ -215,23 +326,20 @@ def _recompute_pt(q, k, lse_blk, *, causal, scale, q_pos, k_pos, k_len,
 
 
 def _bwd_dq_kernel(
-    qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, c_ref, dq_ref,
-    acc_ref, *, causal, scale, nk, k_len, block_q, block_k, window,
+    outer_ref, inner_ref, live_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
+    lse_ref, c_ref, dq_ref, acc_ref, *, causal, scale, k_len, block_q,
+    block_k, window,
 ):
-    ik = pl.program_id(2)
+    iq, ik, first, last = _grid_step(outer_ref, inner_ref)
 
-    @pl.when(ik == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    iq = pl.program_id(1)
-    q_off = qo_ref[0, 0]
-    k_off = ko_ref[0, 0]
-    live = _block_live(
-        q_off, iq, block_q, k_off, ik * block_k, block_k, causal, window
-    )
+    q_off = off_ref[0]
+    k_off = off_ref[1]
 
-    @pl.when(live)
+    @pl.when(live_ref[pl.program_id(1)] != 0)
     def _update():
         # native-dtype dot operands, f32 accumulation + f32 softmax math
         # (see _fwd_kernel's precision note); ds is cast back to the
@@ -260,31 +368,27 @@ def _bwd_dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(ik == nk - 1)
+    @pl.when(last)
     def _write():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
-    qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, c_ref,
-    dk_ref, dv_ref, dk_acc, dv_acc, *, causal, scale, nq, k_len,
-    block_q, block_k, window,
+    outer_ref, inner_ref, live_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
+    lse_ref, c_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, causal, scale,
+    k_len, block_q, block_k, window,
 ):
-    iq = pl.program_id(2)  # q innermost here
+    ik, iq, first, last = _grid_step(outer_ref, inner_ref)  # q innermost
 
-    @pl.when(iq == 0)
+    @pl.when(first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    ik = pl.program_id(1)
-    q_off = qo_ref[0, 0]
-    k_off = ko_ref[0, 0]
-    live = _block_live(
-        q_off, iq, block_q, k_off, ik * block_k, block_k, causal, window
-    )
+    q_off = off_ref[0]
+    k_off = off_ref[1]
 
-    @pl.when(live)
+    @pl.when(live_ref[pl.program_id(1)] != 0)
     def _update():
         # native-dtype dot operands, f32 accumulation + f32 softmax math
         # (see _fwd_kernel's precision note)
@@ -315,7 +419,7 @@ def _bwd_dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(iq == nq - 1)
+    @pl.when(last)
     def _write():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -345,22 +449,61 @@ def _blocks(sq: int, sk: int, block_q: int, block_k: int):
 
 
 def _grid_params(interpret: bool):
-    """Grid semantics for Mosaic: batch*heads and the outer block axis
-    are parallel (independent accumulator streams — Mosaic may pipeline
-    and reorder them); the innermost axis is 'arbitrary' (sequential:
-    it carries the online-softmax / accumulator recurrence across
-    iterations). Interpret mode takes no compiler params."""
+    """Grid semantics for Mosaic: batch*heads is parallel (independent
+    accumulator streams); the axis of steps is 'arbitrary' (sequential:
+    it carries the online-softmax / accumulator recurrence from a row's
+    first pair to its last). Interpret mode takes no compiler params."""
     if interpret:
         return {}
     return {
         "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel", "arbitrary")
         )
     }
 
 
+def _listed_grid(kernel, sq, sk, bq, bk, q_offset, k_offset, *, bh, causal,
+                 window, static_delta, order):
+    """The scalar-prefetch operands of one kernel, ``(outer, inner,
+    live, offsets)``, and its grid ``(bh, steps)``; ticks the kernel's
+    series of ``ps_flash_grid_steps``."""
+    tables = grid_tables(
+        sq, sk, bq, bk, causal=causal, window=window,
+        delta=(
+            q_offset - k_offset if static_delta is None else static_delta
+        ),
+        order=order,
+    )
+    steps = tables[0].shape[0]
+    _count_grid_steps(
+        kernel, steps,
+        None if static_delta is None else int(tables[2].sum()),
+    )
+    offsets = jnp.stack([q_offset, k_offset]).astype(jnp.int32)
+    return (*map(jnp.asarray, tables), offsets), (bh, steps)
+
+
+def _count_grid_steps(kernel, visited, live):
+    from ..telemetry.instruments import cached_flash_instruments
+
+    tel = cached_flash_instruments()
+    if tel is None:
+        return
+    tel["grid_steps"].labels(kernel=kernel, what="visited").inc(visited)
+    if live is not None:
+        tel["grid_steps"].labels(kernel=kernel, what="live").inc(live)
+
+
+# index maps of the listed grid: (b, step, *scalar-prefetch refs). The
+# q-side blocks follow the table that holds q blocks, the k side the other
+def _at(table, transposed=False):
+    if transposed:
+        return lambda b, s, *refs: (b, 0, refs[table][s])
+    return lambda b, s, *refs: (b, refs[table][s], 0)
+
+
 def _fwd_pallas(q, k, v, q_offset, k_offset, *, causal, block_q, block_k,
-                interpret, window):
+                interpret, window, static_delta):
     bh, sq, d = q.shape
     sk = k.shape[1]
     scale = 1.0 / np.sqrt(d)
@@ -369,45 +512,46 @@ def _fwd_pallas(q, k, v, q_offset, k_offset, *, causal, block_q, block_k,
     kp = _pad_to(_pad_to(k, 1, bk), 2, _LANE)
     vp = _pad_to(_pad_to(v, 1, bk), 2, _LANE)
     dp_ = qp.shape[2]
-    nq, nk = qp.shape[1] // bq, kp.shape[1] // bk
-    qo = q_offset.astype(jnp.int32).reshape(1, 1)
-    ko = k_offset.astype(jnp.int32).reshape(1, 1)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    prefetch, grid = _listed_grid(
+        "fwd", sq, sk, bq, bk, q_offset, k_offset, bh=bh, causal=causal,
+        window=window, static_delta=static_delta, order="q",
+    )
     out_t, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, causal=causal, scale=scale, nk=nk, k_len=sk,
+            _fwd_kernel, causal=causal, scale=scale, k_len=sk,
             block_q=bq, block_k=bk, window=window,
         ),
-        grid=(bh, nq, nk),
-        in_specs=[
-            smem,
-            smem,
-            pl.BlockSpec((1, bq, dp_), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, dp_), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, dp_), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, dp_, bq), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, _SUBLANE, bq), lambda b, i, j: (b, 0, i)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, bq, dp_), _at(0)),
+                pl.BlockSpec((1, bk, dp_), _at(1)),
+                pl.BlockSpec((1, bk, dp_), _at(1)),
+            ],
+            out_specs=(
+                pl.BlockSpec((1, dp_, bq), _at(0, transposed=True)),
+                pl.BlockSpec((1, _SUBLANE, bq), _at(0, transposed=True)),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((dp_, bq), jnp.float32),
+                pltpu.VMEM((1, bq), jnp.float32),
+                pltpu.VMEM((1, bq), jnp.float32),
+            ],
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, dp_, qp.shape[1]), q.dtype),
             jax.ShapeDtypeStruct((bh, _SUBLANE, qp.shape[1]), jnp.float32),
         ),
-        scratch_shapes=[
-            pltpu.VMEM((dp_, bq), jnp.float32),
-            pltpu.VMEM((1, bq), jnp.float32),
-            pltpu.VMEM((1, bq), jnp.float32),
-        ],
         interpret=interpret,
         **_grid_params(interpret),
-    )(qo, ko, qp, kp, vp)
+    )(*prefetch, qp, kp, vp)
     out = jnp.swapaxes(out_t, 1, 2)[:, :sq, :d]
     return out, lse[:, 0, :sq]
 
 
 def _bwd_pallas(q, k, v, do, lse, c, q_offset, k_offset, *, causal,
-                block_q, block_k, interpret, window):
+                block_q, block_k, interpret, window, static_delta):
     bh, sq, d = q.shape
     sk = k.shape[1]
     scale = 1.0 / np.sqrt(d)
@@ -429,52 +573,61 @@ def _bwd_pallas(q, k, v, do, lse, c, q_offset, k_offset, *, causal,
     lsep = jnp.broadcast_to(lsep[:, None, :], (bh, _SUBLANE, lsep.shape[1]))
     cp = jnp.broadcast_to(cp[:, None, :], (bh, _SUBLANE, cp.shape[1]))
     dp_ = qp.shape[2]
-    nq, nk = qp.shape[1] // bq, kp.shape[1] // bk
-    qo = q_offset.astype(jnp.int32).reshape(1, 1)
-    ko = k_offset.astype(jnp.int32).reshape(1, 1)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    qspec = pl.BlockSpec((1, bq, dp_), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, bk, dp_), lambda b, i, j: (b, j, 0))
-    vec_q = pl.BlockSpec((1, _SUBLANE, bq), lambda b, i, j: (b, 0, i))
+    listed = functools.partial(
+        _listed_grid, sq=sq, sk=sk, bq=bq, bk=bk, q_offset=q_offset,
+        k_offset=k_offset, bh=bh, causal=causal, window=window,
+        static_delta=static_delta,
+    )
+    static = dict(
+        causal=causal, scale=scale, k_len=sk, block_q=bq, block_k=bk,
+        window=window,
+    )
+
+    def specs(q_table, k_table):
+        qspec = pl.BlockSpec((1, bq, dp_), _at(q_table))
+        kspec = pl.BlockSpec((1, bk, dp_), _at(k_table))
+        vec_q = pl.BlockSpec((1, _SUBLANE, bq), _at(q_table, transposed=True))
+        return [qspec, kspec, kspec, qspec, vec_q, vec_q]
+
+    # dq: q blocks outer, k blocks inner (accumulated)
+    prefetch, grid = listed("dq", order="q")
     dq_t = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, causal=causal, scale=scale, nk=nk, k_len=sk,
-            block_q=bq, block_k=bk, window=window,
+        functools.partial(_bwd_dq_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=specs(0, 1),
+            out_specs=pl.BlockSpec((1, dp_, bq), _at(0, transposed=True)),
+            scratch_shapes=[pltpu.VMEM((dp_, bq), jnp.float32)],
         ),
-        grid=(bh, nq, nk),
-        in_specs=[smem, smem, qspec, kspec, kspec, qspec, vec_q, vec_q],
-        out_specs=pl.BlockSpec((1, dp_, bq), lambda b, i, j: (b, 0, i)),
         out_shape=jax.ShapeDtypeStruct((bh, dp_, qp.shape[1]), q.dtype),
-        scratch_shapes=[pltpu.VMEM((dp_, bq), jnp.float32)],
         interpret=interpret,
         **_grid_params(interpret),
-    )(qo, ko, qp, kp, vp, dop, lsep, cp)
-    # dkv: k blocks outer (parallel), q blocks inner (accumulated)
-    qspec2 = pl.BlockSpec((1, bq, dp_), lambda b, j, i: (b, i, 0))
-    kspec2 = pl.BlockSpec((1, bk, dp_), lambda b, j, i: (b, j, 0))
-    vec_q2 = pl.BlockSpec((1, _SUBLANE, bq), lambda b, j, i: (b, 0, i))
+    )(*prefetch, qp, kp, vp, dop, lsep, cp)
+    # dkv: k blocks outer, q blocks inner (accumulated)
+    prefetch, grid = listed("dkv", order="k")
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, causal=causal, scale=scale, nq=nq, k_len=sk,
-            block_q=bq, block_k=bk, window=window,
-        ),
-        grid=(bh, nk, nq),
-        in_specs=[smem, smem, qspec2, kspec2, kspec2, qspec2, vec_q2, vec_q2],
-        out_specs=(
-            pl.BlockSpec((1, bk, dp_), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, dp_), lambda b, j, i: (b, j, 0)),
+        functools.partial(_bwd_dkv_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=specs(1, 0),
+            out_specs=(
+                pl.BlockSpec((1, bk, dp_), _at(0)),
+                pl.BlockSpec((1, bk, dp_), _at(0)),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((bk, dp_), jnp.float32),
+                pltpu.VMEM((bk, dp_), jnp.float32),
+            ],
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, kp.shape[1], dp_), k.dtype),
             jax.ShapeDtypeStruct((bh, kp.shape[1], dp_), v.dtype),
         ),
-        scratch_shapes=[
-            pltpu.VMEM((bk, dp_), jnp.float32),
-            pltpu.VMEM((bk, dp_), jnp.float32),
-        ],
         interpret=interpret,
         **_grid_params(interpret),
-    )(qo, ko, qp, kp, vp, dop, lsep, cp)
+    )(*prefetch, qp, kp, vp, dop, lsep, cp)
     dq = jnp.swapaxes(dq_t, 1, 2)[:, :sq, :d]
     return dq, dk[:, :sk, :d], dv[:, :sk, :d]
 
@@ -485,15 +638,18 @@ def _bwd_pallas(q, k, v, do, lse, c, q_offset, k_offset, *, causal,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11)
 )
 def _flash(q, k, v, q_offset, k_offset, causal, block_q, block_k,
-           use_pallas, interpret, window):
+           use_pallas, interpret, window, static_delta):
+    """``static_delta`` is ``q_offset - k_offset`` where both arrived as Python
+    ints, else None: what the grid's list is built from, static beside
+    the arrays the kernels mask by."""
     if use_pallas:
         return _fwd_pallas(
             q, k, v, q_offset, k_offset, causal=causal,
             block_q=block_q, block_k=block_k, interpret=interpret,
-            window=window,
+            window=window, static_delta=static_delta,
         )
     return flash_attention_ref(
         q, k, v, q_offset, k_offset, causal=causal, window=window
@@ -513,10 +669,10 @@ FLASH_LSE = "flash_attention_lse"
 
 
 def _flash_fwd(q, k, v, q_offset, k_offset, causal, block_q, block_k,
-               use_pallas, interpret, window):
+               use_pallas, interpret, window, static_delta):
     out, lse = _flash(
         q, k, v, q_offset, k_offset, causal, block_q, block_k,
-        use_pallas, interpret, window,
+        use_pallas, interpret, window, static_delta,
     )
     out = checkpoint_name(out, FLASH_OUT)
     lse = checkpoint_name(lse, FLASH_LSE)
@@ -524,7 +680,7 @@ def _flash_fwd(q, k, v, q_offset, k_offset, causal, block_q, block_k,
 
 
 def _flash_bwd(causal, block_q, block_k, use_pallas, interpret, window,
-               res, ct):
+               static_delta, res, ct):
     q, k, v, out, lse, q_offset, k_offset = res
     do, dlse = ct
     do32 = do.astype(jnp.float32)
@@ -538,7 +694,7 @@ def _flash_bwd(causal, block_q, block_k, use_pallas, interpret, window,
         dq, dk, dv = _bwd_pallas(
             q, k, v, do, lse, c, q_offset, k_offset, causal=causal,
             block_q=block_q, block_k=block_k, interpret=interpret,
-            window=window,
+            window=window, static_delta=static_delta,
         )
     else:
         scale = 1.0 / np.sqrt(q.shape[-1])
@@ -597,12 +753,19 @@ def flash_attention(
     interpret mode and is for tests only: nothing selects it implicitly.
 
     ``q_offset``/``k_offset`` are the GLOBAL sequence positions of row 0
-    (traced values allowed — ring attention passes ``axis_index``-derived
-    offsets), so causal masking is correct on sequence-sharded chunks.
-    ``window`` (requires causal) restricts each query to the ``window``
-    most recent keys (0 <= q_pos - k_pos < window — sliding-window /
-    local attention); out-of-window BLOCKS are skipped entirely, so
-    compute per query is O(window), not O(S).
+    (traced values allowed — ring attention over several devices passes
+    ``axis_index``-derived offsets), so causal masking is correct on
+    sequence-sharded chunks. ``window`` (requires causal) restricts each
+    query to the ``window`` most recent keys (0 <= q_pos - k_pos < window
+    — sliding-window / local attention).
+
+    Blocks the mask drops whole (past the diagonal, out of the window)
+    are not on the kernels' grid: neither fetched nor stepped over, so
+    compute AND traffic per query are O(window), not O(S). Offsets that
+    arrive as Python ints give the exact list of live blocks; traced
+    ones a list of a fixed length (a window: the most blocks a row can
+    touch; plain causal: the rectangle) whose spare steps fetch and
+    compute nothing (``grid_tables``).
     Returns ``out`` or ``(out, lse)`` — lse is what chunk-merging needs.
     """
     if window is not None:
@@ -622,11 +785,14 @@ def flash_attention(
         block_k = min(block_k, cap)
     if use_pallas is None:
         use_pallas = _on_tpu()
+    static_delta = None
+    if _is_static(q_offset) and _is_static(k_offset):
+        static_delta = int(q_offset) - int(k_offset)
     q_offset = jnp.asarray(q_offset, jnp.int32)
     k_offset = jnp.asarray(k_offset, jnp.int32)
     out, lse = _flash(
         q, k, v, q_offset, k_offset, causal, block_q, block_k,
-        bool(use_pallas), bool(interpret), window,
+        bool(use_pallas), bool(interpret), window, static_delta,
     )
     return (out, lse) if with_lse else out
 

@@ -399,6 +399,23 @@ def ftrl_instruments(reg: MetricsRegistry) -> Dict[str, object]:
     }
 
 
+def flash_instruments(reg: MetricsRegistry) -> Dict[str, object]:
+    """The flash kernels' grids (ops/flash_attention.py), counted on the
+    host where a call is traced: the list of block pairs is made there,
+    and a traced program's kernels keep it for every run."""
+    return {
+        "grid_steps": reg.ensure_gauge(
+            "ps_flash_grid_steps",
+            "grid steps a head of the flash kernels traced so far, by "
+            "kernel (fwd / dq / dkv): visited = pairs of blocks the grid "
+            "steps through, live = those of them that can hold a kept "
+            "pair (no series where the offsets are traced: only the run "
+            "knows); visited - live steps write an output no key reaches",
+            labelnames=("kernel", "what"),
+        ),
+    }
+
+
 def device_instruments(reg: MetricsRegistry) -> Dict[str, object]:
     """Device truth plane (telemetry/device.py): per-jit compile and
     recompile counts from the compiled-function inventory, the runtime
@@ -1098,6 +1115,7 @@ cached_kvops_instruments = _cached_family(kvops_instruments)
 cached_serve_instruments = _cached_family(serve_instruments)
 cached_wire_instruments = _cached_family(wire_instruments)
 cached_ftrl_instruments = _cached_family(ftrl_instruments)
+cached_flash_instruments = _cached_family(flash_instruments)
 cached_device_instruments = _cached_family(device_instruments)
 cached_learning_instruments = _cached_family(learning_instruments)
 cached_blackbox_instruments = _cached_family(blackbox_instruments)
@@ -1116,6 +1134,7 @@ INSTRUMENT_FAMILIES = (
     wire_instruments,
     serve_instruments,
     ftrl_instruments,
+    flash_instruments,
     device_instruments,
     learning_instruments,
     recovery_instruments,

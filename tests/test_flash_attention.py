@@ -28,8 +28,9 @@ from parameter_server_tpu.parallel.mesh import make_mesh
 
 # Promoted to the slow tier (PR 2, per the PR-1 ROADMAP note): the
 # shard_map-shim unlock made the full 'not slow' suite overrun the
-# 870s tier-1 budget on a 2-core host. Run via `pytest -m slow`.
-pytestmark = pytest.mark.slow
+# 870s tier-1 budget on a 2-core host. Run via `pytest -m slow`. The
+# listed grid's tests at the end of the file are tier-1.
+slow = pytest.mark.slow
 
 
 def _rand(shape, seed=0):
@@ -38,6 +39,7 @@ def _rand(shape, seed=0):
     )
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("qo,ko", [(0, 0), (64, 0), (0, 128)])
 def test_flash_kernel_matches_ref(causal, qo, ko):
@@ -56,6 +58,7 @@ def test_flash_kernel_matches_ref(causal, qo, ko):
     np.testing.assert_allclose(lse_ref, lse_pal, atol=2e-5, rtol=1e-5)
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize(
     "sq,sk,d",
@@ -86,6 +89,7 @@ def test_flash_kernel_gradients(causal, sq, sk, d):
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-4)
 
 
+@slow
 def test_flash_kernel_d128_fwd():
     """d=128 forward parity (grad coverage lives in the parametrized
     test_flash_kernel_gradients shape (160, 192, 128))."""
@@ -98,6 +102,7 @@ def test_flash_kernel_d128_fwd():
     np.testing.assert_allclose(o_ref, o_pal, atol=2e-5, rtol=1e-5)
 
 
+@slow
 def test_flash_ref_matches_dense():
     bh, s, d = 2, 96, 32
     q, k, v = _rand((bh, s, d), 1), _rand((bh, s, d), 2), _rand((bh, s, d), 3)
@@ -110,6 +115,7 @@ def test_flash_ref_matches_dense():
         )
 
 
+@slow
 def test_flash_fully_masked_chunk_is_zero_with_neg_lse():
     # a kv chunk entirely AFTER the queries (ring hop k_offset > q rows):
     # every row is masked — out must be exactly 0 and lse ~ -inf so the
@@ -124,6 +130,7 @@ def test_flash_fully_masked_chunk_is_zero_with_neg_lse():
     assert float(jnp.max(lse)) < -1e29
 
 
+@slow
 def test_flash_mha_matches_dense_mha():
     b, s, h, nh = 2, 80, 64, 4
     q, k, v = _rand((b, s, h), 1), _rand((b, s, h), 2), _rand((b, s, h), 3)
@@ -136,6 +143,7 @@ def test_flash_mha_matches_dense_mha():
         )
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_flash_matches_dense(causal):
     mesh = make_mesh(num_data=8, num_server=1)
@@ -149,6 +157,7 @@ def test_ring_flash_matches_dense(causal):
     )
 
 
+@slow
 def test_ring_flash_gradients_match_dense():
     mesh = make_mesh(num_data=4, num_server=1)
     b, s, h = 1, 64, 16
@@ -170,6 +179,7 @@ def test_ring_flash_gradients_match_dense():
         np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
 
 
+@slow
 def test_flash_kernel_gradients_through_lse():
     # exercises the dlse cotangent path IN THE PALLAS KERNELS (the ring
     # merge differentiates through lse; the c = delta - dlse folding in
@@ -213,6 +223,7 @@ def _kept_layer(policy):
     return grad, (x, wq, wo)
 
 
+@slow
 def test_a_policy_that_lists_the_residuals_runs_the_forward_kernel_once():
     """The forward rule names its output and log-sum-exp: a checkpoint
     policy that lists the names keeps them, and the gradient program
@@ -232,6 +243,7 @@ def test_a_policy_that_lists_the_residuals_runs_the_forward_kernel_once():
         np.testing.assert_array_equal(a, c)
 
 
+@slow
 @pytest.mark.parametrize("differentiated", [False, True])
 def test_the_names_change_no_program_without_a_checkpoint(
     differentiated, monkeypatch
@@ -273,6 +285,7 @@ def test_the_names_change_no_program_without_a_checkpoint(
     assert names == ([fa.FLASH_OUT, fa.FLASH_LSE] if differentiated else [])
 
 
+@slow
 def test_ring_flash_with_interpret_kernel_on_mesh():
     # the pallas kernel itself (interpret mode) under shard_map: one hop
     # per device with nonzero traced offsets
@@ -288,6 +301,7 @@ def test_ring_flash_with_interpret_kernel_on_mesh():
     )
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_zigzag_ring_matches_dense(causal):
     from parameter_server_tpu.models.attention import zigzag_permutation
@@ -308,6 +322,7 @@ def test_zigzag_ring_matches_dense(causal):
     )
 
 
+@slow
 def test_zigzag_gradients_match_dense():
     from parameter_server_tpu.models.attention import zigzag_permutation
 
@@ -334,6 +349,7 @@ def test_zigzag_gradients_match_dense():
         np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
 
 
+@slow
 def test_zigzag_permutation_roundtrip_and_validation():
     from parameter_server_tpu.models.attention import zigzag_permutation
 
@@ -358,6 +374,7 @@ def dense_swa(q, k, v, window):
     return jnp.einsum("bqk,bkh->bqh", p, v)
 
 
+@slow
 @pytest.mark.parametrize("window", [1, 16, 100])
 def test_sliding_window_kernel_matches_dense(window):
     bh, s, d = 2, 200, 48  # unaligned: exercises padding + block skip
@@ -370,6 +387,7 @@ def test_sliding_window_kernel_matches_dense(window):
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
 
 
+@slow
 def test_sliding_window_gradients():
     bh, s, d = 2, 136, 32
     q, k, v = _rand((bh, s, d), 1), _rand((bh, s, d), 2), _rand((bh, s, d), 3)
@@ -393,6 +411,7 @@ def test_sliding_window_gradients():
             np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-4)
 
 
+@slow
 @pytest.mark.parametrize("impl", ["flash", "zigzag"])
 def test_sliding_window_on_ring(impl):
     from parameter_server_tpu.models.attention import zigzag_permutation
@@ -419,6 +438,7 @@ def test_sliding_window_on_ring(impl):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
 
 
+@slow
 def test_window_validation():
     x = _rand((1, 16, 8), 0)
     with pytest.raises(ValueError, match="causal"):
@@ -432,6 +452,7 @@ def test_window_validation():
         )
 
 
+@slow
 @pytest.mark.parametrize("n_kv_heads", [1, 2])
 def test_gqa_matches_expanded_dense(n_kv_heads):
     # grouped-query attention == dense MHA with the K/V heads repeated
@@ -455,12 +476,14 @@ def test_gqa_matches_expanded_dense(n_kv_heads):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
 
 
+@slow
 def test_gqa_rejects_nondivisible():
     x = _rand((1, 16, 12), 0)
     with pytest.raises(ValueError, match="divide"):
         flash_mha(x, x, x, 4, n_kv_heads=3)
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_ulysses_flash_matches_dense(causal):
     from parameter_server_tpu.models.attention import ulysses_attention
@@ -477,6 +500,7 @@ def test_ulysses_flash_matches_dense(causal):
     )
 
 
+@slow
 def test_ulysses_flash_gradients_match_dense():
     from parameter_server_tpu.models.attention import ulysses_attention
 
@@ -501,6 +525,7 @@ def test_ulysses_flash_gradients_match_dense():
         np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
 
 
+@slow
 def test_ulysses_flash_sliding_window():
     from parameter_server_tpu.models.attention import ulysses_attention
 
@@ -534,6 +559,7 @@ def test_ulysses_flash_sliding_window():
         )
 
 
+@slow
 def test_ulysses_rejects_bad_impl_and_stray_flags():
     from parameter_server_tpu.models.attention import ulysses_attention
 
@@ -549,6 +575,7 @@ def test_ulysses_rejects_bad_impl_and_stray_flags():
         )
 
 
+@slow
 def test_lm_ring_flash_mode_matches_ring():
     from parameter_server_tpu.models.transformer import (
         LMConfig,
@@ -569,3 +596,201 @@ def test_lm_ring_flash_mode_matches_ring():
     lr = lm_forward(params, toks, cfg_r, mesh)
     lf = lm_forward(params, toks, cfg_f, mesh)
     np.testing.assert_allclose(lr, lf, atol=2e-5, rtol=1e-5)
+
+
+# -- the listed grid (tier-1) ------------------------------------------------
+#
+# The kernels visit the block pairs of ``fa.grid_tables`` and no other:
+# exact where the offsets are Python ints, of a fixed length with skipped
+# spare steps where they are traced.
+
+MASKS = {
+    "causal": dict(causal=True),
+    "window1": dict(causal=True, window=1),
+    "window16": dict(causal=True, window=16),
+    "window100": dict(causal=True, window=100),
+    "full": dict(causal=False),
+}
+# (q_offset, k_offset): delta 0, a multiple of the block, not a multiple,
+# and keys ahead of every query (a fully masked chunk)
+OFFSETS = {"d0": (0, 0), "d128": (128, 0), "d70": (70, 0), "ahead": (0, 448)}
+# (sq, sk, block_q, block_k): square, sq != sk with blocks that differ,
+# lengths that are no multiple of their block
+SHAPES = {
+    "square": (256, 256, 64, 64),
+    "wide": (192, 320, 64, 128),
+    "ragged": (200, 136, 128, 64),
+}
+
+
+def _grid_case(mask, offsets, shape):
+    """What ``grid_tables`` is given for a case of the parity test: the
+    lengths, the blocks as ``flash_attention`` clamps them (a window caps
+    them at 128), the mask and ``delta``."""
+    sq, sk, block_q, block_k = SHAPES[shape]
+    bq, bk = fa._blocks(sq, sk, block_q, block_k)
+    qo, ko = OFFSETS[offsets]
+    kw = {"causal": False, "window": None, **MASKS[mask]}
+    return (sq, sk, bq, bk), kw, qo - ko
+
+
+@pytest.mark.parametrize("shape", list(SHAPES))
+@pytest.mark.parametrize("offsets", list(OFFSETS))
+@pytest.mark.parametrize("traced", [False, True], ids=["ints", "traced"])
+@pytest.mark.parametrize("mask", list(MASKS))
+def test_listed_grid_matches_ref(mask, traced, offsets, shape):
+    """Values, lse and the three gradients of the kernels on their listed
+    grid against the XLA path, the offsets as Python ints (the exact
+    list) and traced through ``jit`` (the bounded one)."""
+    sq, sk, block_q, block_k = SHAPES[shape]
+    qo, ko = OFFSETS[offsets]
+    bh, d = 2, 32
+    q, k, v = _rand((bh, sq, d), 1), _rand((bh, sk, d), 2), _rand((bh, sk, d), 3)
+    w, w_lse = _rand((bh, sq, d), 4), _rand((bh, sq), 5)
+
+    def both(use_pallas):
+        def loss(q, k, v, qo, ko):
+            out, lse = flash_attention(
+                q, k, v, q_offset=qo, k_offset=ko, block_q=block_q,
+                block_k=block_k, use_pallas=use_pallas, interpret=use_pallas,
+                with_lse=True, **MASKS[mask],
+            )
+            # a fully masked row's lse is the constant -1e30
+            kept = jnp.where(lse > -1e29, lse, 0.0)
+            return jnp.sum(out * w) + jnp.sum(kept * w_lse), (out, lse)
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+
+    (_, (o_ref, lse_ref)), g_ref = jax.jit(both(False))(q, k, v, qo, ko)
+    if traced:
+        (_, (o_pal, lse_pal)), g_pal = jax.jit(both(True))(
+            q, k, v, jnp.int32(qo), jnp.int32(ko)
+        )
+    else:  # the offsets stay Python ints inside the program
+        (_, (o_pal, lse_pal)), g_pal = jax.jit(
+            lambda q, k, v: both(True)(q, k, v, qo, ko)
+        )(q, k, v)
+    np.testing.assert_allclose(o_ref, o_pal, atol=2e-5, rtol=1e-5)
+    np.testing.assert_allclose(lse_ref, lse_pal, atol=2e-5, rtol=1e-5)
+    for a, b in zip(g_ref, g_pal):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-4)
+    if offsets == "ahead" and MASKS[mask]["causal"]:
+        assert float(jnp.max(jnp.abs(o_pal))) == 0.0
+        assert float(jnp.max(lse_pal)) < -1e29
+
+
+def _live_set(outer, inner, live, order):
+    pairs = {(int(o), int(i)) for o, i, f in zip(outer, inner, live) if f}
+    return pairs if order == "q" else {(i, o) for o, i in pairs}
+
+
+@pytest.mark.parametrize("shape", list(SHAPES))
+@pytest.mark.parametrize("offsets", list(OFFSETS))
+@pytest.mark.parametrize("mask", list(MASKS))
+def test_the_listed_pairs_are_block_live_over_the_rectangle(
+    mask, offsets, shape
+):
+    """The exact list (``delta`` an int) and the bounded one (traced)
+    hold every pair ``_block_live`` keeps, once, and flag no other; each
+    outer block has a step and its steps are consecutive; the bounded
+    list's spare steps repeat a pair, so they fetch nothing."""
+    (sq, sk, bq, bk), kw, delta = _grid_case(mask, offsets, shape)
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    want = {
+        (i, j) for i in range(nq) for j in range(nk)
+        if fa._block_live(delta, i, bq, 0, j * bk, bk, kw["causal"],
+                          kw["window"])
+    }
+    for order, n_outer in (("q", nq), ("k", nk)):
+        exact = fa.grid_tables(sq, sk, bq, bk, delta=delta, order=order, **kw)
+        bounded = jax.jit(
+            lambda d: fa.grid_tables(sq, sk, bq, bk, delta=d, order=order, **kw)
+        )(jnp.int32(delta))
+        visited, live = fa.grid_steps(
+            sq, sk, bq, bk, delta=delta, order=order, **kw
+        )
+        assert (visited, live) == (len(exact[0]), len(want))
+        assert visited <= nq * nk
+        unknown = fa.grid_steps(sq, sk, bq, bk, delta=None, order=order, **kw)
+        assert unknown == (len(bounded[0]), None) and unknown[0] <= nq * nk
+        for outer, inner, flag in (exact, np.asarray(bounded)):
+            assert _live_set(outer, inner, flag, order) == want
+            assert int(np.sum(flag)) == len(want)  # no pair twice
+            # every outer block, in ascending runs
+            assert np.all(np.diff(outer) >= 0)
+            assert sorted(set(outer.tolist())) == list(range(n_outer))
+            # a step that is not live repeats the blocks of the step
+            # before it, or opens the row of a block no key reaches
+            for s in np.flatnonzero(flag == 0):
+                if s and outer[s] == outer[s - 1]:
+                    assert inner[s] == inner[s - 1]
+                else:
+                    assert not np.any(flag[outer == outer[s]])
+
+
+@pytest.mark.parametrize("order", ["q", "k"])
+def test_grid_steps_at_the_cells_shapes(order):
+    """8,192 tokens in 512 x 512 blocks, the LM cells' attention: a
+    causal layer visits the 136 blocks of its triangle and a window
+    layer (1,024) its 45, where the rectangle has 256; with a traced
+    delta the window's list is 4 steps a row."""
+    at = dict(sq=8192, sk=8192, block_q=512, block_k=512, order=order)
+    assert fa.grid_steps(causal=True, window=None, delta=0, **at) == (136, 136)
+    assert fa.grid_steps(causal=True, window=1024, delta=0, **at) == (45, 45)
+    assert fa.grid_steps(causal=False, window=None, delta=0, **at) == (256, 256)
+    visited, live = fa.grid_steps(causal=True, window=1024, delta=None, **at)
+    assert visited == 64 and live is None
+    assert fa.grid_steps(
+        causal=True, window=None, delta=None, **at
+    ) == (256, None)
+    # a chunk whose keys are all ahead: one step a block, none live
+    assert fa.grid_steps(
+        causal=True, window=None, delta=-8192, **at
+    ) == (16, 0)
+
+
+def test_the_lm_step_on_one_device_lists_its_grids(flash_as_on_the_chip):
+    """The offsets reach the kernels as Python ints from a one-device
+    ring: the gradient program of a model with window and full layers
+    holds Pallas grids of the exact lists' lengths, and the gauge counts
+    as many live steps as visited ones."""
+    from conftest import jaxpr_eqns
+    from jax.sharding import Mesh
+
+    from parameter_server_tpu.models import transformer as tfm
+    from parameter_server_tpu.telemetry import registry as telreg
+
+    cfg = tfm.LMConfig(
+        vocab=256, d_model=64, n_heads=2, n_kv_heads=1, n_layers=3,
+        d_ff=64, window=32, attention="ring_flash",
+        layers=(("swa", "dense"), ("swa", "dense"), ("mha", "dense")),
+    )
+    params = tfm.init_lm(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((1, 1100), jnp.int32)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "server"))
+
+    def series():
+        state = telreg.default_registry().export_state()
+        return {
+            (s["labels"]["kernel"], s["labels"]["what"]): s["value"]
+            for s in state.get("ps_flash_grid_steps", {}).get("series", [])
+        }
+
+    before = series()
+    jaxpr = jax.make_jaxpr(
+        jax.grad(lambda p: tfm.lm_loss(p, tokens, cfg, mesh))
+    )(params).jaxpr
+    grids = sorted(
+        e.params["grid_mapping"].grid[1] for e in jaxpr_eqns(jaxpr)
+        if e.primitive.name == "pallas_call"
+    )
+    # window 32 caps the blocks at 128: 9 of them, each row its own block
+    # and the one before; full layers keep 512: a triangle of 3
+    assert fa.grid_steps(
+        1100, 1100, 128, 128, causal=True, window=32, delta=0, order="q"
+    ) == (17, 17)
+    assert grids == [6] * 3 + [17] * 6
+    after = series()
+    moved = {k: after[k] - before.get(k, 0.0) for k in after}
+    for kernel in ("fwd", "dq", "dkv"):
+        assert moved[kernel, "visited"] == moved[kernel, "live"] > 0
