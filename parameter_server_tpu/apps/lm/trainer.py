@@ -41,6 +41,9 @@ _RECORD_FIELDS = dict(zip(
 # that compile are not; a stall's line holds the 8 records before it.
 STALL_RATIO, STALL_EXCESS_S = 2.0, 0.5
 STALL_HISTORY, STALL_MIN_INTERVALS, STALL_CONTEXT = 32, 4, 8
+# the step's stat of a model whose routers have a selection bias: the
+# largest max - min of a layer's bias after the step's update
+EXPERT_BIAS_SPREAD = "expert_bias_spread"
 
 
 def load_description(path: str) -> dict:
@@ -55,12 +58,17 @@ def load_description(path: str) -> dict:
 def model_from_description(desc: dict, *, attention: str = "ring_flash",
                            remat: bool = False, bf16: bool = False):
     """An ``LMConfig`` for a description of one of ``MODEL_TYPES``, each
-    with a top-k expert layer in every layer, RMSNorm and an untied
-    head: ``mistral4`` (latent attention, shared experts beside the
-    routed), ``solar_open2`` (gated NoPE GQA layers at ``gqa_layers``,
-    gated delta-rule layers, "kda", everywhere else; shared experts) or
-    ``mellum`` (rotated GQA layers with a q/k norm, windowed or full by
-    ``layer_types``, rotary tables per kind; no shared expert)."""
+    with a top-k expert layer and RMSNorm: ``mistral4`` (latent
+    attention, shared experts beside the routed), ``solar_open2`` (gated
+    NoPE GQA layers at ``gqa_layers``, gated delta-rule layers, "kda",
+    everywhere else; shared experts), ``mellum`` (rotated GQA layers
+    with a q/k norm, windowed or full by ``layer_types``, rotary tables
+    per kind; no shared expert), all three with softmax routers in every
+    layer and an untied head, or ``lfm2_moe`` (gated short-convolution
+    layers, "conv", beside rotated GQA layers with a q/k norm by
+    ``layer_types``; a dense FFN in the first ``num_dense_layers``
+    layers; sigmoid routers whose expert bias picks and does not weight;
+    a tied head unless the description says otherwise)."""
     from ...models.moe import TopKMoEConfig
 
     kind = desc.get("model_type")
@@ -80,21 +88,32 @@ def model_from_description(desc: dict, *, attention: str = "ring_flash",
         raise ValueError("group-limited routing is not built")
     if desc.get("first_k_dense_replace", 0) != 0:
         raise ValueError(
-            "first_k_dense_replace != 0: leading dense layers are not "
-            "described here"
+            "first_k_dense_replace != 0: leading dense layers are built "
+            "from an lfm2_moe description's num_dense_layers only"
         )
     published, share = desc.get("published", {}), desc.get("share", {})
     # the experts held here and the router's width, under the family's
-    # key: ``n_routed_experts``, or ``num_experts`` (mellum)
-    experts = "num_experts" if kind == "mellum" else "n_routed_experts"
+    # key: ``n_routed_experts``, or ``num_experts`` (mellum, lfm2_moe)
+    experts = (
+        "num_experts" if kind in ("mellum", "lfm2_moe") else "n_routed_experts"
+    )
+    lfm2 = kind == "lfm2_moe"
+    # lfm2_moe scores by sigmoid and names no scoring key; its norm's
+    # epsilon is ``norm_eps``, and its head is tied where no key says
+    scoring = desc.get("scoring_func", "sigmoid" if lfm2 else "softmax")
     common = dict(
         vocab=desc["vocab_size"], d_model=desc["hidden_size"],
         n_heads=desc["num_attention_heads"],
         n_layers=desc["num_hidden_layers"], d_ff=desc["intermediate_size"],
         attention=attention, remat=remat,
         compute_dtype="bfloat16" if bf16 else "float32",
-        tie_head=desc["tie_word_embeddings"], norm="rmsnorm",
-        norm_eps=desc["rms_norm_eps"], ffn_act="swiglu", scale_emb=False,
+        tie_head=(
+            desc.get("tie_word_embeddings", True) if lfm2
+            else desc["tie_word_embeddings"]
+        ),
+        norm="rmsnorm",
+        norm_eps=desc["norm_eps"] if lfm2 else desc["rms_norm_eps"],
+        ffn_act="swiglu", scale_emb=False,
         moe=TopKMoEConfig(
             n_experts=published.get(experts, desc[experts]),
             top_k=desc["num_experts_per_tok"],
@@ -106,6 +125,8 @@ def model_from_description(desc: dict, *, attention: str = "ring_flash",
             routed_scaling_factor=float(
                 desc.get("routed_scaling_factor", 1.0)
             ),
+            scoring=scoring,
+            expert_bias=bool(desc.get("use_expert_bias", False)),
         ),
     )
     return _ATTENTION_OF[kind](desc, common)
@@ -252,8 +273,57 @@ def _mellum(desc: dict, common: dict):
     )
 
 
+def _lfm2_moe(desc: dict, common: dict):
+    """Layer ``i`` is a gated short convolution of ``conv_L_cache`` taps
+    where ``layer_types[i]`` is ``conv`` ("conv") and a GQA layer with a
+    q/k norm under one plain rotary table where it is
+    ``full_attention`` ("mha"); its FFN is the dense SwiGLU of
+    ``intermediate_size`` in the first ``num_dense_layers`` layers and
+    the expert layer in the others."""
+    from ...models.transformer import LMConfig
+
+    kinds = {"conv": "conv", "full_attention": "mha"}
+    types = desc["layer_types"]
+    if len(types) != common["n_layers"]:
+        raise ValueError(
+            f"layer_types describes {len(types)} layers, "
+            f"num_hidden_layers is {common['n_layers']}"
+        )
+    for name in types:
+        if name not in kinds:
+            raise ValueError(
+                f"layer_types: {name!r} is not one of {', '.join(kinds)}"
+            )
+    if desc.get("conv_bias"):
+        raise ValueError(
+            "conv_bias true: the short convolution is built without biases"
+        )
+    rp = desc["rope_parameters"]
+    rope_type = rp.get("rope_type", rp.get("type", "default"))
+    if rope_type != "default":
+        raise ValueError(
+            f"rope_type {rope_type!r}: an lfm2_moe description's attention "
+            "layers are built under the plain rotary table ('default') only"
+        )
+    dense = desc.get("num_dense_layers", 0)
+    if not 0 <= dense <= len(types):
+        raise ValueError(
+            f"num_dense_layers {dense} of {len(types)} layers"
+        )
+    return LMConfig(
+        **common, n_kv_heads=desc["num_key_value_heads"],
+        head_dim=desc.get("head_dim"), qk_norm=True, rope=True,
+        rope_theta=float(rp["rope_theta"]), conv_size=desc["conv_L_cache"],
+        layers=tuple(
+            (kinds[name], "dense" if i < dense else "moe")
+            for i, name in enumerate(types)
+        ),
+    )
+
+
 _ATTENTION_OF = {
     "mistral4": _mistral4, "solar_open2": _solar_open2, "mellum": _mellum,
+    "lfm2_moe": _lfm2_moe,
 }
 MODEL_TYPES = tuple(_ATTENTION_OF)
 
@@ -291,8 +361,9 @@ class Launch:
     loss: object  # device scalar: the launch's last step's loss
     # device arrays (``lm_forward_with_stats``): the counts
     # (``transformer.STEP_COUNTS``: ``expert_rows``, ``buffer_passes``,
-    # ``kda_scan_tokens``, ``attn_token_layers``) summed over the
-    # launch's steps; its last step's choices and router probes
+    # ``kda_scan_tokens``, ``attn_token_layers``, ``conv_token_layers``)
+    # summed over the launch's steps; its last step's choices and router
+    # probes
     stats: dict
     tokens: int
     flow: int = 0  # the launch's flow id: its four phases and its record
@@ -311,10 +382,12 @@ class Trainer:
         import jax
         import optax
 
+        from ...models.moe import EXPERT_LOAD, update_expert_bias
         from ...models.transformer import (
             STEP_COUNTS,
             lm_loss_and_stats,
             next_token_targets,
+            router_bias_names,
         )
         from ...telemetry import registry as telemetry_registry
 
@@ -323,6 +396,9 @@ class Trainer:
         self.zig = cfg.attention == "ring_zigzag"
         self.n_data = mesh.shape["data"]
         self.params = self.opt = None
+        # the routers' selection biases: leaves of the state that take no
+        # gradient and no optimizer step (``trained``); the step moves them
+        self.biases = router_bias_names(cfg)
         self._counters, self._loop_seconds = None, {}
         # the launch record, timed by ``clock`` (a test hands another)
         self.clock, self._collected = time.perf_counter, 0
@@ -350,12 +426,30 @@ class Trainer:
         def one(p, opt, tokens, *targets):
             if not targets:
                 targets = next_token_targets(tokens)
-            (loss, stats), g = jax.value_and_grad(
-                lm_loss_and_stats, has_aux=True
-            )(p, tokens, *targets, cfg, mesh, "data")
+            fixed = {k: p[k] for k in self.biases}
+
+            def loss_of(trained, *args):
+                return lm_loss_and_stats({**trained, **fixed}, *args)
+
+            trained = self.trained(p)
+            (loss, stats), g = jax.value_and_grad(loss_of, has_aux=True)(
+                trained, tokens, *targets, cfg, mesh, "data"
+            )
             with jax.named_scope("lm_opt"):
-                up, opt = tx.update(g, opt, p)
-                p = optax.apply_updates(p, up)
+                up, opt = tx.update(g, opt, trained)
+                p = optax.apply_updates(trained, up)
+            if self.biases:
+                # every column's load of this step, layer by layer as the
+                # stats stack the expert layers
+                with jax.named_scope("lm_moe_bias"):
+                    moved = update_expert_bias(
+                        jax.numpy.stack([fixed[k] for k in self.biases]),
+                        stats[EXPERT_LOAD],
+                    )
+                    p = {**p, **dict(zip(self.biases, moved))}
+                    stats = {**stats, EXPERT_BIAS_SPREAD: jax.numpy.max(
+                        moved.max(-1) - moved.min(-1)
+                    )}
             return p, opt, loss, stats
 
         if self.spl == 1:
@@ -383,6 +477,13 @@ class Trainer:
             self.step = step
 
     # -- state -------------------------------------------------------------
+
+    def trained(self, params: dict) -> dict:
+        """The leaves the gradient and the optimizer see: all but the
+        routers' biases (``params`` itself where there are none)."""
+        if not self.biases:
+            return params
+        return {k: v for k, v in params.items() if k not in self.biases}
 
     def init(self, seed: int, **placement) -> None:
         """Weights from ``seed``, made on the device (``init_lm``);
@@ -439,7 +540,8 @@ class Trainer:
             # moments) sharded over the data axis; composes with tensor
             # parallelism (those leaves keep their server dim)
             params = fsdp_shard_lm_params(params, self.mesh, "data")
-        opt = self.tx.init(params)  # zeros_like inherits each placement
+        # zeros_like inherits each placement
+        opt = self.tx.init(self.trained(params))
         if zero1:
             # ZeRO-1: moments sharded over the data axis (every leaf
             # comes back mesh-committed, scalars replicated)
@@ -516,11 +618,14 @@ class Trainer:
         ``expert_rows`` and ``buffer_passes`` where the model has the
         dropless layer, its ``kda_scan_tokens`` where it has the gated
         delta-rule layer, its ``attn_token_layers`` where it has
-        windowed layers beside full ones. The launch's choices and
-        probes stay on the device (``launch.stats``)."""
+        windowed layers beside full ones, its ``conv_token_layers``
+        where it has short-convolution layers; the routers' bias spread
+        after it (``EXPERT_BIAS_SPREAD``) where they have a bias. The
+        launch's choices and probes stay on the device (``launch.stats``)."""
         from ...models.transformer import (
             ATTN_TOKEN_LAYER_KINDS,
             ATTN_TOKEN_LAYERS,
+            CONV_TOKEN_LAYERS,
             KDA_SCAN_TOKENS,
             STEP_COUNTS,
         )
@@ -537,6 +642,14 @@ class Trainer:
                 if KDA_SCAN_TOKENS in counts:
                     self._counters["kda_scan_tokens"].inc(
                         int(counts[KDA_SCAN_TOKENS])
+                    )
+                if CONV_TOKEN_LAYERS in counts:
+                    self._counters["conv_token_layers"].inc(
+                        int(counts[CONV_TOKEN_LAYERS])
+                    )
+                if EXPERT_BIAS_SPREAD in launch.stats:
+                    self._counters["expert_bias_spread"].set(
+                        float(launch.stats[EXPERT_BIAS_SPREAD])
                     )
                 for kind, n in zip(
                     ATTN_TOKEN_LAYER_KINDS, counts.get(ATTN_TOKEN_LAYERS, ())

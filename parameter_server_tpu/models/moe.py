@@ -8,8 +8,9 @@ expert per shard), the 2-layer FFN runs as dense [E/n, n*C, d] batched
 matmuls, and tokens over capacity pass through on the residual path.
 
 ``topk_moe_ffn`` is the dropless layer of ``LMConfig.moe``
-(``TopKMoEConfig``): softmax over ALL ``n_experts``, the ``top_k``
-largest renormalised, no capacity and no dropped token, gated-SiLU
+(``TopKMoEConfig``): softmax over ALL ``n_experts`` (or a sigmoid of
+each, with a bias that picks the experts and does not weight them), the
+``top_k`` largest renormalised, no capacity and no dropped token, gated-SiLU
 experts beside an optional shared expert. The layer is told which
 experts it holds (``experts_held`` from ``expert_offset``): it routes
 over all of them and computes its own experts' part, which is what one
@@ -176,12 +177,29 @@ class TopKMoEConfig:
     expert_offset: int = 0
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # how a token scores the experts: "softmax" over all of them, or
+    # "sigmoid" of each logit on its own
+    scoring: str = "softmax"
+    # a bias [n_experts] (leaf ``router_bias``) added to the scores where
+    # the experts are chosen and nowhere else: the weights are the scores
+    # at the choices. No gradient reaches it; the train step moves it
+    # against each expert's load (``update_expert_bias``)
+    expert_bias: bool = False
 
     def __post_init__(self):
         if not 1 <= self.top_k <= self.n_experts:
             raise ValueError(
                 f"top_k {self.top_k} must be in [1, n_experts="
                 f"{self.n_experts}]"
+            )
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"scoring {self.scoring!r}: 'softmax' or 'sigmoid'"
+            )
+        if self.expert_bias and self.scoring != "sigmoid":
+            raise ValueError(
+                f"expert_bias with {self.scoring} scoring: the selection "
+                "bias is built for sigmoid scoring only"
             )
         if not (0 <= self.expert_offset
                 and self.expert_offset + self.held <= self.n_experts):
@@ -209,10 +227,13 @@ def init_topk_moe(key, d_model: int, cfg: TopKMoEConfig, std: float):
         shapes.update(
             ws_gate=(d_model, fs), ws_up=(d_model, fs), ws_down=(fs, d_model)
         )
-    return {
+    out = {
         name: std * jax.random.normal(k, shape, jnp.float32)
         for k, (name, shape) in zip(ks, shapes.items())
     }
+    if cfg.expert_bias:
+        out["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+    return out
 
 
 def grouped_matmul(x, w, group_sizes):
@@ -236,19 +257,27 @@ def swiglu(h, w_gate, w_up, w_down):
 TOP_E = "moe_top_e"
 
 
-def route_topk(h32, router, cfg: TopKMoEConfig):
-    """softmax over all experts in f32 (the matmul too: HIGHEST, or the
-    TPU rounds its inputs to bf16), the ``top_k`` largest, renormalised:
-    (weights [T, k] f32, experts [T, k] int32). The weights are read at
-    the choices as named (``TOP_E``), so that a layer rematerialised
-    under a policy that saves them routes as its forward pass did."""
+def route_topk(h32, router, cfg: TopKMoEConfig, bias=None):
+    """The scores (``cfg.scoring``: softmax over all experts, or a
+    sigmoid each) in f32 (the matmul too: HIGHEST, or the TPU rounds its
+    inputs to bf16), the ``top_k`` largest, renormalised: (weights
+    [T, k] f32, experts [T, k] int32). With ``bias`` [n_experts] the
+    experts are the ``top_k`` largest of scores + bias and the weights
+    the scores alone at them; no gradient reaches the bias. The weights
+    are read at the choices as named (``TOP_E``), so that a layer
+    rematerialised under a policy that saves them routes as its forward
+    pass did."""
     logits = jnp.dot(
         h32, router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    p = jax.nn.softmax(logits, axis=-1)
+    if cfg.scoring == "sigmoid":
+        p = jax.nn.sigmoid(logits)
+    else:
+        p = jax.nn.softmax(logits, axis=-1)
+    pick = p if bias is None else p + jax.lax.stop_gradient(bias)
     top_e = checkpoint_name(
-        jax.lax.top_k(p, cfg.top_k)[1].astype(jnp.int32), TOP_E
+        jax.lax.top_k(pick, cfg.top_k)[1].astype(jnp.int32), TOP_E
     )
     top_p = jnp.take_along_axis(p, top_e, axis=-1)
     if cfg.norm_topk_prob:
@@ -337,6 +366,9 @@ def sort_by_expert(top_e, cfg: TopKMoEConfig):
 # the stats of ``topk_moe_ffn`` that are counts: they add up over layers
 # and steps, where the others are kept by layer and of the last step
 MOE_COUNTS = ("expert_rows", "buffer_passes")
+# the stat of a layer with ``expert_bias``: how many tokens chose each of
+# ALL ``n_experts`` (held here or not), what its bias is moved against
+EXPERT_LOAD = "expert_load"
 
 # the router's input, choices and weights are returned at this many
 # tokens (evenly strided), for a caller that holds the router's
@@ -549,7 +581,8 @@ def topk_moe_ffn(lp, h2, cfg: TopKMoEConfig, dtype):
     this pass need it), ``top_e`` [T, k] int32 (the experts each token
     chose, of all ``n_experts``), and at every ``T // PROBE_TOKENS``-th
     token ``probe_x`` (the router's input as it read it), ``probe_e``
-    and ``probe_w`` (its choices and weights).
+    and ``probe_w`` (its choices and weights); with ``expert_bias`` also
+    ``expert_load`` [n_experts] int32 (``EXPERT_LOAD``).
 
     Named scopes: ``lm_moe_route`` (router, top-k, sort, gather and
     combine), ``lm_moe_experts`` (the grouped products), ``lm_moe_shared``.
@@ -557,7 +590,13 @@ def topk_moe_ffn(lp, h2, cfg: TopKMoEConfig, dtype):
     shape = h2.shape
     x = h2.reshape(-1, shape[-1]).astype(dtype)
     with jax.named_scope("lm_moe_route"):
-        top_w, top_e = route_topk(x.astype(jnp.float32), lp["router"], cfg)
+        # a layer without a bias calls ``route_topk(h, router, cfg)``:
+        # the planted router faults of chipbench/tests/test_lm_cell.py
+        # replace it with functions of those three arguments
+        bias = (lp["router_bias"],) if cfg.expert_bias else ()
+        top_w, top_e = route_topk(
+            x.astype(jnp.float32), lp["router"], cfg, *bias
+        )
         index = sort_by_expert(top_e, cfg)
     with jax.named_scope("lm_moe_experts"):
         # cast once, outside the conditional: the parts hand their
@@ -593,4 +632,31 @@ def topk_moe_ffn(lp, h2, cfg: TopKMoEConfig, dtype):
         "probe_e": top_e[::stride],
         "probe_w": jax.lax.stop_gradient(top_w[::stride]),
     }
+    if cfg.expert_bias:
+        stats[EXPERT_LOAD] = expert_load(top_e, cfg)
     return y.reshape(shape), stats
+
+
+def expert_load(top_e, cfg: TopKMoEConfig):
+    """[n_experts] int32: the assignments ``top_e`` [T, k] of each
+    expert, counted by comparison (see ``sort_by_expert``)."""
+    return jnp.sum(
+        top_e.reshape(-1)[:, None]
+        == jnp.arange(cfg.n_experts, dtype=top_e.dtype), axis=0,
+    ).astype(jnp.int32)
+
+
+# the step of ``update_expert_bias``: DeepSeek-V3's rate; no published
+# configuration of a model with a selection bias states one
+EXPERT_BIAS_RATE = 0.001
+
+
+def update_expert_bias(bias, load):
+    """The auxiliary-loss-free balancing step (DeepSeek-V3): every
+    expert's bias moves by ``EXPERT_BIAS_RATE`` towards the mean load,
+    ``b_e += rate * sign(mean(load) - load_e)`` over all the router's
+    columns; an expert at the mean stays where it is."""
+    load = load.astype(jnp.float32)
+    return bias + EXPERT_BIAS_RATE * jnp.sign(
+        jnp.mean(load, axis=-1, keepdims=True) - load
+    )

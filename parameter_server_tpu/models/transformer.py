@@ -36,18 +36,22 @@ from .moe import (
     topk_moe_ffn,
 )
 
-ATTENTION_KINDS = ("mha", "swa", "mla", "kda")
+ATTENTION_KINDS = ("mha", "swa", "mla", "kda", "conv")
 FFN_KINDS = ("dense", "switch", "moe")
 # the stats of ``lm_forward_with_stats`` that are counts: they add up
 # over layers and steps, where the others are kept by layer and of the
 # last step. ``kda_scan_tokens``: token-layers the recurrence of the
 # "kda" layers computed (forward count: tokens x such layers).
 # ``attn_token_layers`` [2]: tokens x "swa" layers and tokens x "mha"
-# layers, of a model that has both (``ATTN_TOKEN_LAYER_KINDS``)
+# layers, of a model that has both (``ATTN_TOKEN_LAYER_KINDS``).
+# ``conv_token_layers``: tokens x "conv" layers computed
 KDA_SCAN_TOKENS = "kda_scan_tokens"
 ATTN_TOKEN_LAYERS = "attn_token_layers"
 ATTN_TOKEN_LAYER_KINDS = ("window", "full")
-STEP_COUNTS = MOE_COUNTS + (KDA_SCAN_TOKENS, ATTN_TOKEN_LAYERS)
+CONV_TOKEN_LAYERS = "conv_token_layers"
+STEP_COUNTS = MOE_COUNTS + (
+    KDA_SCAN_TOKENS, ATTN_TOKEN_LAYERS, CONV_TOKEN_LAYERS,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,11 +181,16 @@ class LMConfig:
     # Attention "kda" is the gated delta-rule linear-attention layer
     # (models/kda.py, ``kda`` below): one chip's sequence only.
     # Attention "swa" is an "mha" layer that sees the last ``window``
-    # keys, beside "mha" layers that see them all
+    # keys, beside "mha" layers that see them all. Attention "conv" is
+    # a gated short convolution (LFM2's): [B | C | x~] = h W_in, then
+    # (C * conv(B * x~)) W_out, the convolution depthwise and causal over
+    # ``conv_size`` tokens (leaves ``conv_in`` [d, 3d], ``conv_taps``
+    # [conv_size, d], ``conv_out`` [d, d]); training only
     layers: "tuple | None" = None
     mla: "MLAConfig | None" = None
     moe: "TopKMoEConfig | None" = None
     kda: "KDAConfig | None" = None
+    conv_size: int = 3
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -217,6 +226,16 @@ class LMConfig:
                 )
         if any(a == "kda" for a, _ in kinds) and self.kda is None:
             raise ValueError("a 'kda' layer needs LMConfig.kda")
+        if any(a == "conv" for a, _ in kinds):
+            if self.attention == "ring_zigzag":
+                raise ValueError(
+                    "a 'conv' layer mixes neighbouring positions: the "
+                    "zigzag layout puts other tokens next to each other"
+                )
+            if self.conv_size < 1:
+                raise ValueError(
+                    f"LMConfig.conv_size must be >= 1, got {self.conv_size}"
+                )
         if any(a == "swa" for a, _ in kinds) and self.window is None:
             raise ValueError(
                 "a 'swa' layer needs LMConfig.window (the span it sees)"
@@ -299,7 +318,7 @@ def refuse_serving(cfg: LMConfig, where: str) -> None:
     """The cached forwards compute "mha" attention (heads of d_model /
     n_heads, no output gate, ONE ``cfg.window`` and one plain rotary
     table for every layer) over a K/V cache and a dense or switch FFN.
-    A layer of another kind ("mla", "kda", "moe"), windowed layers
+    A layer of another kind ("mla", "kda", "conv", "moe"), windowed layers
     beside full ones ("swa" beside "mha"), rotary tables per kind of
     layer or under YaRN, a q/k norm, an explicit head width or an output
     gate is refused by name: decoding it through those would be a
@@ -330,6 +349,13 @@ def refuse_serving(cfg: LMConfig, where: str) -> None:
                 f"{where}: the gated delta-rule layer ('kda') has no "
                 "decode path yet (its recurrent state and convolution "
                 "tail are not carried beside a K/V cache); this model "
+                "trains through lm_forward only"
+            )
+        if att == "conv":
+            raise NotImplementedError(
+                f"{where}: the gated short convolution ('conv') has no "
+                "decode path yet (the last conv_size - 1 rows of B * x~ a "
+                "layer are not carried beside a K/V cache); this model "
                 "trains through lm_forward only"
             )
         if att == "mla":
@@ -383,6 +409,16 @@ def init_lm(key: jax.Array, cfg: LMConfig) -> Dict[str, jax.Array]:
                 k1, cfg.d_model, cfg.kda, s
             ).items():
                 p[f"l{i}/{name}"] = w
+        elif att_kind == "conv":
+            d = cfg.d_model
+            p[f"l{i}/conv_in"] = s * jax.random.normal(k1, (d, 3 * d))
+            p[f"l{i}/conv_out"] = s * jax.random.normal(k2, (d, d))
+            # torch's Conv1d default: uniform over +-1/sqrt(taps)
+            bound = cfg.conv_size ** -0.5
+            p[f"l{i}/conv_taps"] = jax.random.uniform(
+                jax.random.fold_in(k1, 1), (cfg.conv_size, d),
+                minval=-bound, maxval=bound,
+            )
         else:
             # separate q/k/v projections (not a fused [d, 3d]): under
             # tensor parallelism each projection column-shards on its
@@ -422,6 +458,27 @@ def init_lm(key: jax.Array, cfg: LMConfig) -> Dict[str, jax.Array]:
             p[f"l{i}/w1"] = s * jax.random.normal(k3, (cfg.d_model, cfg.d_ff))
             p[f"l{i}/w2"] = s * jax.random.normal(k4, (cfg.d_ff, cfg.d_model))
     return jax.tree.map(lambda x: x.astype(jnp.float32), p)
+
+
+def conv_mix(gates, taps):
+    """The mix of a "conv" layer: ``gates`` [B, S, 3W] = [B | C | x~]
+    (h W_in, split in that order), the output C * conv(B * x~) [B, S, W],
+    the convolution depthwise and causal (``kda.causal_conv``: tap j of
+    ``taps`` [n, W] weighs position t - n + 1 + j), in ``gates.dtype``."""
+    b_gate, c_gate, xt = jnp.split(gates, 3, axis=-1)
+    return c_gate * kdalib.causal_conv(b_gate * xt, taps)
+
+
+def router_bias_names(cfg: LMConfig) -> tuple:
+    """The leaves of the routers' selection biases
+    (``TopKMoEConfig.expert_bias``), in the order in which the training
+    forward's stats stack the expert layers; none without such a bias."""
+    if cfg.moe is None or not cfg.moe.expert_bias:
+        return ()
+    return tuple(
+        f"l{i}/router_bias"
+        for i, (_, ffn) in enumerate(cfg.layer_kinds) if ffn == "moe"
+    )
 
 
 def _is_moe_layer(cfg: LMConfig, i: int) -> bool:
@@ -572,13 +629,20 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
     recurrence computed in this forward pass) and, stacked by such
     layer, ``probe_kda_state`` and ``probe_kda_g`` (models/kda.py: the
     recurrence's last state and log-decay, in the bits it computed with).
-    With "swa" layers also ``attn_token_layers`` [2] int32 (tokens x
-    "swa" layers, tokens x "mha" layers: ``ATTN_TOKEN_LAYER_KINDS``).
+    With "swa" or "conv" layers also ``attn_token_layers`` [2] int32
+    (tokens x "swa" layers, tokens x "mha" layers:
+    ``ATTN_TOKEN_LAYER_KINDS``); with "conv" layers ``conv_token_layers``
+    (int32: tokens x such layers). With a router bias (``TopKMoEConfig.expert_bias``) also
+    ``expert_load`` [layers, n_experts], what the step moves it against.
+    Layers without an expert layer add nothing to the stacks: their
+    index is the expert layers' own (a leading dense layer is not one).
 
     Named scopes, for the device trace: ``lm_attn`` (an "mha", "swa" or
     "mla" layer's projections and attention; in a model with "swa"
     layers ``attn_window`` or ``attn_full`` nested inside it, by the
-    layer's kind), ``lm_kda_*`` (models/kda.py), ``lm_ffn`` or
+    layer's kind), ``lm_kda_*`` (models/kda.py), ``lm_conv_proj`` (a
+    "conv" layer's norm and W_in), ``lm_conv_mix`` (the gates and the
+    causal convolution), ``lm_conv_out`` (W_out), ``lm_ffn`` or
     ``lm_moe_*`` (models/moe.py), ``lm_head``.
     """
     b, s = tokens.shape
@@ -719,6 +783,13 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
                 h, lp, cfg.kda, cfg.norm_eps, dtype
             )
             x = x + att
+        elif att_kind == "conv":
+            with jax.named_scope("lm_conv_proj"):
+                gates = norm(x, lp["ln1"]) @ cast("conv_in")
+            with jax.named_scope("lm_conv_mix"):
+                mixed = conv_mix(gates, cast("conv_taps"))
+            with jax.named_scope("lm_conv_out"):
+                x = x + mixed @ cast("conv_out")
         else:
             # a model with "swa" layers names each layer's kind
             inner = contextlib.nullcontext() if not n_swa else (
@@ -793,20 +864,22 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
         total[k] = sum(have) if k in MOE_COUNTS else jnp.stack(have)
     if n_kda:
         total[KDA_SCAN_TOKENS] = jnp.asarray(b * s * n_kda, jnp.int32)
-    if n_swa:
+    n_conv = sum(a == "conv" for a, _ in kinds)
+    if n_swa or n_conv:
         n_full = sum(a == "mha" for a, _ in kinds)
         total[ATTN_TOKEN_LAYERS] = jnp.asarray(
             [b * s * n_swa, b * s * n_full], jnp.int32
         )
+    if n_conv:
+        total[CONV_TOKEN_LAYERS] = jnp.asarray(b * s * n_conv, jnp.int32)
     with jax.named_scope("lm_head"):
         xn = norm(x.astype(jnp.float32), params["ln_f"])
-        if cfg.tie_head:
-            logits = xn @ params["emb"].T
-        else:
-            logits = jnp.dot(
-                xn.astype(dtype), params["head"].astype(dtype),
-                preferred_element_type=jnp.float32,
-            )
+        # tied or not, the head's product in ``dtype``, accumulated in f32
+        head = params["emb"].T if cfg.tie_head else params["head"]
+        logits = jnp.dot(
+            xn.astype(dtype), head.astype(dtype),
+            preferred_element_type=jnp.float32,
+        )
     return logits, total
 
 

@@ -1030,6 +1030,18 @@ def lm_instruments(reg: MetricsRegistry) -> Dict[str, object]:
             "query sees the last LMConfig.window keys, full = all of them)",
             labelnames=("kind",),
         ),
+        "conv_token_layers": reg.ensure_counter(
+            "ps_lm_conv_token_layers_total",
+            "token-layers of the gated short-convolution ('conv') layers "
+            "computed (forward count: tokens x such layers)",
+        ),
+        "expert_bias_spread": reg.ensure_gauge(
+            "ps_lm_expert_bias_spread",
+            "the routers' selection bias after the last launch collected: "
+            "its largest less its smallest entry over the experts, in the "
+            "layer where that is largest (0 at the start; a model without "
+            "such a bias never sets it)",
+        ),
         "launch_seconds": reg.ensure_histogram(
             "ps_lm_launch_seconds", buckets=PHASE_BUCKETS,
             help="a launch of the LM trainer, its submit to its collect's end",

@@ -809,7 +809,7 @@ def test_mistral4_descriptions_build_the_config_they_built():
 @pytest.mark.parametrize("bad,match", [
     (dict(layers=(("kda", "dense"), ("mha", "dense"))), "needs LMConfig.kda"),
     (dict(layers=(("gla", "dense"), ("mha", "dense"))),
-     r"attention \('mha', 'swa', 'mla', 'kda'\)"),
+     r"attention \('mha', 'swa', 'mla', 'kda', 'conv'\)"),
 ])
 def test_lmconfig_names_the_kinds_that_exist(bad, match):
     with pytest.raises(ValueError, match=match):

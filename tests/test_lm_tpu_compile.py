@@ -1,8 +1,8 @@
 """The benchmark's language-model steps whose expert layers' tail is one
-piece (the Mistral and the Mellum cell), compiled at their real size for
-a TPU v5e that is described and not attached (no chip time, no result,
-no timing): what the chip's compiler would refuse, and the memory it
-plans.
+piece (the Mistral, the Mellum and the LFM2 cell), compiled at their real
+size for a TPU v5e that is described and not attached (no chip time, no
+result, no timing): what the chip's compiler would refuse, and the memory
+it plans.
 
 The topology is described inside a fixture, never while a module is
 imported, and only in this file: one process at a time may load the
@@ -27,6 +27,8 @@ CONFIGS = os.path.join(ROOT, "chipbench", "configs")
 CELLS = {
     "mistral_small4_ep16": (1_154_524_160, []),
     "mellum2_ep4": (1_077_059_840, ["copy"]),
+    # 1,196,018,048 trained and 12 x 64 selection biases
+    "lfm2_ep8": (1_196_018_816, ["copy"]),
 }
 CHIP_BYTES = 16_909_336_064
 
@@ -85,7 +87,10 @@ def step(request, topo, no_compile_cache):
     params = jax.tree.map(
         spec, jax.eval_shape(lambda k: init_lm(k, cfg), jax.random.PRNGKey(0))
     )
-    opt = jax.tree.map(spec, jax.eval_shape(trainer.tx.init, params))
+    # the optimizer's state of the leaves it steps (not the routers' bias)
+    opt = jax.tree.map(
+        spec, jax.eval_shape(trainer.tx.init, trainer.trained(params))
+    )
     tokens = jax.ShapeDtypeStruct(
         (t["batch"], t["seq_len"]), jnp.int32,
         sharding=NamedSharding(mesh, P(None, "data")),
@@ -104,14 +109,17 @@ def step(request, topo, no_compile_cache):
 def test_the_cells_step_compiles_for_one_v5e_chip(step):
     cfg, compiled, _ = step
     # the flash kernels, the only pallas_call of the step: forward, dq and
-    # dkv once a layer (latent attention in one cell, window and full GQA
-    # layers in the other). The forward's output and log-sum-exp are kept
-    # across jax.checkpoint, so the recomputed layer holds no fourth
+    # dkv once an attention layer (latent attention in one cell, window
+    # and full GQA layers in another, full GQA layers among short
+    # convolutions in the third). The forward's output and log-sum-exp
+    # are kept across jax.checkpoint, so the recomputed layer holds no
+    # fourth
     kernels = re.findall(
         r' custom-call\([^\n]*custom_call_target="tpu_custom_call"'
         r'[^\n]*op_name="[^"]*pallas_call"', compiled.as_text()
     )
-    assert len(kernels) == 3 * len(cfg.layer_kinds), len(kernels)
+    attention = sum(a in ("mha", "swa", "mla") for a, _ in cfg.layer_kinds)
+    assert len(kernels) == 3 * attention, len(kernels)
     memory = compiled.memory_analysis()
     planned = (
         memory.argument_size_in_bytes + memory.output_size_in_bytes
@@ -119,8 +127,9 @@ def test_the_cells_step_compiles_for_one_v5e_chip(step):
     )
     # weights once (donated) and the step's temporaries, the sorted-token
     # buffers of the head and each layer's kept attention output among
-    # them: 11.37 GB (Mistral) and 7.67 GB (Mellum, its untaken tail of
-    # 32,768 rows planned too) when written. A plan over 80% of the chip
+    # them: 11.37 GB (Mistral), 7.67 GB (Mellum, its untaken tail of
+    # 32,768 rows planned too) and 7.79 GB (LFM2: 4.80 of arguments, 2.98
+    # of temporaries) when written. A plan over 80% of the chip
     # leaves the allocator no room
     assert 0.25 * CHIP_BYTES < planned < 0.80 * CHIP_BYTES, planned
 
@@ -135,8 +144,10 @@ def test_the_buffers_tail_not_taken_moves_nothing(step):
     column-major), and the change of layout that the step compiled
     without a tail made once a layer after the combine now ends both
     branches of every conditional: exactly one ``copy``, of the one
-    [tokens, d_model] array the branch hands back, and nothing else.
-    Each cell is held to its own list."""
+    [tokens, d_model] array the branch hands back, and nothing else; so
+    does the LFM2 step, whose 12 expert layers hold an eighth of the
+    experts (a head of 16,384 of 32,768 rows). Each cell is held to its
+    own list."""
     cfg, compiled, expected = step
     text = compiled.as_text()
     bodies = dict(re.findall(
