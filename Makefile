@@ -39,6 +39,7 @@ smoke: native
 	python3 chipbench/run.py --workload solar_open2_ep40.packed8k_mb1 --seed 3000000019 --seconds 2 --trace 0 --rehearsal
 	python3 chipbench/run.py --workload mellum2_ep4.packed8k_mb1 --seed 3000000019 --seconds 2 --trace 0 --rehearsal
 	python3 chipbench/run.py --workload lfm2_ep8.packed8k_mb1 --seed 3000000019 --seconds 2 --trace 0 --rehearsal
+	python3 chipbench/run.py --workload trinity_mini_ep8.packed8k_mb1 --seed 3000000019 --seconds 2 --trace 0 --rehearsal
 
 # the full static-analysis suite (script/pslint/, doc/STATIC_ANALYSIS.md):
 # lock-discipline race detector (+ lock-order deadlock cycles),

@@ -22,13 +22,17 @@ description's latent attention, a ``solar_open2`` description's gated
 delta-rule and gated GQA layers, a ``mellum`` description's windowed
 and full GQA layers with rotary tables per kind, each with a dropless
 top-k expert layer with or without a shared expert, RMSNorm and an
-untied head over the file's vocabulary, or an ``lfm2_moe``
-description's gated short convolutions beside GQA layers, a leading
-dense layer, sigmoid routers with a selection bias and a tied head);
-bytes are then ids below 256 of that vocabulary. Such a model trains
-here; the generation flags refuse it by name, because the serving
-forwards have no latent cache, carry no recurrent or convolution state
-and read one window and one rotary table for every layer.
+untied head over the file's vocabulary, an ``lfm2_moe`` description's
+gated short convolutions beside GQA layers, a leading dense layer,
+sigmoid routers with a selection bias and a tied head, or an ``afmoe``
+description's gated window and full GQA layers, only the window layers
+rotated, under sandwich norms, with a leading dense layer and sigmoid
+routers with a selection bias beside a shared expert); bytes are then
+ids below 256 of that vocabulary. Such a model trains here; the
+generation flags refuse it by name, because the serving forwards have no
+latent cache, carry no recurrent or convolution state, read one window
+and one rotary table for every layer and add each block's output
+unnormed.
 """
 
 from __future__ import annotations

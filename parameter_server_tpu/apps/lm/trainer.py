@@ -68,7 +68,12 @@ def model_from_description(desc: dict, *, attention: str = "ring_flash",
     layers, "conv", beside rotated GQA layers with a q/k norm by
     ``layer_types``; a dense FFN in the first ``num_dense_layers``
     layers; sigmoid routers whose expert bias picks and does not weight;
-    a tied head unless the description says otherwise)."""
+    a tied head unless the description says otherwise), or ``afmoe``
+    (gated GQA layers with a q/k norm, windowed and rotated or full and
+    NoPE by ``layer_types``, under sandwich norms; an embedding scaled
+    by sqrt(hidden_size); a dense FFN in the first ``num_dense_layers``
+    layers; sigmoid routers with an expert bias, scaled by
+    ``route_scale``, beside shared experts; an untied head)."""
     from ...models.moe import TopKMoEConfig
 
     kind = desc.get("model_type")
@@ -77,6 +82,8 @@ def model_from_description(desc: dict, *, attention: str = "ring_flash",
             f"model_type {kind!r}: the model types described here are "
             f"{', '.join(MODEL_TYPES)}"
         )
+    if kind == "afmoe":
+        desc = _afmoe_router_keys(desc)
     if desc.get("hidden_act", "silu") != "silu":
         raise ValueError("the layer is a gated SiLU FFN without biases")
     for key in ("attention_bias", "mlp_bias"):
@@ -89,13 +96,16 @@ def model_from_description(desc: dict, *, attention: str = "ring_flash",
     if desc.get("first_k_dense_replace", 0) != 0:
         raise ValueError(
             "first_k_dense_replace != 0: leading dense layers are built "
-            "from an lfm2_moe description's num_dense_layers only"
+            "from the num_dense_layers of an lfm2_moe or afmoe description "
+            "only"
         )
     published, share = desc.get("published", {}), desc.get("share", {})
     # the experts held here and the router's width, under the family's
-    # key: ``n_routed_experts``, or ``num_experts`` (mellum, lfm2_moe)
+    # key: ``n_routed_experts``, or ``num_experts`` (mellum, lfm2_moe,
+    # afmoe)
     experts = (
-        "num_experts" if kind in ("mellum", "lfm2_moe") else "n_routed_experts"
+        "num_experts" if kind in ("mellum", "lfm2_moe", "afmoe")
+        else "n_routed_experts"
     )
     lfm2 = kind == "lfm2_moe"
     # lfm2_moe scores by sigmoid and names no scoring key; its norm's
@@ -273,16 +283,11 @@ def _mellum(desc: dict, common: dict):
     )
 
 
-def _lfm2_moe(desc: dict, common: dict):
-    """Layer ``i`` is a gated short convolution of ``conv_L_cache`` taps
-    where ``layer_types[i]`` is ``conv`` ("conv") and a GQA layer with a
-    q/k norm under one plain rotary table where it is
-    ``full_attention`` ("mha"); its FFN is the dense SwiGLU of
-    ``intermediate_size`` in the first ``num_dense_layers`` layers and
-    the expert layer in the others."""
-    from ...models.transformer import LMConfig
-
-    kinds = {"conv": "conv", "full_attention": "mha"}
+def _typed_layers(desc: dict, common: dict, kinds: dict) -> tuple:
+    """``LMConfig.layers`` of a description whose ``layer_types`` name
+    each layer's attention (``kinds``: its names to the program's) and
+    whose first ``num_dense_layers`` layers have the dense FFN, the
+    others the expert layer."""
     types = desc["layer_types"]
     if len(types) != common["n_layers"]:
         raise ValueError(
@@ -294,6 +299,29 @@ def _lfm2_moe(desc: dict, common: dict):
             raise ValueError(
                 f"layer_types: {name!r} is not one of {', '.join(kinds)}"
             )
+    dense = desc.get("num_dense_layers", 0)
+    if not 0 <= dense <= len(types):
+        raise ValueError(
+            f"num_dense_layers {dense} of {len(types)} layers"
+        )
+    return tuple(
+        (kinds[name], "dense" if i < dense else "moe")
+        for i, name in enumerate(types)
+    )
+
+
+def _lfm2_moe(desc: dict, common: dict):
+    """Layer ``i`` is a gated short convolution of ``conv_L_cache`` taps
+    where ``layer_types[i]`` is ``conv`` ("conv") and a GQA layer with a
+    q/k norm under one plain rotary table where it is
+    ``full_attention`` ("mha"); its FFN is the dense SwiGLU of
+    ``intermediate_size`` in the first ``num_dense_layers`` layers and
+    the expert layer in the others."""
+    from ...models.transformer import LMConfig
+
+    layers = _typed_layers(
+        desc, common, {"conv": "conv", "full_attention": "mha"}
+    )
     if desc.get("conv_bias"):
         raise ValueError(
             "conv_bias true: the short convolution is built without biases"
@@ -305,25 +333,89 @@ def _lfm2_moe(desc: dict, common: dict):
             f"rope_type {rope_type!r}: an lfm2_moe description's attention "
             "layers are built under the plain rotary table ('default') only"
         )
-    dense = desc.get("num_dense_layers", 0)
-    if not 0 <= dense <= len(types):
-        raise ValueError(
-            f"num_dense_layers {dense} of {len(types)} layers"
-        )
     return LMConfig(
         **common, n_kv_heads=desc["num_key_value_heads"],
         head_dim=desc.get("head_dim"), qk_norm=True, rope=True,
         rope_theta=float(rp["rope_theta"]), conv_size=desc["conv_L_cache"],
-        layers=tuple(
-            (kinds[name], "dense" if i < dense else "moe")
-            for i, name in enumerate(types)
-        ),
+        layers=layers,
+    )
+
+
+# an afmoe description's router keys (torchtitan's MoE arguments) and the
+# names the other families give them
+_AFMOE_ROUTER_KEYS = {
+    "score_func": "scoring_func", "route_norm": "norm_topk_prob",
+    "route_scale": "routed_scaling_factor",
+    "num_shared_experts": "n_shared_experts",
+}
+
+
+def _afmoe_router_keys(desc: dict) -> dict:
+    """An afmoe description with its router's keys under the other
+    families' names and its selection bias on, once what is not built
+    is refused by name: grouped routing, a bias rate other than
+    ``models/moe.EXPERT_BIAS_RATE``, a scaled rotary table, and a model
+    whose embedding is not scaled (``mup_enabled`` false)."""
+    from ...models.moe import EXPERT_BIAS_RATE
+
+    for key in ("n_group", "num_expert_groups", "num_limited_groups",
+                "topk_group"):
+        if desc.get(key, 1) != 1:
+            raise ValueError(
+                f"{key} {desc[key]}: grouped routing is not built"
+            )
+    rate = desc.get("load_balance_coeff")
+    if rate != EXPERT_BIAS_RATE:
+        raise ValueError(
+            f"load_balance_coeff {rate!r}: the selection bias moves by "
+            f"models/moe.EXPERT_BIAS_RATE ({EXPERT_BIAS_RATE}) a step only"
+        )
+    if desc.get("rope_scaling") is not None:
+        raise ValueError(
+            "rope_scaling: an afmoe description's window layers are built "
+            "under the plain rotary table only"
+        )
+    if not desc.get("mup_enabled"):
+        raise ValueError(
+            "mup_enabled false: an afmoe description is built with the "
+            "embedding scaled by sqrt(hidden_size) (muP) only"
+        )
+    return {
+        **desc, "use_expert_bias": True,
+        **{other: desc[key] for key, other in _AFMOE_ROUTER_KEYS.items()
+           if key in desc},
+    }
+
+
+def _afmoe(desc: dict, common: dict):
+    """Layer ``i`` is a gated GQA layer with a q/k norm that sees the
+    last ``sliding_window`` keys, rotated by the plain table of
+    ``rope_theta``, where ``layer_types[i]`` is ``sliding_attention``
+    ("swa"), and every earlier key unrotated (NoPE) where it is
+    ``full_attention`` ("mha"); each block's output is normed before its
+    residual add; the embedding rows are scaled by sqrt(hidden_size);
+    the FFN is the dense SwiGLU of ``intermediate_size`` in the first
+    ``num_dense_layers`` layers and the expert layer beside
+    ``num_shared_experts`` shared experts in the others."""
+    from ...models.transformer import LMConfig
+
+    layers = _typed_layers(
+        desc, common, {"sliding_attention": "swa", "full_attention": "mha"}
+    )
+    windowed = any(att == "swa" for att, _ in layers)
+    return LMConfig(
+        **{**common, "scale_emb": True},
+        n_kv_heads=desc["num_key_value_heads"], head_dim=desc["head_dim"],
+        qk_norm=True, attn_gate=True, post_norms=True, rope=windowed,
+        rope_theta=float(desc["rope_theta"]),
+        rope_kinds=("swa",) if windowed else None,
+        window=desc["sliding_window"] if windowed else None, layers=layers,
     )
 
 
 _ATTENTION_OF = {
     "mistral4": _mistral4, "solar_open2": _solar_open2, "mellum": _mellum,
-    "lfm2_moe": _lfm2_moe,
+    "lfm2_moe": _lfm2_moe, "afmoe": _afmoe,
 }
 MODEL_TYPES = tuple(_ATTENTION_OF)
 
@@ -361,7 +453,8 @@ class Launch:
     loss: object  # device scalar: the launch's last step's loss
     # device arrays (``lm_forward_with_stats``): the counts
     # (``transformer.STEP_COUNTS``: ``expert_rows``, ``buffer_passes``,
-    # ``kda_scan_tokens``, ``attn_token_layers``, ``conv_token_layers``)
+    # ``kda_scan_tokens``, ``attn_token_layers``, ``conv_token_layers``,
+    # ``attn_gate_token_layers``)
     # summed over the launch's steps; its last step's choices and router
     # probes
     stats: dict
@@ -617,12 +710,14 @@ class Trainer:
         """``(loss, counts)`` of a launch on the host, counted: its
         ``expert_rows`` and ``buffer_passes`` where the model has the
         dropless layer, its ``kda_scan_tokens`` where it has the gated
-        delta-rule layer, its ``attn_token_layers`` where it has
-        windowed layers beside full ones, its ``conv_token_layers``
+        delta-rule layer, its ``attn_token_layers`` (and of a gated
+        model ``attn_gate_token_layers``) where it has windowed layers
+        beside full ones, its ``conv_token_layers``
         where it has short-convolution layers; the routers' bias spread
         after it (``EXPERT_BIAS_SPREAD``) where they have a bias. The
         launch's choices and probes stay on the device (``launch.stats``)."""
         from ...models.transformer import (
+            ATTN_GATE_TOKEN_LAYERS,
             ATTN_TOKEN_LAYER_KINDS,
             ATTN_TOKEN_LAYERS,
             CONV_TOKEN_LAYERS,
@@ -651,12 +746,14 @@ class Trainer:
                     self._counters["expert_bias_spread"].set(
                         float(launch.stats[EXPERT_BIAS_SPREAD])
                     )
-                for kind, n in zip(
-                    ATTN_TOKEN_LAYER_KINDS, counts.get(ATTN_TOKEN_LAYERS, ())
+                for name, stat in (
+                    ("attention_token_layers", ATTN_TOKEN_LAYERS),
+                    ("attention_gate_token_layers", ATTN_GATE_TOKEN_LAYERS),
                 ):
-                    self._counters["attention_token_layers"].labels(
-                        kind=kind
-                    ).inc(int(n))
+                    for kind, n in zip(
+                        ATTN_TOKEN_LAYER_KINDS, counts.get(stat, ())
+                    ):
+                        self._counters[name].labels(kind=kind).inc(int(n))
                 for j, n in enumerate(counts.get("expert_rows", ())):
                     self._counters["expert_rows"].labels(
                         expert=str(self.cfg.moe.expert_offset + j)

@@ -44,13 +44,17 @@ FFN_KINDS = ("dense", "switch", "moe")
 # "kda" layers computed (forward count: tokens x such layers).
 # ``attn_token_layers`` [2]: tokens x "swa" layers and tokens x "mha"
 # layers, of a model that has both (``ATTN_TOKEN_LAYER_KINDS``).
-# ``conv_token_layers``: tokens x "conv" layers computed
+# ``conv_token_layers``: tokens x "conv" layers computed.
+# ``attn_gate_token_layers`` [2]: of those of ``attn_token_layers``, the
+# token-layers whose output gate was computed (a gated model's)
 KDA_SCAN_TOKENS = "kda_scan_tokens"
 ATTN_TOKEN_LAYERS = "attn_token_layers"
 ATTN_TOKEN_LAYER_KINDS = ("window", "full")
 CONV_TOKEN_LAYERS = "conv_token_layers"
+ATTN_GATE_TOKEN_LAYERS = "attn_gate_token_layers"
 STEP_COUNTS = MOE_COUNTS + (
     KDA_SCAN_TOKENS, ATTN_TOKEN_LAYERS, CONV_TOKEN_LAYERS,
+    ATTN_GATE_TOKEN_LAYERS,
 )
 
 
@@ -124,8 +128,8 @@ class LMConfig:
     # None = d_model // n_heads. Training only: the serving forwards
     # refuse an explicit width by name
     head_dim: "int | None" = None
-    # an "mha" layer's output gate: out = (att * sigmoid(h W_g)) W_o,
-    # W_g [d_model, n_heads * head_dim], elementwise. Training only
+    # an "mha" or "swa" layer's output gate: out = (att * sigmoid(h W_g))
+    # W_o, W_g [d_model, n_heads * head_dim], elementwise. Training only
     attn_gate: bool = False
     # rotary position embedding (RoFormer, Su et al. 2021): q/k head
     # vectors are rotated by position-dependent angles before attention,
@@ -140,11 +144,15 @@ class LMConfig:
     # such layer the tables are per kind: ``rope_theta`` and
     # ``rope_yarn`` make the "mha" layers', ``swa_rope`` the "swa"
     # layers' (None: the same table). Each kind's tables are made once,
-    # outside the layers. YaRN and ``swa_rope`` train only
+    # outside the layers. ``rope_kinds`` names the kinds that are rotated
+    # where the others are NoPE (("swa",): window layers rotated, full
+    # layers not); None = both. YaRN, ``swa_rope`` and ``rope_kinds``
+    # train only
     rope: bool = False
     rope_theta: float = 10000.0
     rope_yarn: "YarnRope | None" = None
     swa_rope: "Rope | None" = None
+    rope_kinds: "tuple | None" = None
     # RMSNorm over each q and each k head's ``head_dim`` channels before
     # the rotation, one scale vector each a layer (leaves ``q_norm``,
     # ``k_norm`` [head_dim], ``norm_eps``). Training only
@@ -187,6 +195,11 @@ class LMConfig:
     # ``conv_size`` tokens (leaves ``conv_in`` [d, 3d], ``conv_taps``
     # [conv_size, d], ``conv_out`` [d, d]); training only
     layers: "tuple | None" = None
+    # sandwich norms: the output of each layer's attention (or other
+    # operator) and of its FFN normed (``norm``, scales ``post_attn_norm``
+    # and ``post_ffn_norm`` [d_model], ones) before its residual add.
+    # Training only
+    post_norms: bool = False
     mla: "MLAConfig | None" = None
     moe: "TopKMoEConfig | None" = None
     kda: "KDAConfig | None" = None
@@ -286,10 +299,19 @@ class LMConfig:
                 f"LMConfig.rope pairs head dimensions: head_dim="
                 f"{self.head_width} must be even"
             )
-        if not self.rope and (self.rope_yarn or self.swa_rope):
+        if not self.rope and (
+            self.rope_yarn or self.swa_rope or self.rope_kinds is not None
+        ):
             raise ValueError(
-                "LMConfig.rope_yarn / swa_rope describe rotary tables: "
-                "they need LMConfig.rope"
+                "LMConfig.rope_yarn / swa_rope / rope_kinds describe rotary "
+                "tables: they need LMConfig.rope"
+            )
+        if self.rope_kinds is not None and not set(self.rope_kinds) <= {
+            "mha", "swa"
+        }:
+            raise ValueError(
+                f"LMConfig.rope_kinds {self.rope_kinds!r}: the rotated kinds "
+                "are 'mha' and 'swa'"
             )
 
     @property
@@ -320,9 +342,10 @@ def refuse_serving(cfg: LMConfig, where: str) -> None:
     table for every layer) over a K/V cache and a dense or switch FFN.
     A layer of another kind ("mla", "kda", "conv", "moe"), windowed layers
     beside full ones ("swa" beside "mha"), rotary tables per kind of
-    layer or under YaRN, a q/k norm, an explicit head width or an output
-    gate is refused by name: decoding it through those would be a
-    different model."""
+    layer or under YaRN, rotation of one kind of layer only, a q/k norm,
+    post-block norms, an explicit head width or an output gate is
+    refused by name: decoding it through those would be a different
+    model."""
     attention_kinds = {att for att, _ in cfg.layer_kinds}
     if {"swa", "mha"} <= attention_kinds:
         raise NotImplementedError(
@@ -337,6 +360,19 @@ def refuse_serving(cfg: LMConfig, where: str) -> None:
             f"{where}: the cached forwards rotate by one plain table; "
             "rotary tables per kind of layer or under YaRN "
             "(LMConfig.swa_rope / rope_yarn) train through lm_forward only"
+        )
+    if cfg.rope_kinds is not None:
+        raise NotImplementedError(
+            f"{where}: the cached forwards rotate every layer or none; "
+            "rotation by kind of layer (LMConfig.rope_kinds: "
+            f"{cfg.rope_kinds!r} rotated, the rest NoPE) trains through "
+            "lm_forward only"
+        )
+    if cfg.post_norms:
+        raise NotImplementedError(
+            f"{where}: the cached forwards add each block's output "
+            "unnormed; post-block norms (LMConfig.post_norms: "
+            "post_attn_norm, post_ffn_norm) train through lm_forward only"
         )
     if cfg.qk_norm:
         raise NotImplementedError(
@@ -399,6 +435,9 @@ def init_lm(key: jax.Array, cfg: LMConfig) -> Dict[str, jax.Array]:
         k1, k2, k3, k4 = ks[2 + 4 * i : 6 + 4 * i]
         p[f"l{i}/ln1"] = jnp.ones((cfg.d_model,))
         p[f"l{i}/ln2"] = jnp.ones((cfg.d_model,))
+        if cfg.post_norms:  # no key drawn: the other leaves as ever
+            p[f"l{i}/post_attn_norm"] = jnp.ones((cfg.d_model,))
+            p[f"l{i}/post_ffn_norm"] = jnp.ones((cfg.d_model,))
         if att_kind == "mla":
             for name, w in latent.init_mla(
                 k1, cfg.d_model, cfg.n_heads, cfg.mla, s
@@ -631,8 +670,10 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
     recurrence's last state and log-decay, in the bits it computed with).
     With "swa" or "conv" layers also ``attn_token_layers`` [2] int32
     (tokens x "swa" layers, tokens x "mha" layers:
-    ``ATTN_TOKEN_LAYER_KINDS``); with "conv" layers ``conv_token_layers``
-    (int32: tokens x such layers). With a router bias (``TopKMoEConfig.expert_bias``) also
+    ``ATTN_TOKEN_LAYER_KINDS``), and in a gated model
+    ``attn_gate_token_layers`` [2] (those whose output gate was
+    computed); with "conv" layers ``conv_token_layers`` (int32: tokens x
+    such layers). With a router bias (``TopKMoEConfig.expert_bias``) also
     ``expert_load`` [layers, n_experts], what the step moves it against.
     Layers without an expert layer add nothing to the stacks: their
     index is the expert layers' own (a leading dense layer is not one).
@@ -640,7 +681,11 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
     Named scopes, for the device trace: ``lm_attn`` (an "mha", "swa" or
     "mla" layer's projections and attention; in a model with "swa"
     layers ``attn_window`` or ``attn_full`` nested inside it, by the
-    layer's kind), ``lm_kda_*`` (models/kda.py), ``lm_conv_proj`` (a
+    layer's kind; ``attn_gate`` inside it, the output gate's product,
+    sigmoid and multiply), ``lm_post_norm`` (a post-block norm, inside
+    the scope of the block it norms: ``lm_attn``, ``lm_conv_out``,
+    ``lm_ffn``, or ``lm_moe_route`` for an expert layer), ``lm_kda_*``
+    (models/kda.py), ``lm_conv_proj`` (a
     "conv" layer's norm and W_in), ``lm_conv_mix`` (the gates and the
     causal convolution), ``lm_conv_out`` (W_out), ``lm_ffn`` or
     ``lm_moe_*`` (models/moe.py), ``lm_head``.
@@ -668,11 +713,13 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
     # permutation) and closed over by every layer — under remat they
     # enter jax.checkpoint as inputs, not per-layer recomputation
     positions = _rope_position_ids(cfg, s, mesh, axis)
-    rope_cs, made = {}, {}  # by kind of layer present; by Rope
+    rope_cs, made = {}, {}  # by kind of layer present and rotated; by Rope
     if cfg.rope:
         full = Rope(cfg.rope_theta, cfg.rope_yarn)
         for kind, rope in (("mha", full), ("swa", cfg.swa_rope or full)):
-            if not any(a == kind for a, _ in kinds):
+            if not any(a == kind for a, _ in kinds) or (
+                cfg.rope_kinds is not None and kind not in cfg.rope_kinds
+            ):
                 continue
             if rope not in made:  # two kinds may share one table
                 made[rope] = _rope_tables(
@@ -701,14 +748,14 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
         q = h @ cast("wq")
         k = h @ cast("wk")
         v = h @ cast("wv")
-        if cfg.qk_norm or cfg.rope:
+        if cfg.qk_norm or kind in rope_cs:
             # by head, BEFORE the GQA broadcast: k is still narrow
             q = q.reshape(b, s, cfg.n_heads, hd)
             k = k.reshape(b, s, cfg.kv_heads, hd)
             if cfg.qk_norm:
                 q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
                 k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-            if cfg.rope:
+            if kind in rope_cs:
                 q = _rotate(q, *rope_cs[kind])
                 k = _rotate(k, *rope_cs[kind])
             q = q.reshape(b, s, width)
@@ -734,30 +781,40 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
 
         if cfg.attention == "a2a":
             # Ulysses: q/k/v stay [B, S, d]; the layer splits heads itself
-            return gated(ulysses_attention(
+            return ulysses_attention(
                 q, k, v, mesh=mesh, axis=axis, n_heads=cfg.n_heads,
                 causal=True,
-            ), h, lp)
+            )
         att = ring_attention(
             heads(q), heads(k), heads(v), mesh=mesh, axis=axis,
             causal=True, impl=impl,
             # the "swa" layers' span; every layer's where none is "swa"
             window=cfg.window if kind == "swa" or not n_swa else None,
         )
-        att = (
+        return (
             att.reshape(b, cfg.n_heads, s, hd)
             .transpose(0, 2, 1, 3)
             .reshape(b, s, width)
         )
-        return gated(att, h, lp)
 
     def gated(att, h, lp):
+        """An "mha" or "swa" layer's output times its gate, sigmoid(h
+        W_g), where the model has one."""
         if not cfg.attn_gate:
             return att
-        gate = jax.nn.sigmoid(jnp.dot(
-            h, lp["wg"].astype(dtype), preferred_element_type=jnp.float32
-        ))
-        return (att.astype(jnp.float32) * gate).astype(dtype)
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(jnp.dot(
+                h, lp["wg"].astype(dtype), preferred_element_type=jnp.float32
+            ))
+            return (att.astype(jnp.float32) * gate).astype(dtype)
+
+    def residual(x, y, lp, name):
+        """x + y, y normed by ``lp[name]`` first where the model has
+        post-block norms."""
+        if cfg.post_norms:
+            with jax.named_scope("lm_post_norm"):
+                y = norm(y, lp[name])
+        return x + y
 
     def mla(h, lp):
         m = cfg.mla
@@ -782,14 +839,14 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
             att, stats = kdalib.kda_attention(
                 h, lp, cfg.kda, cfg.norm_eps, dtype
             )
-            x = x + att
+            x = residual(x, att, lp, "post_attn_norm")
         elif att_kind == "conv":
             with jax.named_scope("lm_conv_proj"):
                 gates = norm(x, lp["ln1"]) @ cast("conv_in")
             with jax.named_scope("lm_conv_mix"):
                 mixed = conv_mix(gates, cast("conv_taps"))
             with jax.named_scope("lm_conv_out"):
-                x = x + mixed @ cast("conv_out")
+                x = residual(x, mixed @ cast("conv_out"), lp, "post_attn_norm")
         else:
             # a model with "swa" layers names each layer's kind
             inner = contextlib.nullcontext() if not n_swa else (
@@ -800,9 +857,12 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
             with jax.named_scope("lm_attn"), inner:
                 h = norm(x, lp["ln1"])
                 att = (
-                    mla(h, lp) if att_kind == "mla" else mha(h, lp, att_kind)
+                    mla(h, lp) if att_kind == "mla"
+                    else gated(mha(h, lp, att_kind), h, lp)
                 )
-                x = x + att.astype(dtype) @ cast("wo")
+                x = residual(
+                    x, att.astype(dtype) @ cast("wo"), lp, "post_attn_norm"
+                )
         h2 = norm(x, lp["ln2"])
         if ffn_kind == "switch":
             moe_p = {
@@ -813,22 +873,27 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
             # MoE routing (top-1 argmax + capacity bookkeeping) stays in
             # the params' dtype — f32 — for stable expert selection
             with jax.named_scope("lm_ffn"):
-                x = x + moe_ffn(
+                x = residual(x, moe_ffn(
                     moe_p, h2.astype(jnp.float32), mesh=mesh, axis=axis,
                     capacity_factor=cfg.capacity_factor,
-                ).astype(dtype)
+                ).astype(dtype), lp, "post_ffn_norm")
         elif ffn_kind == "moe":
             y, moe_stats = topk_moe_ffn(lp, h2, cfg.moe, dtype)
-            x = x + y
+            # a post-norm counts with the expert layer it norms
+            with jax.named_scope(
+                "lm_moe_route"
+            ) if cfg.post_norms else contextlib.nullcontext():
+                x = residual(x, y, lp, "post_ffn_norm")
             stats = {**stats, **moe_stats}
         else:
             with jax.named_scope("lm_ffn"):
                 if cfg.ffn_act == "swiglu":
-                    x = x + swiglu(
+                    y = swiglu(
                         h2, cast("w_gate"), cast("w_up"), cast("w_down")
                     )
                 else:
-                    x = x + jax.nn.gelu(h2 @ cast("w1")) @ cast("w2")
+                    y = jax.nn.gelu(h2 @ cast("w1")) @ cast("w2")
+                x = residual(x, y, lp, "post_ffn_norm")
         return x, stats
 
     if cfg.remat:
@@ -870,6 +935,8 @@ def lm_forward_with_stats(params, tokens, cfg: LMConfig, mesh: Mesh,
         total[ATTN_TOKEN_LAYERS] = jnp.asarray(
             [b * s * n_swa, b * s * n_full], jnp.int32
         )
+        if cfg.attn_gate:  # every such layer is gated
+            total[ATTN_GATE_TOKEN_LAYERS] = total[ATTN_TOKEN_LAYERS]
     if n_conv:
         total[CONV_TOKEN_LAYERS] = jnp.asarray(b * s * n_conv, jnp.int32)
     with jax.named_scope("lm_head"):

@@ -1030,6 +1030,13 @@ def lm_instruments(reg: MetricsRegistry) -> Dict[str, object]:
             "query sees the last LMConfig.window keys, full = all of them)",
             labelnames=("kind",),
         ),
+        "attention_gate_token_layers": reg.ensure_counter(
+            "ps_lm_attention_gate_token_layers_total",
+            "of the token-layers of ps_lm_attention_token_layers_total, "
+            "those whose output gate (sigmoid(h W_g) on the attention's "
+            "output) was computed, by kind of layer",
+            labelnames=("kind",),
+        ),
         "conv_token_layers": reg.ensure_counter(
             "ps_lm_conv_token_layers_total",
             "token-layers of the gated short-convolution ('conv') layers "

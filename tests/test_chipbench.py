@@ -57,11 +57,12 @@ for _mod in (test_seam, test_lm_cell, test_lm_hybrid_cell, test_lm_swa_cell):
 
 # The Mellum cell's module holds BENCHMARK.json to the five cells it had
 # then. Later cells are files and entries of their own (the LFM2 cell:
-# tests/test_lm_conv.py holds it), and a PR that is not a ``benchmark``
+# tests/test_lm_conv.py holds it; the Trinity cell: tests/test_lm_afmoe.py
+# and the rehearsals below), and a PR that is not a ``benchmark``
 # PR may not edit that module: its test reads the benchmark without the
 # cells that came after it, and holds everything else as it was written.
 # A ``benchmark`` PR writes the count there (PERF.md section 7).
-CELLS_SINCE = ("lfm2_ep8.packed8k_mb1",)
+CELLS_SINCE = ("lfm2_ep8.packed8k_mb1", "trinity_mini_ep8.packed8k_mb1")
 
 
 class _BenchmarkAsItWas:
@@ -95,6 +96,59 @@ _counted = (
 def test_the_benchmark_lists_the_swa_cell_for_each_swa_metric(monkeypatch):
     monkeypatch.setattr(test_lm_swa_cell, "json", _BenchmarkAsItWas())
     _counted()
+
+
+def _rehearse(workload: str, traced: bool):
+    """One rehearsal of a cell through ``chipbench/run.py``, the
+    benchmark's command, in a process of its own: ``(last line,
+    stdout)``."""
+    # a traced run needs the traffic's traced stretch (0.5 s) in its window
+    done = subprocess.run(
+        [sys.executable, "chipbench/run.py", "--workload", workload,
+         "--seed", "3000000019", "--seconds", "2" if traced else "0.5",
+         "--trace", str(int(traced)), "--rehearsal"],
+        cwd=REPO, capture_output=True, text=True, timeout=600,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert done.returncode == 0, done.stderr[-3000:]
+    return json.loads(done.stdout.strip().splitlines()[-1]), done.stdout
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_the_trinity_cell_rehearses_through_the_seam(traced):
+    """The Trinity cell (tests/test_lm_afmoe.py holds its program and
+    faults): ``correct`` and the checks of the LFM2 runner it inherits;
+    traced, every ``lm_afmoe_*`` metric read from its recorded capture."""
+    from chipbench import lastline
+    from test_lm_afmoe import AFMOE_METRICS, CELL, CHECKS
+
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    line, out = _rehearse(CELL, traced)
+    assert lastline.faults(line, bench, CELL, traced) == []
+    assert line["correct"] is True and line["failed"] == 0
+    assert set(line["checks"]) == CHECKS
+    if not traced:
+        assert set(line["metrics"]) == {"examples_per_s", "setup_s"}
+        return
+    layers = {
+        m["name"] for m in lastline.cell_metrics(bench, CELL, "per_layer")
+    }
+    assert AFMOE_METRICS <= layers
+    assert set(line["metrics"]) == {"examples_per_s", "setup_s"} | layers
+    value = lambda name: line["metrics"][name]["value"]  # noqa: E731
+    for name in AFMOE_METRICS:
+        if name.endswith(("_mfu", "_roofline", "_share")):
+            assert 0 < value(name) <= 100, name
+    assert value("lm_afmoe_window_attention_share") < value(
+        "lm_afmoe_attention_share"
+    )
+    assert value("lm_afmoe_gate_share") < value("lm_afmoe_attention_share")
+    notes = [json.loads(ln) for ln in out.splitlines()
+             if ln.startswith('{"chipbench"')]
+    rows = next(n for n in notes
+                if n["chipbench"] == "lm_conv_held_rows_by_layer")
+    assert len(rows["first_launch"]) == 4 and rows["expected"] == 64
 
 
 def test_selfcheck_reproduces_its_known_answers():

@@ -543,8 +543,9 @@ def test_the_benchmark_lists_the_cell_for_each_conv_metric():
     assert len(cell["why"]) <= 200 and len(entry["why"]) <= 200
     with open(os.path.join(ROOT, entry["file"])) as f:
         assert json.load(f)["reduced"] == entry["reduced"]
-    assert bench["workloads"][-1] == cell
-    assert bench["configs"][-1] == entry
+    # appended after the cells before it (later cells come after it)
+    assert bench["workloads"].index(cell) == 5
+    assert bench["configs"].index(entry) == 5
 
 
 # -- the cell's runner, rehearsed --------------------------------------------
